@@ -1,16 +1,3 @@
-import os
-import sys
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-
-# The roofline cells compile against the 512-chip production mesh on a host
-# backend; the fused-decode bench times the real single-host serving engine,
-# where 512 fake devices would poison every measurement — so the flag is
-# only set for the roofline modes.
-if "--fused-decode-bench" not in sys.argv:
-    from repro.launch.hostdev import set_host_device_count
-    set_host_device_count(512)
-
 """Roofline analysis (deliverable g): per (arch x shape), derive the three
 terms from compiled artifacts on the single-pod production mesh:
 
@@ -38,18 +25,24 @@ collective-permute), so they get the identical correction for free.
 import argparse
 import dataclasses
 import json
+import os
 import re
+import sys
 
-import jax
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+
+import jax  # noqa: E402
 
 from repro.configs import assigned_archs, get_config  # noqa: E402
 from repro.configs.base import LM_SHAPES  # noqa: E402
-from repro.compat import cost_analysis_dict  # noqa: E402
+from repro.core.pipeline import TPU_V5E  # noqa: E402
 from repro.launch.dryrun import parse_collectives  # noqa: E402
-from repro.launch.mesh import ambient_mesh, make_production_mesh  # noqa: E402
+from repro.launch.hostdev import set_host_device_count  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.launch.steps import build_step  # noqa: E402
 
-from . import hw  # noqa: E402
+#: chips of the single-pod production mesh (launch/mesh.py, 16 x 16)
+CHIPS_SINGLE_POD = 256
 
 ART = os.path.join(os.path.dirname(__file__), "artifacts")
 
@@ -71,13 +64,13 @@ def _compile_cost_variant(cfg, shape, n_periods: int, mesh, *,
         kw["quantized"] = quantized
         if shape.kind == "decode":
             kw["kv_quant"] = kv_quant
-    with ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = build_step(vcfg, shape, mesh, **kw)
         jfn = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
                       out_shardings=bundle.out_shardings,
                       donate_argnums=bundle.donate_argnums)
         compiled = jfn.lower(*bundle.args).compile()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     coll = parse_collectives(compiled.as_text())
     coll_bytes = sum(c["bytes"] for c in coll["computations"].values())
     n_while = len(coll["whiles"])
@@ -104,7 +97,7 @@ def analytic_hbm_bytes(cfg, shape, parallelism: str, quantized: bool,
                writes it once.
       optimizer: sharded moments read+write (train).
     """
-    n_chips = hw.CHIPS_SINGLE_POD
+    n_chips = CHIPS_SINGLE_POD
     n_model = 16 if parallelism == "tp" else 1
     b, s = shape.global_batch, shape.seq_len
     n = cfg.param_count_estimate()
@@ -162,7 +155,7 @@ def analytic_collective_bytes(cfg, shape, parallelism: str) -> float:
     tp serving: one all-reduce of (tokens_loc x d) per layer + flash-decode
                 LSE merges (tiny).
     """
-    n_chips = hw.CHIPS_SINGLE_POD
+    n_chips = CHIPS_SINGLE_POD
     b, s = shape.global_batch, shape.seq_len
     d = cfg.d_model
     L = cfg.n_layers
@@ -248,17 +241,17 @@ def run_cell(arch: str, shape_name: str, *, quantized: bool = True,
                                    kv_quant=kv_quant)
     coll_bytes = analytic_collective_bytes(cfg, shape, parallelism)
 
-    t_compute = corr["flops"] / hw.PEAK_BF16_FLOPS
-    t_memory = mem_bytes / hw.HBM_BW
-    t_coll = coll_bytes / hw.ICI_BW
+    t_compute = corr["flops"] / TPU_V5E.peak_bf16_flops
+    t_memory = mem_bytes / TPU_V5E.hbm_bw
+    t_coll = coll_bytes / TPU_V5E.ici_bw
     terms = {"compute": t_compute, "memory": t_memory, "collective": t_coll}
     dominant = max(terms, key=terms.get)
     bound = max(terms.values())
 
     model_flops = analytic_model_flops(cfg, shape)
-    model_per_chip = model_flops / hw.CHIPS_SINGLE_POD
+    model_per_chip = model_flops / CHIPS_SINGLE_POD
     hlo_ratio = model_per_chip / max(corr["flops"], 1.0)
-    mfu_bound = (model_per_chip / hw.PEAK_BF16_FLOPS) / max(bound, 1e-30)
+    mfu_bound = (model_per_chip / TPU_V5E.peak_bf16_flops) / max(bound, 1e-30)
 
     result = {
         "arch": arch, "shape": shape_name, "status": "ok",
@@ -479,6 +472,10 @@ def main():
     if args.fused_decode_bench:
         fused_decode_bench([])
         return 0
+    # the roofline cells compile against the production mesh on host
+    # devices; the fused-decode bench above times the real single-host
+    # engine, where fake devices would skew every measurement
+    set_host_device_count(512)
 
     archs = assigned_archs() if (args.all or not args.arch) else [args.arch]
     results = []

@@ -55,7 +55,7 @@ def plan_report(csv_rows):
     §3.1 analytical model, with the pipeline margin the paper argues in
     prose. These are the tiles the Pallas kernels actually run with."""
     from repro.configs import get_config, list_configs
-    from repro.core.pipeline import TPU_V5E
+    from repro.core.pipeline import current_hw
     from repro.runtime import planner
 
     print("\n== execution plans (spx_matmul, 4-bit, m=256) ==")
@@ -72,20 +72,16 @@ def plan_report(csv_rows):
         print(f"  {name:22s} {k_dim:6d}->{n_dim:<6d}  "
               f"{plan.bm:4d}x{plan.bn:4d}x{plan.bk:4d} "
               f"{plan.margin:7.2f}  {plan.vmem_bytes/2**20:7.2f}")
-        assert plan.vmem_bytes <= TPU_V5E.vmem_bytes
+        assert plan.vmem_bytes <= current_hw().vmem_bytes
         csv_rows.append((f"plan/{name}", 0.0, plan.margin))
 
 
 def fused_decode_table(csv_rows):
-    """Run the fused-vs-unfused decode bench (benchmarks.roofline
-    --fused-decode-bench) in a subprocess — importing benchmarks.roofline
-    here would leak roofline-mode environment setup into this process —
-    and fold BENCH_roofline.json into the CSV."""
-    import subprocess
-    repo = os.path.normpath(os.path.join(os.path.dirname(__file__), ".."))
-    subprocess.run([sys.executable, "-m", "benchmarks.roofline",
-                    "--fused-decode-bench"], cwd=repo, check=True)
-    with open(os.path.join(repo, "BENCH_roofline.json")) as fh:
+    """Run the fused-vs-unfused decode bench in this process (one process
+    holds the chip) and fold BENCH_roofline.json into the CSV."""
+    from benchmarks import roofline
+    roofline.fused_decode_bench([])
+    with open(roofline.BENCH) as fh:
         r = json.load(fh)
     for axis in ("paged", "paged-spx"):
         csv_rows.append((f"roofline/fused_decode_{axis}_tok_per_s", 0.0,
@@ -123,6 +119,8 @@ def main() -> None:
     ap.add_argument("--skip-roofline-table", action="store_true")
     ap.add_argument("--skip-fused-decode-bench", action="store_true")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     csv_rows: list = []
     from benchmarks import fig5, quant_quality, serving_bench, table1

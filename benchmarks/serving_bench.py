@@ -59,11 +59,8 @@ from __future__ import annotations
 
 import json
 import os
-import sys
 
 import numpy as np
-
-REPO = os.path.normpath(os.path.join(os.path.dirname(__file__), ".."))
 
 ARTIFACT = os.path.normpath(os.path.join(os.path.dirname(__file__), "..",
                                          "BENCH_serving.json"))
@@ -197,8 +194,10 @@ def run(csv_rows, *, requests: int = 10, slots: int = 4, max_seq: int = 64,
     # paged (CI asserts the four per-arch keys exist in the artifact)
     result["arch_matrix"] = _arch_matrix_scenario(csv_rows, rt)
     # tensor-parallel acceptance: 1-vs-2-shard tok/s + per-shard peak KV
-    # bytes, measured in a forced-8-host-device child process
-    result["sharded"] = _sharded_scenario(csv_rows)
+    # bytes (needs two devices)
+    sharded = _sharded_scenario(csv_rows)
+    if sharded is not None:
+        result["sharded"] = sharded
 
     with open(out_path, "w") as fh:
         json.dump(result, fh, indent=2, sort_keys=True)
@@ -675,13 +674,10 @@ def _arch_matrix_scenario(csv_rows, rt, *, slots: int = 4,
     return report
 
 
-def sharded_child(*, requests: int = 8, slots: int = 4, max_seq: int = 64,
+def sharded_serving(*, requests: int = 8, slots: int = 4, max_seq: int = 64,
                   new_tokens: int = 8, seed: int = 3) -> dict:
-    """The forced-host-device half of the sharded scenario: serve the
-    pinned workload at shards=1 and shards=2 and report throughput,
-    per-shard peak KV bytes and greedy agreement. Runs in the child
-    process ``_sharded_scenario`` spawns (``--sharded-child``) — the
-    parent keeps its real single-device topology."""
+    """Serve the pinned workload at shards=1 and shards=2 and report
+    throughput, per-shard peak KV bytes and greedy agreement."""
     import dataclasses
 
     import jax
@@ -734,32 +730,22 @@ def sharded_child(*, requests: int = 8, slots: int = 4, max_seq: int = 64,
     return out
 
 
-def _sharded_scenario(csv_rows) -> dict:
-    """Tensor-parallel serving: spawn a child with 8 forced host devices
-    (the flag must precede jax backend init, so it cannot be set in this
-    process — repro.launch.hostdev owns the pattern), run
-    ``sharded_child`` there, and assert the sharded contract: greedy
-    outputs bit-identical across shard counts, 2-shard KV head-sharded
-    with per-shard peak bytes halved. Keys land in
-    BENCH_serving.json["sharded"] for the CI checks job."""
-    from repro.launch.hostdev import run_with_host_devices
+def _sharded_scenario(csv_rows) -> dict | None:
+    """Tensor-parallel serving, in this process: run ``sharded_serving``
+    and assert the sharded contract — greedy outputs bit-identical across
+    shard counts, 2-shard KV head-sharded with per-shard peak bytes
+    halved. Needs two devices: on CPU, force host devices before jax
+    starts (XLA_FLAGS=--xla_force_host_platform_device_count=8, as the
+    CI checks job does). With one device the scenario is skipped and
+    returns None. Keys land in BENCH_serving.json["sharded"]."""
+    import jax
 
-    print("\n== serving: tensor-parallel, 1 vs 2 shards (child with 8 "
-          "host devices) ==")
-    env = dict(os.environ)
-    src = os.path.join(REPO, "src")
-    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    r = run_with_host_devices(
-        [sys.executable, "-m", "benchmarks.serving_bench",
-         "--sharded-child"], 8, timeout=1800, env=env, cwd=REPO)
-    if r.returncode != 0:
-        raise RuntimeError(f"sharded child failed:\n{r.stdout[-2000:]}\n"
-                           f"{r.stderr[-4000:]}")
-    payload = None
-    for line in r.stdout.splitlines():
-        if line.startswith("SHARDED_JSON "):
-            payload = json.loads(line[len("SHARDED_JSON "):])
-    assert payload is not None, f"no SHARDED_JSON line:\n{r.stdout[-2000:]}"
+    if jax.device_count() < 2:
+        print("\n== serving: tensor-parallel skipped (one device) ==")
+        return None
+    print(f"\n== serving: tensor-parallel, 1 vs 2 shards "
+          f"({jax.device_count()} devices) ==")
+    payload = sharded_serving()
 
     s1, s2 = payload["shards_1"], payload["shards_2"]
     for n, s in (("1", s1), ("2", s2)):
@@ -783,8 +769,4 @@ def _sharded_scenario(csv_rows) -> dict:
 
 
 if __name__ == "__main__":
-    if "--sharded-child" in sys.argv:
-        print("SHARDED_JSON " + json.dumps(sharded_child()))
-    else:
-        rows: list = []
-        run(rows)
+    run([])

@@ -23,7 +23,8 @@ from __future__ import annotations
 import dataclasses
 import math
 
-__all__ = ["HwSpec", "TPU_V5E", "BlockPlan", "plan_matmul_blocks"]
+__all__ = ["HwSpec", "TPU_V5E", "HARDWARE", "hw_for_kind", "current_hw",
+           "BlockPlan", "plan_matmul_blocks"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -32,18 +33,49 @@ class HwSpec:
     peak_bf16_flops: float      # FLOP/s per chip
     hbm_bw: float               # bytes/s per chip
     ici_bw: float               # bytes/s per link
-    vmem_bytes: int             # per-core VMEM
+    vmem_bytes: int             # VMEM one kernel may claim (scoped limit)
     mxu_dim: int = 128          # systolic tile
+    source: str = ""
 
 
+#: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+#: 819 GB/s, 1,600 Gbit/s ICI per chip (4 links of 50 GB/s). The chip has
+#: 128 MiB of VMEM, but Mosaic gives one kernel a 16 MiB scoped window
+#: unless the kernel raises ``vmem_limit_bytes`` — the planner budgets
+#: against that window, which is what the compiler accepts as-is.
 TPU_V5E = HwSpec(
     name="tpu_v5e",
     peak_bf16_flops=197e12,
     hbm_bw=819e9,
     ici_bw=50e9,
-    vmem_bytes=128 * 1024 * 1024,
+    vmem_bytes=16 * 1024 * 1024,
     mxu_dim=128,
+    source="Google Cloud documentation, 'TPU v5e'",
 )
+
+#: the one hardware table, keyed by ``jax.Device.device_kind``
+HARDWARE = {"TPU v5 lite": TPU_V5E}
+
+
+def hw_for_kind(device_kind: str) -> HwSpec:
+    """The table entry for ``device_kind``; an unknown chip is an error,
+    never a default."""
+    try:
+        return HARDWARE[device_kind]
+    except KeyError:
+        raise LookupError(
+            f"no hardware entry for device kind {device_kind!r}; known: "
+            f"{sorted(HARDWARE)}") from None
+
+
+def current_hw() -> HwSpec:
+    """The running chip's entry on TPU; off the chip (CPU tests,
+    interpret-mode kernels, described-topology compiles) plans target
+    v5e explicitly."""
+    import jax
+    if jax.default_backend() == "tpu":
+        return hw_for_kind(jax.devices()[0].device_kind)
+    return TPU_V5E
 
 
 @dataclasses.dataclass
@@ -72,9 +104,12 @@ def _block_cost(m, n, k, bm, bn, bk, weight_bits, act_bytes, hw: HwSpec):
     flops = 2.0 * bm * bn * bk
     t_load = load_bytes / hw.hbm_bw
     t_compute = flops / hw.peak_bf16_flops
-    # double-buffered inputs + accumulator + dequantized weight tile
-    vmem = 2 * (bm * bk * act_bytes + bk * bn * weight_bits / 8) \
-        + bm * bn * 4 + bk * bn * 2
+    # double-buffered input and output tiles, the f32 accumulator, and the
+    # in-VMEM decode of one weight tile: int32 codes, f32 levels and the
+    # MXU operand in the activation dtype (kernels/spx_matmul.py)
+    vmem = (2 * (bm * bk * act_bytes + bk * bn * weight_bits / 8)
+            + 2 * bm * bn * act_bytes + bm * bn * 4
+            + bk * bn * (4 + 4 + act_bytes))
     return load_bytes, flops, t_load, t_compute, int(vmem)
 
 

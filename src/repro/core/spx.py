@@ -37,6 +37,7 @@ __all__ = [
     "fake_quantize",
     "calibrate_minmax",
     "calibrate_mse",
+    "int4_group",
     "pack_int4",
     "unpack_int4",
     "SCHEMES",
@@ -258,22 +259,37 @@ def calibrate_mse(x: jax.Array, scheme: str, channel_axis: int | None = -1,
 # int4 packing (two codes per byte) — halves HBM traffic again for b<=4
 # ---------------------------------------------------------------------------
 
+def int4_group(n: int) -> int:
+    """Lane-group width of the int4 packing along a last dim of ``n``
+    codes: column c of each ``2 * G``-column group shares a byte with
+    column c + G. ``G = 128`` (one TPU lane tile) whenever ``n`` allows
+    it, so a kernel unpacks a tile by concatenating two lane-aligned
+    halves — no interleave; otherwise one group spans the whole dim."""
+    return 128 if n % 256 == 0 else n // 2
+
+
 def pack_int4(codes: jax.Array) -> jax.Array:
-    """Pack uint8 codes (<16) pairwise along the LAST axis: even idx -> low
-    nibble. Last dim must be even."""
-    if codes.shape[-1] % 2:
+    """Pack uint8 codes (<16) two per byte along the LAST axis: within
+    each group of ``2 * int4_group(n)`` columns, the first half goes to
+    the low nibble and the second half to the high nibble. Last dim must
+    be even."""
+    n = codes.shape[-1]
+    if n % 2:
         raise ValueError(
             f"pack_int4 needs an even last dim (two 4-bit codes per byte); "
             f"got shape {tuple(codes.shape)} with last dim "
-            f"{codes.shape[-1]}. Pad the weight or pass pack=False.")
-    lo = codes[..., 0::2]
-    hi = codes[..., 1::2]
-    return (lo | (hi << 4)).astype(jnp.uint8)
+            f"{n}. Pad the weight or pass pack=False.")
+    g = int4_group(n)
+    grouped = codes.reshape(*codes.shape[:-1], n // (2 * g), 2, g)
+    lo, hi = grouped[..., 0, :], grouped[..., 1, :]
+    packed = (lo | (hi << 4)).astype(jnp.uint8)
+    return packed.reshape(*codes.shape[:-1], n // 2)
 
 
 def unpack_int4(packed: jax.Array) -> jax.Array:
     """Inverse of pack_int4; doubles the last axis."""
-    lo = packed & 0x0F
-    hi = (packed >> 4) & 0x0F
-    out = jnp.stack([lo, hi], axis=-1)
-    return out.reshape(*packed.shape[:-1], packed.shape[-1] * 2).astype(jnp.uint8)
+    n = packed.shape[-1] * 2
+    g = int4_group(n)
+    grouped = packed.reshape(*packed.shape[:-1], n // (2 * g), g)
+    out = jnp.stack([grouped & 0x0F, (grouped >> 4) & 0x0F], axis=-2)
+    return out.reshape(*packed.shape[:-1], n).astype(jnp.uint8)

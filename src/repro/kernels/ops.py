@@ -18,6 +18,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import spx
 from repro.core.quantized import QuantizedTensor
@@ -33,7 +34,7 @@ from .spx_matmul import spx_matmul_pallas
 
 __all__ = ["spx_matmul", "flash_attention", "paged_attention",
            "paged_attention_quant", "paged_decode_ragged", "resolve_impl",
-           "op_calls", "reset_op_calls"]
+           "op_calls", "op_impls", "reset_op_calls"]
 
 
 def _on_tpu() -> bool:
@@ -49,6 +50,9 @@ def _on_tpu() -> bool:
 # ---------------------------------------------------------------------------
 
 _OP_CALLS: collections.Counter = collections.Counter()
+#: the same calls keyed by (op, impl that ran): an accelerator impl that
+#: handed a shape to the oracle shows up under "ref"
+_OP_IMPLS: collections.Counter = collections.Counter()
 
 
 def op_calls() -> dict[str, int]:
@@ -56,8 +60,28 @@ def op_calls() -> dict[str, int]:
     return dict(_OP_CALLS)
 
 
+def op_impls() -> dict[tuple[str, str], int]:
+    """Wrapper-call counts per (op, impl that ran) since the last
+    ``reset_op_calls()``; the quantized-pool decode counts as
+    ``paged_decode_ragged_quant``."""
+    return dict(_OP_IMPLS)
+
+
 def reset_op_calls() -> None:
     _OP_CALLS.clear()
+    _OP_IMPLS.clear()
+
+
+def _whole_per_device(fn, mesh):
+    """``fn`` (a Pallas kernel call) for a jitted program over ``mesh``.
+    GSPMD cannot partition a Mosaic kernel, so under a mesh of several
+    devices every device runs the whole call: a shard_map with replicated
+    specs gathers sharded operands first. Splitting the kernel's work
+    across the mesh is not done here."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
 
 
 def resolve_impl(impl: str) -> str:
@@ -103,8 +127,10 @@ registry.register("spx_matmul", "interpret",
 
 
 def spx_matmul(x: jax.Array, qt: QuantizedTensor, *, impl: str = "auto",
-               out_dtype=None) -> jax.Array:
-    """x: (..., K) @ dequant(qt: (K, N)) -> (..., N)."""
+               out_dtype=None, mesh=None) -> jax.Array:
+    """x: (..., K) @ dequant(qt: (K, N)) -> (..., N). ``mesh``: the
+    device mesh the enclosing jitted program is partitioned over, if any
+    (a Pallas kernel then runs whole on every device)."""
     _OP_CALLS["spx_matmul"] += 1
     entry = registry.resolve("spx_matmul", impl)
     k_dim, n_dim = qt.logical_shape
@@ -116,6 +142,7 @@ def spx_matmul(x: jax.Array, qt: QuantizedTensor, *, impl: str = "auto",
         # merges differently-sharded dims and forces a full gather
         # (measured 16x replicated linear-layer compute, EXPERIMENTS.md
         # §Perf cell 2)
+        _OP_IMPLS["spx_matmul", "ref"] += 1
         return entry.fn(x, qt, scale, plan=None, out_dtype=out_dtype)
 
     lead = x.shape[:-1]
@@ -123,7 +150,15 @@ def spx_matmul(x: jax.Array, qt: QuantizedTensor, *, impl: str = "auto",
     m = x2.shape[0]
     plan = planner.plan_matmul(m, k_dim, n_dim, weight_bits=qt.bits,
                                act_bytes=x.dtype.itemsize, packed=qt.packed)
-    if plan is None:                       # ragged dims: oracle fallback
+    if plan is None:
+        if entry.impl == "pallas":
+            # on the chip the oracle would hide that the kernel never ran
+            raise ValueError(
+                f"spx_matmul: no legal Pallas tiling for (M={m}, "
+                f"K={k_dim}, N={n_dim}, packed={qt.packed}); pad the "
+                "weight to 128-multiples or run impl='ref'")
+        # interpret mode (CPU debugging): ragged dims run the oracle
+        _OP_IMPLS["spx_matmul", "ref"] += 1
         out = ref_impl.spx_matmul_ref(x2, qt.codes, scale, qt.lut,
                                       packed=qt.packed, out_dtype=out_dtype)
         return out.reshape(*lead, n_dim)
@@ -144,7 +179,10 @@ def spx_matmul(x: jax.Array, qt: QuantizedTensor, *, impl: str = "auto",
             # and cache a garbage plan
             plan = _autotune_matmul(key, entry, x2, qt, scale, plan,
                                     out_dtype)
-    out = entry.fn(x2, qt, scale, plan=plan, out_dtype=out_dtype)
+    _OP_IMPLS["spx_matmul", entry.impl] += 1
+    out = _whole_per_device(
+        functools.partial(entry.fn, plan=plan, out_dtype=out_dtype),
+        mesh)(x2, qt, scale)
     return out.reshape(*lead, n_dim)
 
 
@@ -197,9 +235,11 @@ registry.register("flash_attention", "interpret",
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, impl: str = "auto") -> jax.Array:
+                    causal: bool = True, impl: str = "auto",
+                    mesh=None) -> jax.Array:
     """GQA attention. q: (B, Hq, Sq, dh); k, v: (B, Hkv, Skv, dh);
-    Hq % Hkv == 0. Returns (B, Hq, Sq, dh)."""
+    Hq % Hkv == 0. Returns (B, Hq, Sq, dh). ``mesh`` as in
+    ``spx_matmul``."""
     _OP_CALLS["flash_attention"] += 1
     b, hq, sq, dh = q.shape
     _, hkv, skv, _ = k.shape
@@ -215,13 +255,24 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     vf = v.reshape(b * hq, skv, dh)
 
     if entry.impl == "ref":
+        _OP_IMPLS["flash_attention", "ref"] += 1
         return entry.fn(qf, kf, vf, causal=causal, plan=None).reshape(q.shape)
 
     plan = planner.plan_attention(sq, skv, dh, act_bytes=q.dtype.itemsize)
-    if plan is None:                       # ragged seq dims: ref fallback
+    if plan is None:
+        if entry.impl == "pallas":
+            raise ValueError(
+                f"flash_attention: no legal Pallas tiling for (Sq={sq}, "
+                f"Skv={skv}); pad the sequence to a multiple of 8 or run "
+                "impl='ref'")
+        _OP_IMPLS["flash_attention", "ref"] += 1
         return ref_impl.attention_ref(qf, kf, vf,
                                       causal=causal).reshape(q.shape)
-    return entry.fn(qf, kf, vf, causal=causal, plan=plan).reshape(q.shape)
+    _OP_IMPLS["flash_attention", entry.impl] += 1
+    out = _whole_per_device(
+        functools.partial(entry.fn, causal=causal, plan=plan), mesh)(
+            qf, kf, vf)
+    return out.reshape(q.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -248,7 +299,7 @@ registry.register("paged_attention", "interpret",
 
 def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                     block_table: jax.Array, ctx_len: jax.Array, *,
-                    impl: str = "auto") -> jax.Array:
+                    impl: str = "auto", mesh=None) -> jax.Array:
     """Decode attention of one query token per sequence against its paged
     KV context.
 
@@ -257,7 +308,8 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     (B, max_pages) int32 physical page per logical page; ctx_len: (B,)
     int32 — positions < ctx_len attended (0 = inactive row, output zeros).
     Returns (B, Hq, dh). Page geometry is chosen at pool-allocation time
-    via planner.plan_kv_pages, not per call.
+    via planner.plan_kv_pages, not per call. ``mesh`` as in
+    ``spx_matmul``.
     """
     _OP_CALLS["paged_attention"] += 1
     b, hq, dh = q.shape
@@ -265,9 +317,11 @@ def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     assert hq % hkv == 0, (hq, hkv)
     q4 = q.reshape(b, hkv, hq // hkv, dh)
     entry = registry.resolve("paged_attention", impl)
-    out = entry.fn(q4, k_pages, v_pages,
-                   jnp.asarray(block_table, jnp.int32),
-                   jnp.asarray(ctx_len, jnp.int32))
+    _OP_IMPLS["paged_attention", entry.impl] += 1
+    fn = entry.fn if entry.impl == "ref" else _whole_per_device(entry.fn,
+                                                                mesh)
+    out = fn(q4, k_pages, v_pages, jnp.asarray(block_table, jnp.int32),
+             jnp.asarray(ctx_len, jnp.int32))
     return out.reshape(b, hq, dh)
 
 
@@ -299,7 +353,7 @@ registry.register("paged_attention_quant", "interpret",
 def paged_attention_quant(q: jax.Array, k_pages: dict, v_pages: dict,
                           block_table: jax.Array, ctx_len: jax.Array, *,
                           kv_scheme: str = "uniform8",
-                          impl: str = "auto") -> jax.Array:
+                          impl: str = "auto", mesh=None) -> jax.Array:
     """Decode attention of one query token per sequence against its
     quantized paged KV context, with LUT dequantization fused into the
     page-streaming loop (no full-pool dequant pass — the paper's §3.2
@@ -309,7 +363,8 @@ def paged_attention_quant(q: jax.Array, k_pages: dict, v_pages: dict,
     dh) uint8, "scale": (n_pages, Hkv, page_size, 1) f32} — the pools
     ``nn.attention.paged_kv_write`` maintains; ``kv_scheme`` names the
     core/spx level set the codes were quantized under (static — resolves
-    to a <=256-entry f32 codebook). Returns (B, Hq, dh).
+    to a <=256-entry f32 codebook). Returns (B, Hq, dh). ``mesh`` as in
+    ``spx_matmul``.
     """
     _OP_CALLS["paged_attention_quant"] += 1
     b, hq, dh = q.shape
@@ -318,10 +373,12 @@ def paged_attention_quant(q: jax.Array, k_pages: dict, v_pages: dict,
     q4 = q.reshape(b, hkv, hq // hkv, dh)
     lut = spx.codebook(spx.scheme_levels(kv_scheme), dtype=jnp.float32)
     entry = registry.resolve("paged_attention_quant", impl)
-    out = entry.fn(q4, k_pages["codes"], k_pages["scale"],
-                   v_pages["codes"], v_pages["scale"],
-                   jnp.asarray(block_table, jnp.int32),
-                   jnp.asarray(ctx_len, jnp.int32), lut)
+    _OP_IMPLS["paged_attention_quant", entry.impl] += 1
+    fn = entry.fn if entry.impl == "ref" else _whole_per_device(entry.fn,
+                                                                mesh)
+    out = fn(q4, k_pages["codes"], k_pages["scale"], v_pages["codes"],
+             v_pages["scale"], jnp.asarray(block_table, jnp.int32),
+             jnp.asarray(ctx_len, jnp.int32), lut)
     return out.reshape(b, hq, dh)
 
 
@@ -375,7 +432,7 @@ registry.register("paged_decode_ragged_quant", "interpret",
 def paged_decode_ragged(q: jax.Array, k_pages, v_pages,
                         block_table: jax.Array, ctx_len: jax.Array,
                         q_len: jax.Array, *, kv_scheme: str | None = None,
-                        impl: str = "auto") -> jax.Array:
+                        impl: str = "auto", mesh=None) -> jax.Array:
     """Ragged decode-window attention in ONE kernel launch per tick.
 
     q: (B, W, Hq, dh) — W window positions per slot (spec K+1, or 1 for
@@ -393,7 +450,7 @@ def paged_decode_ragged(q: jax.Array, k_pages, v_pages,
     The per-slot attend_len = ctx_len + q_len drives the kernel's page
     loop trip count directly — no pow2 window padding, so varying
     attend_len across ticks never retraces. Block tables and both length
-    vectors ride as scalar prefetch.
+    vectors ride as scalar prefetch. ``mesh`` as in ``spx_matmul``.
     """
     _OP_CALLS["paged_decode_ragged"] += 1
     quant = isinstance(k_pages, dict)
@@ -411,6 +468,7 @@ def paged_decode_ragged(q: jax.Array, k_pages, v_pages,
 
     op = "paged_decode_ragged_quant" if quant else "paged_decode_ragged"
     entry = registry.resolve(op, impl)
+    _OP_IMPLS[op, entry.impl] += 1
     if quant:
         if kv_scheme is None:
             raise ValueError("quantized KV pools need kv_scheme for the "
@@ -445,7 +503,10 @@ def paged_decode_ragged(q: jax.Array, k_pages, v_pages,
 
             planner.measured_best(key, [plan], runner)
 
-    out = entry.fn(*args, w=w)
+    fn = functools.partial(entry.fn, w=w)
+    if entry.impl != "ref":
+        fn = _whole_per_device(fn, mesh)
+    out = fn(*args)
     # inverse of the rep-major packing
     return jnp.moveaxis(out.reshape(b, hkv, rep, w, dh), 3, 1) \
               .reshape(b, w, hq, dh)

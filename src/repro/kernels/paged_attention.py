@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import pallas_compiler_params
+from .decode import lut_decode, lut_spec
 
 __all__ = ["paged_attention_pallas", "paged_attention_quant_pallas",
            "paged_decode_ragged_pallas", "paged_decode_ragged_quant_pallas"]
@@ -110,10 +110,10 @@ def _quant_kernel(bt_ref, ctx_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
                   lut_ref, o_ref, m_ref, l_ref, acc_ref, *, scale: float,
                   page_size: int, n_logical: int, out_dtype):
     """Fused-dequant variant: K/V pages arrive as uint8 codebook codes plus
-    a per-token f32 scale; the LUT gather (VPU) happens page-by-page in
+    a per-token f32 scale; the LUT decode (VPU) happens page-by-page in
     VMEM, so HBM only ever moves 1-byte codes — the §3.2 memory win
     applied to the decode hot path. The codebook (<=256 f32 entries) is
-    resident in VMEM for the whole grid."""
+    resident in SMEM for the whole grid."""
     del bt_ref
     b = pl.program_id(0)
     j = pl.program_id(2)
@@ -126,9 +126,8 @@ def _quant_kernel(bt_ref, ctx_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
 
     @pl.when(j * page_size < ctx)
     def _page():
-        lut = lut_ref[...]
-        k = jnp.take(lut, kc_ref[0, 0].astype(jnp.int32), axis=0)
-        v = jnp.take(lut, vc_ref[0, 0].astype(jnp.int32), axis=0)
+        k = lut_decode(kc_ref[0, 0].astype(jnp.int32), lut_ref)
+        v = lut_decode(vc_ref[0, 0].astype(jnp.int32), lut_ref)
         _page_update(q_ref[0, 0], k, v, ctx, j, scale=scale,
                      page_size=page_size, m_ref=m_ref, l_ref=l_ref,
                      acc_ref=acc_ref,
@@ -212,7 +211,7 @@ def _ragged_quant_kernel(bt_ref, ctx_ref, qn_ref, q_ref, kc_ref, ks_ref,
                          acc_ref, *, scale: float, page_size: int, w: int,
                          n_logical: int, out_dtype):
     """Fused-LUT ragged megakernel: K/V pages stream as uint8 codes +
-    per-token scale, the <=256-entry codebook sits in VMEM for the whole
+    per-token scale, the <=256-entry codebook sits in SMEM for the whole
     grid, and dequantization happens page-by-page right before the MXU —
     the §3.2 memory win on the one launch the decode tick makes."""
     del bt_ref
@@ -228,9 +227,8 @@ def _ragged_quant_kernel(bt_ref, ctx_ref, qn_ref, q_ref, kc_ref, ks_ref,
 
     @pl.when(j * page_size < ctx + qn)
     def _page():
-        lut = lut_ref[...]
-        k = jnp.take(lut, kc_ref[0, 0].astype(jnp.int32), axis=0)
-        v = jnp.take(lut, vc_ref[0, 0].astype(jnp.int32), axis=0)
+        k = lut_decode(kc_ref[0, 0].astype(jnp.int32), lut_ref)
+        v = lut_decode(vc_ref[0, 0].astype(jnp.int32), lut_ref)
         _ragged_page_update(q_ref[0, 0], k, v, ctx, qn, j, scale=scale,
                             page_size=page_size, w=w, m_ref=m_ref,
                             l_ref=l_ref, acc_ref=acc_ref,
@@ -287,7 +285,7 @@ def paged_decode_ragged_pallas(q, k_pages, v_pages, block_table, ctx_len,
                           w=w, n_logical=max_pages, out_dtype=out_dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, dh), out_dtype),
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_table, ctx_len, q_len, q, k_pages, v_pages)
@@ -326,8 +324,7 @@ def paged_decode_ragged_quant_pallas(q, k_codes, k_scale, v_codes, v_scale,
             page_spec(1),                  # k scale
             page_spec(dh),                 # v codes
             page_spec(1),                  # v scale
-            pl.BlockSpec(lut.shape,        # whole LUT, VMEM-resident
-                         lambda bb, h, j, bt, ctx, qn: (0,)),
+            lut_spec(),                    # whole LUT, SMEM-resident
         ],
         out_specs=pl.BlockSpec((1, 1, rows, dh),
                                lambda bb, h, j, bt, ctx, qn: (bb, h, 0, 0)),
@@ -343,7 +340,7 @@ def paged_decode_ragged_quant_pallas(q, k_codes, k_scale, v_codes, v_scale,
                           out_dtype=out_dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows, dh), out_dtype),
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_table, ctx_len, q_len, q, k_codes, k_scale, v_codes, v_scale,
@@ -386,7 +383,7 @@ def paged_attention_pallas(q, k_pages, v_pages, block_table, ctx_len, *,
                           n_logical=max_pages, out_dtype=out_dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rep, dh), out_dtype),
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_table, ctx_len, q, k_pages, v_pages)
@@ -429,8 +426,7 @@ def paged_attention_quant_pallas(q, k_codes, k_scale, v_codes, v_scale,
             page_spec(1),                  # k scale
             page_spec(dh),                 # v codes
             page_spec(1),                  # v scale
-            pl.BlockSpec(lut.shape,        # whole LUT, VMEM-resident
-                         lambda bb, h, j, bt, ctx: (0,)),
+            lut_spec(),                    # whole LUT, SMEM-resident
         ],
         out_specs=pl.BlockSpec((1, 1, rep, dh),
                                lambda bb, h, j, bt, ctx: (bb, h, 0, 0)),
@@ -445,7 +441,7 @@ def paged_attention_quant_pallas(q, k_codes, k_scale, v_codes, v_scale,
                           n_logical=max_pages, out_dtype=out_dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rep, dh), out_dtype),
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_table, ctx_len, q, k_codes, k_scale, v_codes, v_scale, lut)

@@ -9,8 +9,9 @@ This is the paper's accelerator, re-thought for the TPU memory hierarchy:
   PU pipeline (clk_compute)    MXU consuming the current VMEM tile while the
                                next tile's DMA is in flight
   row-of-weights per clock     (bk x bn) weight-code tile per grid step
-  shift-add of PoT terms       b-bit code -> bf16 via VMEM LUT gather (VPU),
-                               then a dense MXU matmul
+  shift-add of PoT terms       b-bit code -> level via a select chain over
+                               the SMEM codebook (VPU), then a dense MXU
+                               matmul
   temporary `array t`          f32 accumulator tile in VMEM scratch
 
 The load/compute decoupling argument of §3.1 (loading must stay ahead of
@@ -32,33 +33,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import pallas_compiler_params
+from repro.core.spx import int4_group
+
+from .decode import lut_decode, lut_spec, unpack_int4_tile
 
 __all__ = ["spx_matmul_pallas"]
 
 
-def _unpack_int4_block(codes):
-    """(bk, bn/2) uint8 -> (bk, bn) uint8, even logical idx = low nibble."""
-    lo = codes & 0x0F
-    hi = (codes >> 4) & 0x0F
-    stacked = jnp.stack([lo, hi], axis=-1)
-    return stacked.reshape(codes.shape[0], codes.shape[1] * 2)
-
-
 def _kernel(x_ref, codes_ref, scale_ref, lut_ref, o_ref, acc_ref, *,
-            packed: bool, n_k: int, out_dtype):
-    """One grid step: decode a weight tile in VMEM, MXU-accumulate."""
+            group: int | None, n_k: int, out_dtype):
+    """One grid step: decode a weight tile in VMEM, MXU-accumulate.
+    ``group`` is the int4 lane-group width of packed codes (None when the
+    codes are stored one per byte)."""
     k_idx = pl.program_id(2)
 
     @pl.when(k_idx == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    codes = codes_ref[...]
-    if packed:
-        codes = _unpack_int4_block(codes)
-    # LUT decode on the VPU: codes index a <=256-entry table resident in VMEM.
-    w = jnp.take(lut_ref[...], codes.astype(jnp.int32), axis=0)
+    if group is None:
+        codes = codes_ref[...].astype(jnp.int32)
+    else:
+        codes = unpack_int4_tile(codes_ref[...], group)
+    # LUT decode on the VPU against the SMEM-resident codebook
+    w = lut_decode(codes, lut_ref)
     x = x_ref[...]
     acc_ref[...] += jax.lax.dot_general(
         x, w.astype(x.dtype),
@@ -80,7 +78,9 @@ def spx_matmul_pallas(x, codes, scale, lut, *, packed: bool,
     """x:(M,K) @ dequant(codes:(K,N), scale:(1,N), lut:(2^b,)) -> (M,N).
 
     codes are uint8; if ``packed`` the stored array is (K, N//2) with two
-    4-bit codes per byte. Block shapes are chosen by the planner
+    4-bit codes per byte in the lane-grouped layout of
+    ``core/spx.pack_int4`` (``bn`` is then a multiple of twice the group
+    width). Block shapes are chosen by the planner
     (repro.runtime.planner) and passed explicitly; shapes must be pre-padded
     to block multiples by the ops.py wrapper.
     """
@@ -91,22 +91,24 @@ def spx_matmul_pallas(x, codes, scale, lut, *, packed: bool,
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, (m, n, k, bm, bn, bk)
     n_k = k // bk
     pack_div = 2 if packed else 1
+    group = int4_group(n) if packed else None
+    assert group is None or bn % (2 * group) == 0, (n, bn, group)
 
     grid = (m // bm, n // bn, n_k)
     return pl.pallas_call(
-        functools.partial(_kernel, packed=packed, n_k=n_k,
+        functools.partial(_kernel, group=group, n_k=n_k,
                           out_dtype=out_dtype),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn // pack_div), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
-            pl.BlockSpec(lut.shape, lambda i, j, kk: (0,)),
+            lut_spec(),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, codes, scale, lut)

@@ -24,8 +24,7 @@ import jax
 
 from repro.configs import assigned_archs, get_config
 from repro.configs.base import LM_SHAPES
-from repro.compat import cost_analysis_dict
-from repro.launch.mesh import ambient_mesh, make_production_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import build_step
 
 ARTIFACT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
@@ -122,7 +121,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.monotonic()
-    with ambient_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = build_step(cfg, shape, mesh, **(extra_kw or {}))
         jfn = jax.jit(bundle.fn, in_shardings=bundle.in_shardings,
                       out_shardings=bundle.out_shardings,
@@ -134,7 +133,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         t_compile = time.monotonic() - t0
 
     mem = compiled.memory_analysis()
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     hlo = compiled.as_text()
     coll = parse_collectives(hlo)
 

@@ -10,39 +10,21 @@ from it inherited 512 fake devices (benchmarks/roofline.py grew a lazy
 import to dodge that).
 
 This module is the shared helper instead — import-safe (never touches
-jax), explicit about process boundaries:
-
-* ``set_host_device_count(n)`` — mutate THIS process's ``XLA_FLAGS``.
-  Call it at the top of a ``main()``, before anything runs a jax
-  computation. Replaces an existing force flag rather than stacking a
-  second one; preserves unrelated flags.
-* ``host_device_env(n)`` — a copy of the environment with the flag set,
-  for spawning a subprocess with its own device count.
-* ``run_with_host_devices(argv, n)`` — subprocess.run with that env
-  (the pattern tests/test_sharded_serving.py and the sharded serving
-  bench use: the parent process keeps its real device topology).
-* ``forced_host_device_count()`` — parse the current flag, or None.
+jax): ``set_host_device_count(n)`` mutates THIS process's ``XLA_FLAGS``.
+Call it at the top of a ``main()``, before anything runs a jax
+computation. It replaces an existing force flag rather than stacking a
+second one and preserves unrelated flags. A process that holds a chip
+spawns no second jax process: the chip belongs to one process at a time.
 """
 from __future__ import annotations
 
 import os
 import re
-import subprocess
-import sys
 
-__all__ = ["set_host_device_count", "host_device_env",
-           "run_with_host_devices", "forced_host_device_count"]
+__all__ = ["set_host_device_count"]
 
 _FLAG = "--xla_force_host_platform_device_count"
 _FLAG_RE = re.compile(re.escape(_FLAG) + r"=(\d+)")
-
-
-def forced_host_device_count(env=None) -> int | None:
-    """The forced host device count in ``env`` (default: this process's
-    environment), or None when the flag is absent."""
-    env = os.environ if env is None else env
-    m = _FLAG_RE.search(env.get("XLA_FLAGS", ""))
-    return int(m.group(1)) if m else None
 
 
 def _with_flag(flags: str, n: int) -> str:
@@ -60,29 +42,3 @@ def set_host_device_count(n: int) -> None:
     if n < 1:
         raise ValueError(f"host device count must be >= 1, got {n}")
     os.environ["XLA_FLAGS"] = _with_flag(os.environ.get("XLA_FLAGS", ""), n)
-
-
-def host_device_env(n: int, base=None) -> dict:
-    """A copy of ``base`` (default: this environment) with the force flag
-    set to ``n`` — for subprocesses that need their own device count."""
-    if n < 1:
-        raise ValueError(f"host device count must be >= 1, got {n}")
-    env = dict(os.environ if base is None else base)
-    env["XLA_FLAGS"] = _with_flag(env.get("XLA_FLAGS", ""), n)
-    return env
-
-
-def run_with_host_devices(argv, n: int, *, timeout=600, check=False,
-                          **kw) -> subprocess.CompletedProcess:
-    """Run ``argv`` (or a ``python -c`` source string) in a subprocess
-    with ``n`` forced host devices. The child gets a fresh jax backend,
-    so the flag actually applies; the parent's device topology is
-    untouched — this is the ONLY safe way to mix device counts in one
-    test/bench process tree."""
-    if isinstance(argv, str):
-        argv = [sys.executable, "-c", argv]
-    env = host_device_env(n, base=kw.pop("env", None))
-    kw.setdefault("capture_output", True)
-    kw.setdefault("text", True)
-    return subprocess.run(list(argv), env=env, timeout=timeout,
-                          check=check, **kw)

@@ -6,45 +6,23 @@ for data parallelism (gradient all-reduce) — parameters never shard over it.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-try:  # AxisType landed after jax 0.4.x; older versions infer Auto
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover
-    AxisType = None
+__all__ = ["make_production_mesh", "make_host_mesh", "make_serving_mesh"]
 
-__all__ = ["make_production_mesh", "make_host_mesh", "make_serving_mesh",
-           "mesh_axis_kwargs", "ambient_mesh"]
-
-
-def ambient_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh where this
-    jax supports one (jax.set_mesh / jax.sharding.use_mesh); no-op
-    otherwise — explicit NamedShardings on jit in/out cover our use."""
-    import contextlib
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return contextlib.nullcontext()
-
-
-def mesh_axis_kwargs(n_axes: int) -> dict:
-    """``axis_types=(Auto,)*n`` where this jax supports it, else nothing."""
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+_AUTO2 = (AxisType.Auto,) * 2
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (CPU tests)."""
-    return jax.make_mesh((data, model), ("data", "model"),
-                         **mesh_axis_kwargs(2))
+    return jax.make_mesh((data, model), ("data", "model"), axis_types=_AUTO2)
 
 
 def make_serving_mesh(model: int = 1, devices=None):
@@ -64,4 +42,4 @@ def make_serving_mesh(model: int = 1, devices=None):
             f"devices, got {len(devs)}")
     arr = np.empty((1, model), dtype=object)
     arr[0, :] = devs
-    return Mesh(arr, ("data", "model"), **mesh_axis_kwargs(2))
+    return Mesh(arr, ("data", "model"), axis_types=_AUTO2)

@@ -58,12 +58,36 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.configs.base import ArchConfig
 from repro.core import spx
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import encdec as encdec_mod
 from repro.models import lm as lm_mod
+from repro.nn.layers import quantize_params
 from repro.runtime import Runtime
 from repro.serving import ReplicaRouter
 from repro.serving.engine import Request, ServeConfig, ServeEngine
+
+
+def init_params(cfg: ArchConfig, seed: int, scheme: str | None):
+    """Random weights for ``cfg`` from ``seed``, SPx-quantized under
+    ``scheme`` (None keeps them dense). Decoder-only configs (dense, MoE,
+    SSM, hybrid, M-RoPE) init through the LM assembly, enc-dec through the
+    encoder-decoder one. The f32 tree is unreferenced once quantized, so
+    only the quantized weights stay on the device — at qwen2-vl-2b width
+    that is 7.1 GB of f32 traded for 0.66 GB of codes plus the 1.9 GB
+    embedding and head, which stay dense."""
+    init = encdec_mod.encdec_init if cfg.enc_dec else lm_mod.lm_init
+    params = init(jax.random.PRNGKey(seed), cfg)
+    return quantize_params(params, scheme) if scheme else params
+
+
+def build_engine(params, cfg: ArchConfig, sconf: ServeConfig, rt: Runtime):
+    """The serving front end for a resolved ``sconf``: a ReplicaRouter
+    when it asks for replicas, otherwise one ServeEngine."""
+    if sconf.replicas > 1:
+        return ReplicaRouter(params, cfg, sconf, rt=rt)
+    return ServeEngine(params, cfg, sconf, rt=rt)
 
 
 def _run_streaming(eng, reqs, arrival_s: float):
@@ -189,24 +213,20 @@ def main(argv=None):
                          "(0 = back-to-back, still interleaved with ticks)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
 
-    # the driver serves every bundled architecture: decoder-only configs
-    # (dense/MoE/SSM/hybrid/M-RoPE) through the LM assembly, enc-dec
-    # through the encoder-decoder assembly with synthetic input frames —
-    # two distinct inputs alternating across requests, so the state
-    # cache's shared cross-KV region sees hits (docs/SERVING.md)
+    # enc-dec configs serve synthetic input frames — two distinct inputs
+    # alternating across requests, so the state cache's shared cross-KV
+    # region sees hits (docs/SERVING.md)
+    frame_sets = None
     if cfg.enc_dec:
-        params = encdec_mod.encdec_init(jax.random.PRNGKey(args.seed), cfg)
         fr_rng = np.random.default_rng(args.seed + 1)
         frame_sets = fr_rng.standard_normal(
             (2, cfg.enc_seq_len, cfg.d_model)).astype(np.float32)
-    else:
-        params = lm_mod.lm_init(jax.random.PRNGKey(args.seed), cfg)
-        frame_sets = None
     scheme = None if args.scheme == "none" else args.scheme
     rt = Runtime(impl="auto", q_chunk=256, kv_quant=args.kv_quant,
                  kv_scheme=args.kv_scheme)
@@ -222,10 +242,7 @@ def main(argv=None):
         scheduler=args.scheduler, host_pages=args.host_pages,
         prefix_cache_pages=args.prefix_cache_pages,
         shards=args.shards, replicas=args.replicas).resolve(cfg)
-    if sconf.replicas > 1:
-        eng = ReplicaRouter(params, cfg, sconf, rt=rt)
-    else:
-        eng = ServeEngine(params, cfg, sconf, rt=rt)
+    eng = build_engine(init_params(cfg, args.seed, scheme), cfg, sconf, rt)
 
     rng = np.random.default_rng(args.seed)
     sys_prompt = (rng.integers(0, cfg.vocab_size, args.shared_prefix)

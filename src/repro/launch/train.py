@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, reduced
 from repro.data.tokens import TokenStream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import encdec as ed
 from repro.models import lm as lm_mod
 from repro.runtime import Runtime
@@ -47,6 +48,7 @@ def main(argv=None):
                     help="SPx scheme for gradient compression, e.g. sp2_8")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

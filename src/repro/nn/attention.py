@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import spx
 from repro.kernels import ops
 from repro.kernels import ref as kref
@@ -137,7 +136,8 @@ def attention_core(q, k, v, *, causal: bool, rt: Runtime):
         return _attention_core_cp(q, k, v, causal=causal, rt=rt)
     impl = registry.resolve("flash_attention", rt.impl).impl
     if impl in ("pallas", "interpret"):
-        return ops.flash_attention(q, k, v, causal=causal, impl=impl)
+        return ops.flash_attention(q, k, v, causal=causal, impl=impl,
+                                   mesh=rt.mesh)
     b, hq, sq, dh = q.shape
     hkv = k.shape[1]
     rep = hq // hkv
@@ -172,7 +172,7 @@ def _attention_core_cp(q, k, v, *, causal: bool, rt: Runtime):
                                   q_chunk=min(rt.q_chunk, s_loc),
                                   unroll=rt.unroll, q_offset=off)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=rt.mesh,
         in_specs=(P(dp, None, axis, None), P(dp, None, None, None),
                   P(dp, None, None, None)),
@@ -387,7 +387,7 @@ def decode_attention(q, k_cache, v_cache, k_new, v_new, pos, *, rt: Runtime):
                   else arr_spec)
     rep_spec = P(dp, None, None)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_local_flash_decode, shard_size=shard_size,
                           axis=axis, kv_scheme=scheme),
         mesh=rt.mesh,
@@ -555,7 +555,8 @@ def attn_paged_step(p: dict, x: jax.Array, ctx_len: jax.Array,
         # <= ctx_len[b] + i, rows past n_valid[b] come back zero (unused)
         out = ops.paged_decode_ragged(
             q, kp, vp, block_table, ctx_len, n_valid,
-            kv_scheme=rt.kv_scheme if quantized else None, impl=rt.impl)
+            kv_scheme=rt.kv_scheme if quantized else None, impl=rt.impl,
+            mesh=rt.mesh)
         o = out.reshape(b, c, n_heads * head_dim)
     elif c == 1:
         q1 = q[:, 0].reshape(b, n_heads, head_dim)
@@ -563,10 +564,10 @@ def attn_paged_step(p: dict, x: jax.Array, ctx_len: jax.Array,
             out = ops.paged_attention_quant(q1, kp, vp, block_table,
                                             attend_len,
                                             kv_scheme=rt.kv_scheme,
-                                            impl=rt.impl)
+                                            impl=rt.impl, mesh=rt.mesh)
         else:
             out = ops.paged_attention(q1, kp, vp, block_table, attend_len,
-                                      impl=rt.impl)
+                                      impl=rt.impl, mesh=rt.mesh)
         o = out[:, None]                                 # (B, 1, Hq*dh)->..
         o = o.reshape(b, 1, n_heads * head_dim)
     else:

@@ -73,7 +73,8 @@ def dense_init(key, d_in: int, d_out: int, *, bias: bool = False,
 def dense_apply(p: dict, x: jax.Array, rt: Runtime | None = None) -> jax.Array:
     w = p["w"]
     if isinstance(w, QuantizedTensor):
-        y = ops.spx_matmul(x, w, impl=(rt.impl if rt else "auto"))
+        y = ops.spx_matmul(x, w, impl=(rt.impl if rt else "auto"),
+                           mesh=(rt.mesh if rt else None))
     else:
         y = jnp.dot(x, w.astype(x.dtype))
     if "b" in p:
@@ -170,7 +171,10 @@ def quantize_params(params: Any, scheme: str = "sp2_4", *,
             return quantize_weight(leaf, scheme, calibration=calibration)
         return leaf
 
-    return jax.tree_util.tree_map_with_path(maybe_q, params)
+    # a QuantizedTensor is itself a pytree: without is_leaf the walk would
+    # reach its scale array and quantize that a second time
+    return jax.tree_util.tree_map_with_path(
+        maybe_q, params, is_leaf=lambda l: isinstance(l, QuantizedTensor))
 
 
 def param_count(params: Any) -> int:

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.quantized import QuantizedTensor, dequantize
 from repro.runtime import Runtime
 
@@ -151,7 +150,7 @@ def moe_apply(p: dict, x: jax.Array, *, top_k: int, n_experts: int,
             and n_tok % max(n_data, 1) == 0):
         axis = rt.model_axis
         dp = rt.data_axes if rt.data_axes else None
-        fn = shard_map(
+        fn = jax.shard_map(
             functools.partial(_moe_local, top_k=top_k,
                               n_experts_global=n_experts,
                               capacity_factor=capacity_factor,

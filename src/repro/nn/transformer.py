@@ -89,14 +89,18 @@ def _slot_init(key, slot: str, cfg: ArchConfig, dtype) -> dict:
 
 
 def stack_init(key, cfg: ArchConfig, dtype=jnp.float32) -> dict:
-    """Stacked params: {'slots': [slot_pytree(P, ...), ...]}."""
+    """Stacked params: {'slots': [slot_pytree(P, ...), ...]}.
+
+    Each slot's periods initialize under ``vmap`` over their keys — the
+    same values as initializing period by period and stacking, without
+    holding every period's copy next to the stack (at full width that
+    would double the f32 weights on the device)."""
     n_p = cfg.n_periods
     slots = []
     for j, slot in enumerate(cfg.pattern):
         keys = jax.random.split(jax.random.fold_in(key, j), n_p)
-        per_period = [_slot_init(k, slot, cfg, dtype) for k in keys]
-        slots.append(jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *per_period))
+        slots.append(jax.vmap(
+            lambda k, slot=slot: _slot_init(k, slot, cfg, dtype))(keys))
     return {"slots": slots}
 
 

@@ -46,7 +46,8 @@ import functools
 import os
 from typing import Callable, Optional
 
-from repro.core.pipeline import HwSpec, TPU_V5E, plan_matmul_blocks
+from repro.core.pipeline import HwSpec, current_hw, plan_matmul_blocks
+from repro.core.spx import int4_group
 
 __all__ = [
     "MatmulBlocks", "AttentionBlocks", "KVPagePlan", "FusedDecodePlan",
@@ -107,10 +108,9 @@ class KVPagePlan:
     vmem_bytes: int
 
 
-def _divisors(dim: int, *, even: bool = False) -> tuple[int, ...]:
+def _divisors(dim: int) -> tuple[int, ...]:
     out = tuple(c for c in _BLOCK_CANDIDATES
-                if c <= dim and dim % c == 0
-                and (not even or c % 2 == 0))
+                if c <= dim and dim % c == 0)
     # the §3.1 margin is block-size-neutral along K (load and compute both
     # scale linearly), so an unfiltered search ties and the min-VMEM
     # tie-break degenerates to 8-wide tiles. Floor candidates at the MXU
@@ -122,10 +122,17 @@ def _divisors(dim: int, *, even: bool = False) -> tuple[int, ...]:
     return out
 
 
+def _bn_legal(n: int, bn: int, packed: bool) -> bool:
+    """bn tiles n, and an int4-packed tile holds whole lane groups
+    (``core/spx.pack_int4``) so the kernel can unpack it on its own."""
+    return n % bn == 0 and not (packed and bn % (2 * int4_group(n)))
+
+
 def matmul_candidates(m: int, k: int, n: int, *,
                       packed: bool = False) -> tuple:
     """(bm, bn, bk) candidate tuples under the divisibility rules the
-    Pallas wrapper needs: bn | n, bk | k (bn even when int4-packed); bm is
+    Pallas wrapper needs: bn | n, bk | k (bn a multiple of two int4 lane
+    groups when packed — the whole dim when it has one group); bm is
     free (M is padded).
 
     Units: ``m``/``k``/``n`` are matrix dims in elements; returned
@@ -133,7 +140,9 @@ def matmul_candidates(m: int, k: int, n: int, *,
     callers (``_plan_matmul_cached``, the autotuner) cache downstream.
     """
     bm_c = tuple(c for c in _BLOCK_CANDIDATES if c <= max(m, DEFAULT_BM))
-    bn_c = _divisors(n, even=packed)
+    bn_c = tuple(c for c in _divisors(n) if _bn_legal(n, c, packed))
+    if packed and not bn_c and n <= max(_BLOCK_CANDIDATES):
+        bn_c = (n,)
     bk_c = _divisors(k)
     return bm_c, bn_c, bk_c
 
@@ -162,7 +171,7 @@ def _plan_matmul_cached(m: int, k: int, n: int, weight_bits: int,
                               vmem_fraction=VMEM_BUDGET_FRACTION)
     # the tiny-problem fallback inside plan_matmul_blocks ignores the
     # candidate filter; re-check divisibility before trusting it
-    if n % plan.bn or k % plan.bk or (packed and plan.bn % 2):
+    if not _bn_legal(n, plan.bn, packed) or k % plan.bk:
         return None
     return MatmulBlocks(plan.bm, plan.bn, plan.bk, plan.pipelined,
                         plan.margin, plan.vmem_bytes)
@@ -170,7 +179,7 @@ def _plan_matmul_cached(m: int, k: int, n: int, weight_bits: int,
 
 def plan_matmul(m: int, k: int, n: int, *, weight_bits: int = 16,
                 act_bytes: int = 2, packed: bool = False,
-                hw: HwSpec = TPU_V5E) -> Optional[MatmulBlocks]:
+                hw: HwSpec | None = None) -> Optional[MatmulBlocks]:
     """Blocks for x:(M,K) @ W:(K,N) with b-bit SPx weight codes, or None if
     no legal blocking exists (caller falls back to the ref path).
 
@@ -185,10 +194,11 @@ def plan_matmul(m: int, k: int, n: int, *, weight_bits: int = 16,
     pinned = _env_override("REPRO_BLOCKS_MATMUL", 3)
     if pinned is not None:
         bm, bn, bk = pinned
-        if n % bn or k % bk or (packed and bn % 2):
+        if not _bn_legal(n, bn, packed) or k % bk:
             return None
         return MatmulBlocks(bm, bn, bk, False, 0.0, 0)
-    return _plan_matmul_cached(m, k, n, weight_bits, act_bytes, packed, hw)
+    return _plan_matmul_cached(m, k, n, weight_bits, act_bytes, packed,
+                               hw or current_hw())
 
 
 @functools.lru_cache(maxsize=4096)
@@ -202,8 +212,9 @@ def _plan_attention_cached(sq: int, skv: int, dh: int, act_bytes: int,
             # across the KV loop)
             t_load = 2 * bkv * dh * act_bytes / hw.hbm_bw
             t_compute = 4.0 * bq * bkv * dh / hw.peak_bf16_flops
-            vmem = (2 * (bq * dh + 2 * bkv * dh) * act_bytes
-                    + bq * dh * 4 + 2 * bq * 4)      # acc + (m, l) scratch
+            vmem = (2 * (2 * bq * dh + 2 * bkv * dh) * act_bytes
+                    + bq * dh * 4 + 2 * bq * 4       # acc + (m, l) scratch
+                    + 2 * bq * bkv * 4)              # f32 scores + probs
             if vmem > hw.vmem_bytes * VMEM_BUDGET_FRACTION:
                 continue
             margin = t_compute / max(t_load, 1e-30)
@@ -217,7 +228,7 @@ def _plan_attention_cached(sq: int, skv: int, dh: int, act_bytes: int,
 
 
 def plan_attention(sq: int, skv: int, dh: int, *, act_bytes: int = 2,
-                   hw: HwSpec = TPU_V5E) -> Optional[AttentionBlocks]:
+                   hw: HwSpec | None = None) -> Optional[AttentionBlocks]:
     """(bq, bkv) for flash attention over (Sq, Skv, dh), or None when the
     sequence dims admit no candidate blocking (ref fallback).
 
@@ -231,7 +242,8 @@ def plan_attention(sq: int, skv: int, dh: int, *, act_bytes: int = 2,
         if sq % bq or skv % bkv:
             return None
         return AttentionBlocks(bq, bkv, False, 0.0, 0)
-    return _plan_attention_cached(sq, skv, dh, act_bytes, hw)
+    return _plan_attention_cached(sq, skv, dh, act_bytes,
+                                  hw or current_hw())
 
 
 # ---------------------------------------------------------------------------
@@ -291,7 +303,7 @@ def _plan_kv_pages_cached(n_kv_heads: int, dh: int, rep: int,
 
 def plan_kv_pages(n_kv_heads: int, dh: int, *, rep: int = 1,
                   act_bytes: int = 2, kv_scheme: str | None = None,
-                  hw: HwSpec = TPU_V5E) -> KVPagePlan:
+                  hw: HwSpec | None = None) -> KVPagePlan:
     """Tokens-per-page for the paged KV cache.
 
     Units: ``n_kv_heads``/``dh`` are element counts (the cache page is
@@ -318,7 +330,7 @@ def plan_kv_pages(n_kv_heads: int, dh: int, *, rep: int = 1,
     else:
         tok_bytes, floor_bytes = dh * act_bytes, act_bytes
     return _plan_kv_pages_cached(n_kv_heads, dh, rep, act_bytes, tok_bytes,
-                                 floor_bytes, hw)
+                                 floor_bytes, hw or current_hw())
 
 
 def plan_seq_pages(n_tokens: int, page_size: int, *,
@@ -452,7 +464,7 @@ def plan_shard_budget(n_kv_heads: int, dh: int, *, shards: int = 1,
                       page_size: int, n_pages: int, n_layers: int = 1,
                       slab_bytes: int = 0, act_bytes: int = 2,
                       kv_scheme: str | None = None,
-                      hw: HwSpec = TPU_V5E) -> ShardBudget:
+                      hw: HwSpec | None = None) -> ShardBudget:
     """Per-shard page/slab/VMEM budget for a tensor-parallel paged engine.
 
     The page pool is ``(layers, n_pages, Hkv, page_size, dh)`` per K/V
@@ -493,10 +505,11 @@ class FusedDecodePlan:
     whole (rep * w, dh) query window + f32 accumulator/stats resident,
     double-buffers one K+V page pair (codes + per-token scale for a
     quantized pool), and — quantized pools only — keeps the <=256-entry
-    codebook LUT pinned in VMEM for the entire launch.
+    codebook LUT pinned in SMEM for the entire launch and decodes each
+    page in VMEM (int32 codes, then f32 levels, for K and V).
 
     rows         rep * w query rows per grid step (w = spec K+1, or 1)
-    lut_bytes    resident codebook bytes (0 for a dense pool)
+    lut_bytes    SMEM-resident codebook bytes (0 for a dense pool)
     pipelined    §3.1 condition for the page loop at this window size
     margin       compute/load ratio for one (K, V) page pair
     vmem_bytes   total per-step working set in bytes
@@ -517,10 +530,11 @@ def _plan_fused_decode_cached(dh: int, rep: int, w: int, page_size: int,
     # QK^T + PV for all ``rows`` window rows on the current one
     t_load = 2 * page_size * tok_bytes / hw.hbm_bw
     t_compute = 4.0 * rows * page_size * dh / hw.peak_bf16_flops
+    decode = 2 * page_size * dh * (4 + 4) if lut_bytes else 0
     vmem = (2 * 2 * page_size * tok_bytes     # double-buffered K+V pages
-            + rows * dh * act_bytes           # resident ragged q window
+            + 2 * 2 * rows * dh * act_bytes   # q window in, output out
             + rows * dh * 4 + 2 * rows * 4    # f32 acc + (m, l) scratch
-            + lut_bytes)                      # whole-launch-resident LUT
+            + decode)                         # in-VMEM K/V page decode
     margin = t_compute / max(t_load, 1e-30)
     return FusedDecodePlan(rows, lut_bytes, t_load <= t_compute, margin,
                            int(vmem))
@@ -529,15 +543,15 @@ def _plan_fused_decode_cached(dh: int, rep: int, w: int, page_size: int,
 def plan_fused_decode(dh: int, *, rep: int = 1, w: int = 1,
                       page_size: int = 8, act_bytes: int = 2,
                       kv_scheme: str | None = None,
-                      hw: HwSpec = TPU_V5E) -> FusedDecodePlan:
+                      hw: HwSpec | None = None) -> FusedDecodePlan:
     """Working-set model for the ragged decode megakernel.
 
     Units: ``dh`` and ``page_size`` are element/token counts; ``rep = Hq //
     Hkv``; ``w`` is the static decode window (spec K+1, or 1 for plain
     decode); ``act_bytes`` the query/cache element width. ``kv_scheme``
     switches the streamed-page byte model to the quantized codes+scale
-    layout *and* charges the scheme's codebook LUT as VMEM-resident for
-    the whole launch (it is prefetched once, not per page).
+    layout, counts the scheme's codebook LUT (SMEM-resident for the whole
+    launch, prefetched once) and charges the per-page decode to VMEM.
 
     Always returns a plan — page geometry was already fixed by
     ``plan_kv_pages`` at pool allocation, so there is no candidate search
@@ -556,7 +570,8 @@ def plan_fused_decode(dh: int, *, rep: int = 1, w: int = 1,
     else:
         tok_bytes, lut_bytes = dh * act_bytes, 0
     return _plan_fused_decode_cached(dh, rep, w, page_size, act_bytes,
-                                     tok_bytes, lut_bytes, hw)
+                                     tok_bytes, lut_bytes,
+                                     hw or current_hw())
 
 
 def fused_decode_key(b: int, hkv: int, rep: int, w: int, dh: int,

@@ -215,6 +215,9 @@ class ServeEngine:
         # jitted steps; block tables and token batches stay replicated.
         self.mesh = None
         self._policy = None
+        #: the one device a single-shard engine was pinned to (None: the
+        #: default device); state caches are allocated there too
+        self.device = None
         if sc.shards > 1:
             devs = (list(devices) if devices is not None
                     else list(jax.devices()))
@@ -233,6 +236,9 @@ class ServeEngine:
             self.params = jax.device_put(
                 self.params,
                 self._policy.named(self._policy.param_specs(self.params)))
+        elif devices is not None:
+            self.device = list(devices)[0]
+            self.params = jax.device_put(self.params, self.device)
 
         self.slot_req: list[Optional[Request]] = [None] * self.batch_slots
         self.slot_pos = np.zeros(self.batch_slots, np.int64)  # tokens held
@@ -267,10 +273,11 @@ class ServeEngine:
         #: work now raises under strict=True and flips this flag)
         self.drained = True
 
-        if sc.kv_layout == "paged":
-            self._init_paged()
-        else:
-            self._init_dense()
+        with jax.default_device(self.device):
+            if sc.kv_layout == "paged":
+                self._init_paged()
+            else:
+                self._init_dense()
         if self.mesh is not None:
             # head-shard the paged KV/cross pools over the model axis
             # (replicated where Hkv doesn't divide); slabs replicate.

@@ -7,7 +7,10 @@ gets its own state cache with a **per-replica page budget** (an explicit
 across replicas; the defaults are already per-replica) and, when the
 config also shards (``shards > 1``) and enough devices exist, its own
 **disjoint device slice** — replica i runs on devices
-``[i*shards, (i+1)*shards)``, so replicas never contend for a chip.
+``[i*shards, (i+1)*shards)``, so replicas never contend for a chip; a
+single-shard replica i holds device i (wrapping around when there are
+fewer devices than replicas). Weights are quantized once, here, and the
+replicas share that result.
 
 Requests route at submit time to the least-loaded replica (queued +
 resident, ties to the lowest index — deterministic, so a replayed
@@ -27,6 +30,7 @@ from typing import Optional
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.nn.layers import quantize_params
 from repro.runtime import Runtime
 from repro.serving.config import ServeConfig
 from repro.serving.engine import Request, ServeEngine
@@ -61,6 +65,10 @@ class ReplicaRouter:
         else:
             import jax
             devs = list(jax.devices())
+        if sc.quantize:
+            # once for the fleet: each engine's quantize_params then finds
+            # every weight already quantized
+            params = quantize_params(params, sc.quantize)
         self.engines: list[ServeEngine] = []
         for i in range(sc.replicas):
             if sc.shards > 1:
@@ -74,7 +82,7 @@ class ReplicaRouter:
                         "(repro.launch.hostdev)")
                 slice_ = devs[lo:lo + sc.shards]
             else:
-                slice_ = None       # single-device replicas share placement
+                slice_ = [devs[i % len(devs)]]
             self.engines.append(ServeEngine(params, cfg, per_replica,
                                             rt=rt, devices=slice_))
         self._rid_replica: dict[int, int] = {}

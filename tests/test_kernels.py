@@ -32,8 +32,10 @@ def test_spx_matmul_shapes_schemes(m, n, k, scheme):
     qt = quantize_weight(w, scheme)
     scale = qt.scale.reshape(1, n)
     want = ref.spx_matmul_ref(x, qt.codes, scale, qt.lut, packed=qt.packed)
+    # a packed tile holds whole int4 lane groups (spx.int4_group)
+    bn = 2 * spx.int4_group(n) if qt.packed else 128
     got = spx_matmul_pallas(x, qt.codes, scale, qt.lut, packed=qt.packed,
-                            bm=min(128, m), bn=128, bk=128, interpret=True)
+                            bm=min(128, m), bn=bn, bk=128, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)
 
@@ -83,9 +85,28 @@ def test_ops_wrapper_ragged_k_falls_back_to_ref():
     x = _mk((4, 100), jnp.float32, seed=15)      # K=100 has no aligned block
     w = _mk((100, 30), jnp.float32, seed=16)     # N=30 ragged too
     qt = quantize_weight(w, "sp2_4")
+    ops.reset_op_calls()
     got = ops.spx_matmul(x, qt, impl="interpret")
+    # the fallback is counted under the impl that actually ran
+    assert ops.op_impls() == {("spx_matmul", "ref"): 1}
+    ops.spx_matmul(_mk((4, 256), jnp.float32, seed=18),
+                   quantize_weight(_mk((256, 256), jnp.float32, seed=19),
+                                   "sp2_4"), impl="interpret")
+    assert ops.op_impls()[("spx_matmul", "interpret")] == 1
     want = ops.spx_matmul(x, qt, impl="ref")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5)
+
+
+def test_ops_wrapper_ragged_dims_raise_on_pallas():
+    """On the chip a shape with no legal tiling is an error, not a silent
+    run of the oracle."""
+    x = _mk((4, 100), jnp.float32, seed=15)
+    qt = quantize_weight(_mk((100, 30), jnp.float32, seed=16), "sp2_4")
+    with pytest.raises(ValueError, match="no legal Pallas tiling"):
+        ops.spx_matmul(x, qt, impl="pallas")
+    q = _mk((1, 2, 7, 64), jnp.float32, seed=17)
+    with pytest.raises(ValueError, match="no legal Pallas tiling"):
+        ops.flash_attention(q, q, q, impl="pallas")
 
 
 def test_quantized_matmul_end_to_end_snr():

@@ -96,6 +96,21 @@ def test_plan_cache_hits():
     assert planner._plan_matmul_cached.cache_info().hits == before + 1
 
 
+def test_hardware_table_by_device_kind():
+    """One table keyed by device_kind: an unknown chip is an error, and
+    off the chip the planners target v5e explicitly."""
+    from repro.core.pipeline import current_hw, hw_for_kind
+    assert hw_for_kind("TPU v5 lite") is TPU_V5E
+    assert TPU_V5E.source
+    with pytest.raises(LookupError, match="no hardware entry"):
+        hw_for_kind("TPU v99")
+    assert current_hw() is TPU_V5E
+    assert (planner.plan_matmul(256, 1536, 8960, weight_bits=4,
+                                packed=True)
+            == planner.plan_matmul(256, 1536, 8960, weight_bits=4,
+                                   packed=True, hw=TPU_V5E))
+
+
 def test_ragged_dims_return_none():
     assert planner.plan_matmul(8, 250, 130, weight_bits=4) is None
     assert planner.plan_attention(7, 13, 64) is None
@@ -210,3 +225,23 @@ def test_engine_decode_reuses_compilation():
     n = step._cache_size()
     _, caches = step(params, tok, pos + 1, caches, cfg, rt.replace())
     assert step._cache_size() == n
+
+
+def test_compile_cache_location(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; otherwise
+    the cache sits at the fixed <checkout>/.jax_cache."""
+    import pathlib
+    from repro.launch import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = compile_cache.enable_compile_cache()
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert got == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -117,6 +117,7 @@ from repro.serving.engine import Request
 kvq = sys.argv[1] == "kvq"
 spec = sys.argv[2] == "spec"
 fused = sys.argv[3] == "fused"
+impl = sys.argv[4] if len(sys.argv) > 4 else "ref"
 
 # the pinned exact-greedy workload (vocab 32 keeps random-init top-2
 # logit gaps wide; dh=128 keeps kernels in their deployed regime) with
@@ -124,7 +125,7 @@ fused = sys.argv[3] == "fused"
 cfg = dataclasses.replace(reduced(get_config("gemma-2b"), vocab=32),
                           head_dim=128, n_kv_heads=2)
 params = lm_mod.lm_init(jax.random.PRNGKey(3), cfg)
-rt = Runtime(impl="ref", q_chunk=16, kv_quant=kvq,
+rt = Runtime(impl=impl, q_chunk=16, kv_quant=kvq,
              kv_scheme="spx_8_x3" if kvq else "none")
 rng = np.random.default_rng(3)
 prompts = [rng.integers(1, cfg.vocab_size, int(n)).astype(np.int32)
@@ -163,6 +164,62 @@ def test_sharded_greedy_bit_identical(kvq, spec, fused):
                "spec" if spec else "nospec",
                "fused" if fused else "unfused")
     assert "OK identical" in out
+
+
+@pytest.mark.parametrize("kvq", [False, True], ids=["plain", "spx-kv"])
+def test_sharded_pallas_kernels_bit_identical(kvq):
+    """The Pallas kernels (interpret mode) inside the shards=2 program:
+    GSPMD cannot partition a Mosaic call, so each runs whole on every
+    device of the mesh, and greedy outputs match one device's."""
+    out = _run(_IDENTITY_WORKER, "kvq" if kvq else "plain", "nospec",
+               "fused", "interpret")
+    assert "OK identical" in out
+
+
+_PLACEMENT_WORKER = r"""
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+sys.path.insert(0, "src")
+import dataclasses
+import jax
+import numpy as np
+from repro.configs import get_config, reduced
+from repro.core.quantized import QuantizedTensor
+from repro.models import lm as lm_mod
+from repro.runtime import Runtime
+from repro.serving import ReplicaRouter, ServeConfig, ServeEngine
+from repro.serving.engine import Request
+
+cfg = dataclasses.replace(reduced(get_config("gemma-2b"), vocab=32),
+                          head_dim=128)
+params = lm_mod.lm_init(jax.random.PRNGKey(3), cfg)
+rt = Runtime(impl="ref", q_chunk=16)
+sc = ServeConfig(batch_slots=2, max_seq=64, quantize="sp2_4",
+                 kv_layout="paged", page_size=8)
+router = ReplicaRouter(params, cfg, sc.replace(replicas=4), rt=rt)
+devs = jax.devices()
+for i, eng in enumerate(router.engines):
+    leaves = jax.tree_util.tree_leaves((eng.params, eng.caches))
+    assert all(l.devices() == {devs[i]} for l in leaves), i
+rng = np.random.default_rng(3)
+prompts = [rng.integers(1, cfg.vocab_size, int(n)).astype(np.int32)
+           for n in (3, 9, 17, 6, 11, 4)]
+for i, p in enumerate(prompts):
+    router.submit(Request(rid=i, prompt=p, max_new_tokens=4))
+got = {r.rid: tuple(r.output) for r in router.run()}
+solo = ServeEngine(params, cfg, sc, rt=rt)
+for i, p in enumerate(prompts):
+    solo.submit(Request(rid=i, prompt=p, max_new_tokens=4))
+assert got == {r.rid: tuple(r.output) for r in solo.run()}
+print("OK placement", router.metrics()["requests_per_replica"])
+"""
+
+
+def test_router_pins_replica_i_to_device_i():
+    """Four single-shard replicas on four host devices: replica i's
+    weights and state cache live on device i alone, and the fleet's
+    greedy outputs match one engine's."""
+    assert "OK placement" in _run(_PLACEMENT_WORKER)
 
 
 # ---------------------------------------------------------------------------

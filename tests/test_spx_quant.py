@@ -133,6 +133,26 @@ class TestQuantizeProps:
             np.asarray(spx.unpack_int4(spx.pack_int4(codes))), np.asarray(codes))
 
 
+def test_pack_int4_lane_groups():
+    """Column c of each 256-column group shares a byte with column c+128
+    (low / high nibble), so a kernel unpacks a tile by concatenating two
+    lane-aligned halves; a dim with no 256 multiple is one group."""
+    rng = np.random.default_rng(5)
+    codes = rng.integers(0, 16, size=(2, 512)).astype(np.uint8)
+    packed = np.asarray(spx.pack_int4(jnp.asarray(codes)))
+    assert packed.shape == (2, 256)
+    for g in range(2):
+        blk = packed[:, 128 * g:128 * (g + 1)]
+        np.testing.assert_array_equal(blk & 0x0F,
+                                      codes[:, 256 * g:256 * g + 128])
+        np.testing.assert_array_equal(blk >> 4,
+                                      codes[:, 256 * g + 128:256 * (g + 1)])
+    small = rng.integers(0, 16, size=(3, 96)).astype(np.uint8)
+    sp = np.asarray(spx.pack_int4(jnp.asarray(small)))
+    np.testing.assert_array_equal(sp & 0x0F, small[:, :48])
+    np.testing.assert_array_equal(sp >> 4, small[:, 48:])
+
+
 # ---------------------------------------------------------------------------
 # QuantizedTensor + ref matmul
 # ---------------------------------------------------------------------------
@@ -147,6 +167,38 @@ class TestQuantizedTensor:
         wh = dequantize(qt, jnp.float32)
         rel = float(jnp.linalg.norm(wh - w) / jnp.linalg.norm(w))
         assert rel < 0.25  # 4-bit nonuniform: coarse but sane
+
+    @pytest.mark.parametrize("scheme", ["sp2_4", "spx_8_x3"])
+    def test_stacked_quantize_matches_whole_stack(self, scheme):
+        """Quantizing a layer stack one matrix at a time gives exactly the
+        codes and scales of calibrating the whole stack at once."""
+        rng = np.random.default_rng(6)
+        w = jnp.asarray(rng.standard_normal((3, 64, 256)) * 0.05,
+                        jnp.float32)
+        qt = quantize_weight(w, scheme)
+        scale = spx.calibrate_mse(w, scheme, axes=(-2,))
+        codes = spx.quantize_to_codes(w, spx.scheme_levels(scheme), scale)
+        if qt.packed:
+            codes = spx.pack_int4(codes)
+        np.testing.assert_array_equal(np.asarray(qt.scale),
+                                      np.asarray(scale))
+        np.testing.assert_array_equal(np.asarray(qt.codes),
+                                      np.asarray(codes))
+
+    def test_quantize_params_leaves_quantized_weights_alone(self):
+        """Quantizing an already-quantized tree changes nothing, even where
+        a stacked weight's scale is itself large enough to quantize."""
+        from repro.nn.layers import quantize_params
+        rng = np.random.default_rng(7)
+        params = {"mlp": {"w": jnp.asarray(
+            rng.standard_normal((2, 64, 4096)) * 0.05, jnp.float32)}}
+        once = quantize_params(params, "sp2_4")
+        assert once["mlp"]["w"].scale.size >= 4096
+        twice = quantize_params(once, "sp2_4")
+        assert isinstance(twice["mlp"]["w"].scale, jax.Array)
+        for a, b in zip(jax.tree_util.tree_leaves(once),
+                        jax.tree_util.tree_leaves(twice)):
+            assert a is b
 
     def test_8bit_tighter_than_4bit(self):
         rng = np.random.default_rng(1)

@@ -87,8 +87,8 @@ def test_elastic_restore_new_sharding(tmp_path):
     tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
     save_checkpoint(str(tmp_path), 5, tree)
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.launch.mesh import mesh_axis_kwargs
-    mesh = jax.make_mesh((1,), ("data",), **mesh_axis_kwargs(1))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     sh = {"w": NamedSharding(mesh, P(None, None))}
     restored, step, _ = restore_checkpoint(str(tmp_path), tree, shardings=sh)
     assert step == 5

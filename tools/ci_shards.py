@@ -33,6 +33,7 @@ SHARDS: dict[str, list[str]] = {
     # kernels/runtime/quant math/docs — many small fast tests
     "core": [
         "tests/test_attention.py",
+        "tests/test_chip_compile.py",
         "tests/test_ci_shards.py",
         "tests/test_docs.py",
         "tests/test_kernels.py",
