@@ -42,11 +42,12 @@ def _on_tpu() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Trace-time launch accounting: each public wrapper bumps its op counter on
-# every call. Under jit the wrapper body runs at TRACE time only, so after a
-# steady-state run the counter reads *kernel launches per compiled step* —
-# the megakernel tests assert exactly one paged_decode_ragged per decode
-# trace and zero legacy paged_attention* calls.
+# Trace-time call accounting: each public wrapper bumps its op counter on
+# every call. Under jit the wrapper body runs at TRACE time only, so the
+# counter reads wrapper calls in the traces made since the last reset — not
+# launches, and nothing for a step whose trace was cached by an earlier
+# caller in the process. Launches are counted where they happen, by the
+# serving engine (``metrics()["launches"]``).
 # ---------------------------------------------------------------------------
 
 _OP_CALLS: collections.Counter = collections.Counter()

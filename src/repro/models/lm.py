@@ -346,13 +346,17 @@ def lm_paged_step(params, tokens, ctx_len, block_table, n_valid, state_idx,
     attention patterns pass all-sentinel). Returns (logits (B, V) at each
     row's last valid position, new_caches).
     """
-    x = embedding_apply(params["embed"], tokens)
-    h, new_caches = stack_paged(params["stack"], x, ctx_len, block_table,
-                                n_valid, state_idx, cfg, rt, caches)
-    h = norm_apply(cfg.norm, params["final_norm"], h)
-    last = jnp.clip(n_valid - 1, 0, tokens.shape[1] - 1)          # (B,)
-    h_last = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
-    logits = jnp.dot(h_last, _head_w(params, cfg).astype(h.dtype))
+    with jax.named_scope("prefill_step"):
+        x = embedding_apply(params["embed"], tokens)
+        h, new_caches = stack_paged(params["stack"], x, ctx_len,
+                                    block_table, n_valid, state_idx, cfg,
+                                    rt, caches)
+        with jax.named_scope("head"):
+            h = norm_apply(cfg.norm, params["final_norm"], h)
+            last = jnp.clip(n_valid - 1, 0, tokens.shape[1] - 1)  # (B,)
+            h_last = jnp.take_along_axis(h, last[:, None, None],
+                                         axis=1)[:, 0]
+            logits = jnp.dot(h_last, _head_w(params, cfg).astype(h.dtype))
     return logits, new_caches
 
 
@@ -372,11 +376,12 @@ def lm_paged_verify(params, tokens, ctx_len, block_table, n_valid,
     stays tiny. Rows with ``n_valid`` < C carry garbage logits past their
     window — the engine only reads positions < n_valid.
     """
-    x = embedding_apply(params["embed"], tokens)
-    h, new_caches = stack_paged(params["stack"], x, ctx_len, block_table,
-                                n_valid, state_idx, cfg, rt, caches)
-    h = norm_apply(cfg.norm, params["final_norm"], h)
-    logits = jnp.dot(h, _head_w(params, cfg).astype(h.dtype))
+    with jax.named_scope("verify_step"):
+        x = embedding_apply(params["embed"], tokens)
+        h, new_caches = stack_paged(params["stack"], x, ctx_len,
+                                    block_table, n_valid, state_idx, cfg,
+                                    rt, caches)
+        logits = _window_head(params, cfg, h)
     return logits, new_caches
 
 
@@ -397,10 +402,17 @@ def lm_paged_fused_step(params, tokens, ctx_len, block_table, n_valid,
     Rows past ``n_valid`` carry garbage logits (the kernel returns zeros
     for them pre-head) — the engine only reads positions < n_valid.
     """
-    x = embedding_apply(params["embed"], tokens)
-    h, new_caches = stack_paged(params["stack"], x, ctx_len, block_table,
-                                n_valid, state_idx, cfg, rt, caches,
-                                fused=True)
-    h = norm_apply(cfg.norm, params["final_norm"], h)
-    logits = jnp.dot(h, _head_w(params, cfg).astype(h.dtype))
+    with jax.named_scope("decode_step"):
+        x = embedding_apply(params["embed"], tokens)
+        h, new_caches = stack_paged(params["stack"], x, ctx_len,
+                                    block_table, n_valid, state_idx, cfg,
+                                    rt, caches, fused=True)
+        logits = _window_head(params, cfg, h)
     return logits, new_caches
+
+
+def _window_head(params, cfg: ArchConfig, h):
+    """Final norm and vocabulary head at every window position."""
+    with jax.named_scope("head"):
+        h = norm_apply(cfg.norm, params["final_norm"], h)
+        return jnp.dot(h, _head_w(params, cfg).astype(h.dtype))

@@ -546,8 +546,10 @@ def attn_paged_step(p: dict, x: jax.Array, ctx_len: jax.Array,
     else:
         q, k = _apply_positional(q, k, positions, rope_theta, None)
     valid = jnp.arange(c)[None, :] < n_valid[:, None]               # (B, C)
-    kp, vp = paged_kv_write(cache["kp"], cache["vp"], k, v, block_table,
-                            positions, valid, kv_scheme=rt.kv_scheme)
+    with jax.named_scope("kv_write"):
+        kp, vp = paged_kv_write(cache["kp"], cache["vp"], k, v,
+                                block_table, positions, valid,
+                                kv_scheme=rt.kv_scheme)
     attend_len = ctx_len + n_valid
     if fused:
         # one megakernel launch for the whole (slot, attend_len) ragged

@@ -160,12 +160,13 @@ def _slot_apply(slot: str, p: dict, x, positions, cfg: ArchConfig,
         ctx_len, block_table, n_valid, state_idx = paged_ctx
     if mixer in ("attn", "xdec"):
         if mode == "paged":
-            y, new_cache = attn_paged_step(
-                p["attn"], h, ctx_len, block_table, cache,
-                n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-                head_dim=cfg.dh, n_valid=n_valid,
-                rope_theta=cfg.rope_theta,
-                mrope_sections=cfg.mrope_sections, rt=rt, fused=fused)
+            with jax.named_scope("attention"):
+                y, new_cache = attn_paged_step(
+                    p["attn"], h, ctx_len, block_table, cache,
+                    n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
+                    head_dim=cfg.dh, n_valid=n_valid,
+                    rope_theta=cfg.rope_theta,
+                    mrope_sections=cfg.mrope_sections, rt=rt, fused=fused)
         elif mode == "decode":
             y, kv = attn_decode_step(
                 p["attn"], h, pos, (cache["k"], cache["v"]),
@@ -301,9 +302,10 @@ def _slot_apply(slot: str, p: dict, x, positions, cfg: ArchConfig,
         x = x + y
 
     if ffn == "dense":
-        h = norm_apply(cfg.norm, p["norm2"], x)
-        x = x + mlp_apply(p["mlp"], h, variant=cfg.mlp_variant, act=cfg.act,
-                          rt=rt)
+        with jax.named_scope("mlp"):
+            h = norm_apply(cfg.norm, p["norm2"], x)
+            x = x + mlp_apply(p["mlp"], h, variant=cfg.mlp_variant,
+                              act=cfg.act, rt=rt)
     elif ffn == "moe":
         h = norm_apply(cfg.norm, p["norm2"], x)
         y, a = moe_apply(p["moe"], h, top_k=cfg.top_k,

@@ -67,6 +67,7 @@ submit order or which other requests share the batch.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import hashlib
 import time
@@ -87,6 +88,7 @@ from repro.serving.config import LEGACY_KNOBS, ServeConfig
 from repro.serving.kv_cache import (StateCache, cross_kv_bytes_per_seq,
                                     kv_bytes_per_token,
                                     ssm_state_bytes_per_seq)
+from repro.serving.spans import span
 from repro.serving.spec import PromptLookupDrafter
 from repro.serving.stream import StreamState, TokenStream
 from repro.sharding import ShardingPolicy
@@ -126,6 +128,8 @@ class Request:
     output: list = dataclasses.field(default_factory=list)
     done: bool = False
     t_enqueue: float = 0.0
+    #: when the scheduler first placed the request in a slot
+    t_admitted: float = 0.0
     t_first_token: float = 0.0
     t_done: float = 0.0
     preemptions: int = 0            # times this request was preempted
@@ -248,13 +252,19 @@ class ServeEngine:
         # terminal at finish/cancel/error; stream() hands out views
         self._streams: dict[int, StreamState] = {}
         self._cancelled = 0
-        self._occ_samples: list[float] = []
+        # per-tick pool (paged) or slot (dense) occupancy
+        self._occ_sum = 0.0
+        self._occ_n = 0
+        self._occ_peak = 0.0
         self._tokens_out = 0
         self._steps = 0
         self._wall = 0.0
-        # jitted forward passes issued (prefill chunks + decode/verify
-        # steps) — the quantity speculation shrinks per emitted token
-        self._model_calls = 0
+        # jitted model programs launched, by program (_launch): their sum
+        # is the model-call count speculation shrinks per emitted token
+        self._launches: collections.Counter = collections.Counter()
+        # prompt tokens, and rows computed, over the prefill launches
+        self._prefill_valid = 0
+        self._prefill_computed = 0
         # speculation counters: per-row windows that carried >= 1 draft
         # (a batched verify call holds one window per drafted slot),
         # and drafts proposed/accepted across them
@@ -485,6 +495,10 @@ class ServeEngine:
                 rid, self.pages_per_seq)
 
     def submit(self, req: Request):
+        with span("serve.submit"):
+            self._enqueue(req)
+
+    def _enqueue(self, req: Request):
         if len(req.prompt) < 1 or req.max_new_tokens < 1:
             raise ValueError(
                 f"request {req.rid}: needs a non-empty prompt and "
@@ -572,21 +586,37 @@ class ServeEngine:
 
     def _tick(self):
         self._steps += 1
-        if self.kv_layout == "paged":
-            self._admit_paged()
-            self._prefill_tick()
-            self._decode_step_paged()
-            self._occ_samples.append(self.pool.stats.occupancy)
-        else:
-            self._admit_dense()
-            self._decode_step_dense()
-            self._occ_samples.append(
-                sum(r is not None for r in self.slot_req)
-                / self.batch_slots)
-        # wake async stream consumers once per tick — every emission of
-        # this tick is already in Request.output by now
-        for st in self._streams.values():
-            st.notify()
+        paged = self.kv_layout == "paged"
+        live = [i for i, r in enumerate(self.slot_req) if r is not None]
+        prefilling = sum(self._fed[i] >= 0 for i in live) if paged else 0
+        with span("serve.tick", queued=len(self.queue),
+                  prefilling=int(prefilling),
+                  decoding=len(live) - int(prefilling)):
+            with span("serve.admit") as s:
+                queued, preempted = len(self.queue), self._preemptions
+                if paged:
+                    self._admit_paged()
+                else:
+                    self._admit_dense()
+                preempted = self._preemptions - preempted
+                s.set_metadata(
+                    admitted=queued + preempted - len(self.queue),
+                    preempted=preempted)
+            if paged:
+                self._prefill_tick()
+                self._decode_step_paged()
+                occ = self.pool.stats.occupancy
+            else:
+                self._decode_step_dense()
+                occ = (sum(r is not None for r in self.slot_req)
+                       / self.batch_slots)
+            self._occ_sum += occ
+            self._occ_n += 1
+            self._occ_peak = max(self._occ_peak, occ)
+            # wake async stream consumers once per tick — every emission
+            # of this tick is already in Request.output by now
+            for st in self._streams.values():
+                st.notify()
 
     def run(self, max_steps: int = 10_000, *, strict: bool = True):
         """Drive until queue + slots drain (or step limit).
@@ -704,11 +734,15 @@ class ServeEngine:
         and cache state are kept). Benchmarks call this between a warmup
         pass — which pays all the jit compiles — and the measured pass."""
         self.finished = []
-        self._occ_samples = []
+        self._occ_sum = 0.0
+        self._occ_n = 0
+        self._occ_peak = 0.0
         self._tokens_out = 0
         self._steps = 0
         self._wall = 0.0
-        self._model_calls = 0
+        self._launches.clear()
+        self._prefill_valid = 0
+        self._prefill_computed = 0
         self._spec_windows = 0
         self._spec_proposed = 0
         self._spec_accepted = 0
@@ -746,6 +780,7 @@ class ServeEngine:
         """Throughput/latency/occupancy counters for the work so far."""
         lat = [r.t_done - r.t_enqueue for r in self.finished]
         ttft = [r.t_first_token - r.t_enqueue for r in self.finished]
+        wait = [r.t_admitted - r.t_enqueue for r in self.finished]
         # bytes follow the layout actually allocated: cache dtype, or the
         # codes+scale quantized layout when rt.kv_quant is set. The
         # decoder pattern holds the serving state, so byte helpers see
@@ -860,7 +895,15 @@ class ServeEngine:
             "requests_cancelled": self._cancelled,
             "tokens_generated": self._tokens_out,
             "engine_steps": self._steps,
-            "model_calls": self._model_calls,
+            "model_calls": sum(self._launches.values()),
+            # launches by program: prefill, decode (fused or the
+            # one-token step), verify (unfused speculation),
+            # dense_prefill, dense_decode, encode (enc-dec cross entries)
+            "launches": dict(self._launches),
+            # prompt tokens over rows computed by the prefill launches:
+            # the share not spent on padding and idle slots
+            "prefill_row_util": self._prefill_valid / self._prefill_computed
+            if self._prefill_computed else 0.0,
             "wall_s": self._wall,
             "tokens_per_s": self._tokens_out / self._wall
             if self._wall else 0.0,
@@ -870,10 +913,14 @@ class ServeEngine:
             "latency_p50_ms": 1e3 * float(np.median(lat)) if lat else 0.0,
             "latency_p95_ms": 1e3 * float(np.percentile(lat, 95))
             if lat else 0.0,
-            "occupancy_mean": float(np.mean(self._occ_samples))
-            if self._occ_samples else 0.0,
-            "occupancy_peak": float(np.max(self._occ_samples))
-            if self._occ_samples else 0.0,
+            # submission -> first placed in a slot
+            "queue_wait_p50_ms": 1e3 * float(np.median(wait))
+            if wait else 0.0,
+            "queue_wait_p95_ms": 1e3 * float(np.percentile(wait, 95))
+            if wait else 0.0,
+            "occupancy_mean": self._occ_sum / self._occ_n
+            if self._occ_n else 0.0,
+            "occupancy_peak": self._occ_peak,
             "peak_kv_bytes": int(peak_kv),
             # the unified bill: token KV + SSM slabs + cross entries —
             # comparable across layouts and architectures
@@ -1008,11 +1055,12 @@ class ServeEngine:
                 self.params,
                 jnp.asarray(req.frames, jnp.float32)[None],
                 self.cfg, self.rt)
-            self._model_calls += 1
+            self._launches["encode"] += 1
             self.caches = self._fill_cross(
                 self.caches, jnp.int32(self.pool.seq_cross(req.rid)),
                 entries)
         self.queue.remove(req)
+        req.t_admitted = _now()
         self.slot_req[slot] = req
         self.slot_pos[slot] = matched
         self._fed[slot] = matched
@@ -1216,6 +1264,47 @@ class ServeEngine:
                 return
         raise KeyError(f"request {rid} is not resident in any slot")
 
+    def _launch(self, kind: str, program: str, call, *, rows: int,
+                tokens: int, **args) -> np.ndarray:
+        """Run one model program and bring its logits to the host.
+        ``call()`` packs and uploads the inputs, calls the jitted step and
+        returns its logits; ``kind`` ("prefill" or "decode") names the
+        spans, ``program`` the launch counter. ``rows``: rows the program
+        computes; ``tokens``: the valid ones among them."""
+        with span(f"serve.{kind}.launch", rows=rows, tokens=tokens, **args):
+            logits = call()
+        self._launches[program] += 1
+        if kind == "prefill":
+            self._prefill_valid += tokens
+            self._prefill_computed += rows
+        with span(f"serve.{kind}.wait", bytes=logits.nbytes):
+            return np.asarray(logits)
+
+    def _paged_launch(self, kind: str, program: str, step, width: int,
+                      feed: dict) -> np.ndarray:
+        """One paged step over a ``(batch_slots, width)`` token block.
+        ``feed``: slot -> (tokens already in its pages, its next tokens);
+        the other rows ride along masked (n_valid = 0)."""
+        def call():
+            tokens = np.zeros((self.batch_slots, width), np.int32)
+            ctx = np.zeros(self.batch_slots, np.int32)
+            n_valid = np.zeros(self.batch_slots, np.int32)
+            for i, (pos, toks) in feed.items():
+                tokens[i, :len(toks)] = toks
+                ctx[i] = pos
+                n_valid[i] = len(toks)
+            logits, self.caches = step(
+                self.params, jnp.asarray(tokens), jnp.asarray(ctx),
+                jnp.asarray(self.block_tables), jnp.asarray(n_valid),
+                jnp.asarray(self._state_idx), self.caches, self.cfg,
+                self.rt)
+            return logits
+        args = {"c_pad": width} if kind == "prefill" else {}
+        return self._launch(kind, program, call,
+                            rows=self.batch_slots * width,
+                            tokens=sum(len(t) for _, t in feed.values()),
+                            **args)
+
     def _prefill_tick(self):
         """Advance every prefilling slot by one prompt chunk in a single
         batched call (per-row ctx_len/n_valid make ragged rows legal —
@@ -1226,152 +1315,123 @@ class ServeEngine:
                 if self.slot_req[i] is not None and self._fed[i] >= 0]
         if not rows:
             return
-        chunk = {i: min(self.prefill_chunk,
-                        len(self.slot_req[i].prompt) - int(self._fed[i]))
-                 for i in rows}
-        c_pad = _pad_pow2(max(chunk.values()), self.prefill_chunk)
-        tokens = np.zeros((self.batch_slots, c_pad), np.int32)
-        ctx = np.zeros(self.batch_slots, np.int32)
-        n_valid = np.zeros(self.batch_slots, np.int32)
-        for i in rows:
-            fed, c = int(self._fed[i]), chunk[i]
-            tokens[i, :c] = self.slot_req[i].prompt[fed:fed + c]
-            ctx[i] = fed
-            n_valid[i] = c
-        logits, self.caches = self._paged_step(
-            self.params, jnp.asarray(tokens), jnp.asarray(ctx),
-            jnp.asarray(self.block_tables), jnp.asarray(n_valid),
-            jnp.asarray(self._state_idx), self.caches, self.cfg, self.rt)
-        self._model_calls += 1
-        logits = np.asarray(logits)
-        for i in rows:
-            req = self.slot_req[i]
-            self._fed[i] += chunk[i]
-            self.slot_pos[i] = self._fed[i]
-            if self.prefix_cache:
-                # index every prompt page this chunk completed — full
-                # prompt pages are immutable from here on, so queued
-                # requests with the same prefix can start sharing them
-                # on the very next admission tick (before _maybe_finish:
-                # a released page stays indexed and revivable)
-                self.pool.register_prefix(
-                    req.rid, req.prompt, int(self._fed[i]),
-                    keys=self._prompt_keys.get(req.rid))
-            if self._fed[i] == len(req.prompt):
-                self._fed[i] = -1           # -> decoding
-                first = self._pick_token(logits[i], req)
-                req.output.append(int(first))
-                self._tokens_out += 1
-                if self.spec_k:
-                    self.drafter.extend(req.rid, int(first))
-                req.t_first_token = _now()
-                self._maybe_finish(i)       # max_new_tokens == 1
+        with span("serve.prefill"):
+            feed = {}
+            for i in rows:
+                fed = int(self._fed[i])
+                feed[i] = (fed, self.slot_req[i].prompt[
+                    fed:fed + self.prefill_chunk])
+            c_pad = _pad_pow2(max(len(t) for _, t in feed.values()),
+                              self.prefill_chunk)
+            logits = self._paged_launch("prefill", "prefill",
+                                        self._paged_step, c_pad, feed)
+            with span("serve.prefill.emit"):
+                for i in rows:
+                    self._after_chunk(i, len(feed[i][1]), logits[i])
+
+    def _after_chunk(self, slot: int, n: int, logits: np.ndarray):
+        """Bookkeeping after ``n`` more prompt tokens of ``slot`` went
+        through the model; the last chunk picks the first token."""
+        req = self.slot_req[slot]
+        self._fed[slot] += n
+        self.slot_pos[slot] = self._fed[slot]
+        if self.prefix_cache:
+            # index every prompt page this chunk completed — full prompt
+            # pages are immutable from here on, so queued requests with
+            # the same prefix can start sharing them on the very next
+            # admission tick (before _maybe_finish: a released page stays
+            # indexed and revivable)
+            self.pool.register_prefix(
+                req.rid, req.prompt, int(self._fed[slot]),
+                keys=self._prompt_keys.get(req.rid))
+        if self._fed[slot] == len(req.prompt):
+            self._fed[slot] = -1            # -> decoding
+            first = self._pick_token(logits, req)
+            req.output.append(int(first))
+            self._tokens_out += 1
+            if self.spec_k:
+                self.drafter.extend(req.rid, int(first))
+            req.t_first_token = _now()
+            self._maybe_finish(slot)        # max_new_tokens == 1
 
     def _decode_step_paged(self):
+        """One decode tick for every decoding slot, in one launch over a
+        fixed window W per row: the pending token plus this slot's drafts
+        (``n_valid`` carries the ragged part). Three programs serve it:
+
+        * fused (``lm_paged_fused_step``): every layer's attention is one
+          ``paged_decode_ragged`` launch; W = spec_k + 1, or 1 without
+          speculation, so drafted and undrafted ticks share one compile;
+        * unfused with drafts (``lm_paged_verify``): W = spec_k + 1;
+        * unfused without drafts (``lm_paged_step``, the C == 1 decode
+          kernel): W = 1 — no K+1 window is paid for zero drafts.
+
+        Each row keeps its longest accepted prefix plus a bonus or
+        correction token (``_accept_tokens``; with no drafts that IS the
+        plain greedy/sampled pick, same key chain), so greedy outputs are
+        bit-identical across the three — regression-tested. Rollback is
+        cursor arithmetic: ``slot_pos`` advances only past accepted
+        tokens; a rejected tail's page slots are overwritten by the next
+        window at those positions and never attended (``attend_len``
+        masks them)."""
         active = [i for i, r in enumerate(self.slot_req)
                   if r is not None and self._fed[i] < 0]
         if not active:
             return
-        if self.fused_decode:
-            self._decode_step_fused(active)
-            return
-        if self.spec_k:
-            drafts = {}
-            for i in active:
-                req = self.slot_req[i]
-                room = self._draft_room(req, int(self.slot_pos[i]))
-                drafts[i] = (self.drafter.propose(req.rid,
-                                                  min(self.spec_k, room))
-                             if room > 0 else [])
-            if any(drafts.values()):
-                self._verify_step(active, drafts)
-                return
-            # every tail was novel: degrade to the plain one-token step
-            # below (the C==1 decode kernel) instead of paying the K+1
-            # verify window for zero drafts
-        tokens = np.zeros((self.batch_slots, 1), np.int32)
-        n_valid = np.zeros(self.batch_slots, np.int32)
-        ctx = np.zeros(self.batch_slots, np.int32)
-        for i in active:
-            tokens[i, 0] = self.slot_req[i].output[-1]
-            n_valid[i] = 1
-            ctx[i] = self.slot_pos[i]
-        logits, self.caches = self._paged_step(
-            self.params, jnp.asarray(tokens), jnp.asarray(ctx),
-            jnp.asarray(self.block_tables), jnp.asarray(n_valid),
-            jnp.asarray(self._state_idx), self.caches, self.cfg, self.rt)
-        self._model_calls += 1
-        logits = np.asarray(logits)
-        for i in active:
-            req = self.slot_req[i]
-            tok = self._pick_token(logits[i], req)
+        with span("serve.decode"):
+            drafts = {i: self._propose(i) for i in active}
+            w = (self.spec_k + 1) if self.spec_k else 1
+            if self.fused_decode:
+                step, program = self._fused_step, "decode"
+            elif any(drafts.values()):
+                step, program = self._paged_verify, "verify"
+            else:
+                step, program, w = self._paged_step, "decode", 1
+            feed = {i: (int(self.slot_pos[i]),
+                        [self.slot_req[i].output[-1]] + drafts[i])
+                    for i in active}
+            logits = self._paged_launch("decode", program, step, w, feed)
+            if logits.ndim == 2:
+                logits = logits[:, None]    # lm_paged_step: (B, V)
+            with span("serve.decode.emit"):
+                for i in active:
+                    self._emit_window(i, drafts[i], logits[i])
+
+    def _emit_window(self, slot: int, drafts: list[int],
+                     logits: np.ndarray):
+        """Emit what one verified window (``logits``: (W, V)) accepts
+        and move the slot's write cursor past it."""
+        req = self.slot_req[slot]
+        emitted = self._accept_tokens(req, drafts, logits)
+        accepted = len(emitted) - 1                  # drafts kept
+        for tok in emitted:
             req.output.append(int(tok))
             if self.spec_k:
                 # keep the n-gram history == prompt + output even on
-                # degraded (no-draft) ticks, or later proposals would
-                # continue from a stale tail
+                # undrafted ticks, or later proposals would continue
+                # from a stale tail
                 self.drafter.extend(req.rid, int(tok))
-            self._tokens_out += 1
-            self.slot_pos[i] += 1
-            self._maybe_finish(i)
-
-    def _decode_step_fused(self, active):
-        """One megakernel decode tick for every decoding slot: plain
-        decode and draft-verify collapse onto a single
-        ``lm_paged_fused_step`` call over a fixed window W (spec_k + 1,
-        or 1 without speculation) — per-row ``n_valid`` carries the
-        ragged part (1 + drafts for this slot), so drafted and undrafted
-        rows share the launch instead of forking into separate
-        ``_paged_step`` / ``_paged_verify`` compiles. Acceptance,
-        rollback and drafter bookkeeping are identical to the unfused
-        path (``_accept_tokens`` with an empty draft list IS the plain
-        greedy/sampled pick, same key chain), so greedy outputs are
-        bit-identical fused vs unfused — regression-tested."""
-        w = (self.spec_k + 1) if self.spec_k else 1
-        drafts: dict[int, list[int]] = {i: [] for i in active}
-        if self.spec_k:
-            for i in active:
-                req = self.slot_req[i]
-                room = self._draft_room(req, int(self.slot_pos[i]))
-                if room > 0:
-                    drafts[i] = self.drafter.propose(req.rid,
-                                                     min(self.spec_k, room))
-        tokens = np.zeros((self.batch_slots, w), np.int32)
-        n_valid = np.zeros(self.batch_slots, np.int32)
-        ctx = np.zeros(self.batch_slots, np.int32)
-        for i in active:
-            req = self.slot_req[i]
-            d = drafts[i]
-            tokens[i, 0] = req.output[-1]
-            if d:
-                tokens[i, 1:1 + len(d)] = d
-            n_valid[i] = 1 + len(d)
-            ctx[i] = self.slot_pos[i]
-        logits, self.caches = self._fused_step(
-            self.params, jnp.asarray(tokens), jnp.asarray(ctx),
-            jnp.asarray(self.block_tables), jnp.asarray(n_valid),
-            jnp.asarray(self._state_idx), self.caches, self.cfg, self.rt)
-        self._model_calls += 1
-        logits = np.asarray(logits)                  # (B, W, V)
-        for i in active:
-            req = self.slot_req[i]
-            emitted = self._accept_tokens(req, drafts[i], logits[i])
-            accepted = len(emitted) - 1              # drafts kept
-            for tok in emitted:
-                req.output.append(int(tok))
-                if self.spec_k:
-                    self.drafter.extend(req.rid, int(tok))
-            self._tokens_out += len(emitted)
-            if drafts[i]:
-                self._spec_windows += 1
-            self._spec_proposed += len(drafts[i])
-            self._spec_accepted += accepted
-            # KV rollback: pending token + accepted drafts stay; the
-            # write cursor retreats past any rejected tail
-            self.slot_pos[i] = int(ctx[i]) + 1 + accepted
-            self._maybe_finish(i)
+        self._tokens_out += len(emitted)
+        if drafts:
+            self._spec_windows += 1
+        self._spec_proposed += len(drafts)
+        self._spec_accepted += accepted
+        # KV rollback: pending token + accepted drafts stay; the write
+        # cursor retreats past any rejected tail
+        self.slot_pos[slot] += 1 + accepted
+        self._maybe_finish(slot)
 
     # -- speculative decoding (serving/spec.py has the drafter) --------------
+
+    def _propose(self, slot: int) -> list[int]:
+        """The slot's draft tokens this tick (none without speculation or
+        without room)."""
+        if not self.spec_k:
+            return []
+        req = self.slot_req[slot]
+        room = self._draft_room(req, int(self.slot_pos[slot]))
+        return (self.drafter.propose(req.rid, min(self.spec_k, room))
+                if room > 0 else [])
 
     def _draft_room(self, req: Request, pos: int) -> int:
         """Max draft tokens this window may carry. Two caps: the window
@@ -1381,50 +1441,6 @@ class ServeEngine:
         covers every write)."""
         return min(req.max_new_tokens - len(req.output),
                    self.max_seq - 1 - pos) - 1
-
-    def _verify_step(self, active, drafts: dict[int, list[int]]):
-        """Draft-and-verify decode tick: at least one decoding slot has
-        ``drafts`` (rows with none ride along as 1-valid plain decodes),
-        one batched ``lm_paged_verify`` scores all windows, and each row
-        keeps its longest accepted prefix plus a bonus or correction
-        token. Rollback is cursor arithmetic: ``slot_pos`` advances only
-        past accepted tokens; the rejected tail's page slots are
-        overwritten by the next window at those positions and are never
-        attended (``attend_len`` masks them)."""
-        w = self.spec_k + 1
-        tokens = np.zeros((self.batch_slots, w), np.int32)
-        n_valid = np.zeros(self.batch_slots, np.int32)
-        ctx = np.zeros(self.batch_slots, np.int32)
-        for i in active:
-            req = self.slot_req[i]
-            d = drafts[i]
-            tokens[i, 0] = req.output[-1]
-            if d:
-                tokens[i, 1:1 + len(d)] = d
-            n_valid[i] = 1 + len(d)
-            ctx[i] = self.slot_pos[i]
-        logits, self.caches = self._paged_verify(
-            self.params, jnp.asarray(tokens), jnp.asarray(ctx),
-            jnp.asarray(self.block_tables), jnp.asarray(n_valid),
-            jnp.asarray(self._state_idx), self.caches, self.cfg, self.rt)
-        self._model_calls += 1
-        logits = np.asarray(logits)                  # (B, W, V)
-        for i in active:
-            req = self.slot_req[i]
-            emitted = self._accept_tokens(req, drafts[i], logits[i])
-            accepted = len(emitted) - 1              # drafts kept
-            for tok in emitted:
-                req.output.append(int(tok))
-                self.drafter.extend(req.rid, int(tok))
-            self._tokens_out += len(emitted)
-            if drafts[i]:
-                self._spec_windows += 1
-            self._spec_proposed += len(drafts[i])
-            self._spec_accepted += accepted
-            # KV rollback: pending token + accepted drafts stay; the
-            # write cursor retreats past the rejected tail
-            self.slot_pos[i] = int(ctx[i]) + 1 + accepted
-            self._maybe_finish(i)
 
     def _accept_tokens(self, req: Request, drafts: list[int],
                        logits: np.ndarray) -> list[int]:
@@ -1502,57 +1518,70 @@ class ServeEngine:
         for slot in range(self.batch_slots):
             if self.slot_req[slot] is None and self.queue:
                 req = self.queue.pop(0)
+                req.t_admitted = _now()
                 self.slot_req[slot] = req
-                # prefill this slot: run prompt through a single-row batch,
-                # then splice its caches into the engine batch at `slot`
-                tok = jnp.asarray(req.prompt, jnp.int32)[None, :]
-                if self.cfg.enc_dec:
-                    row_caches = encdec_mod.encdec_init_caches(
-                        self.cfg, 1, self.max_seq,
-                        dtype=self.kv_cache_dtype,
-                        kv_quant=self.rt.kv_quant)
-                    frames = jnp.asarray(req.frames, jnp.float32)[None]
-                    logits, row_caches = self._prefill_one(
-                        self.params, frames, tok, row_caches, self.cfg,
-                        self.rt)
-                else:
-                    row_caches = lm_mod.init_caches(
-                        self.cfg, 1, self.max_seq,
-                        dtype=self.kv_cache_dtype,
-                        kv_quant=self.rt.kv_quant)
-                    logits, row_caches = self._prefill_one(
-                        self.params, tok, row_caches, self.cfg, self.rt)
-                self._model_calls += 1
-                self.caches = _splice_caches(self.caches, row_caches, slot)
-                self.slot_pos[slot] = len(req.prompt)
-                first = self._pick_token(logits[0], req)
-                req.output.append(int(first))
-                self._tokens_out += 1
-                req.t_first_token = _now()
-                self._maybe_finish(slot)    # max_new_tokens == 1
+                with span("serve.prefill"):
+                    n = len(req.prompt)
+                    logits = self._launch(
+                        "prefill", "dense_prefill",
+                        lambda: self._prefill_slot(slot, req),
+                        rows=n, tokens=n, c_pad=n)
+                    with span("serve.prefill.emit"):
+                        self.slot_pos[slot] = n
+                        first = self._pick_token(logits[0], req)
+                        req.output.append(int(first))
+                        self._tokens_out += 1
+                        req.t_first_token = _now()
+                        self._maybe_finish(slot)    # max_new_tokens == 1
+
+    def _prefill_slot(self, slot: int, req: Request):
+        """Prefill ``slot`` with the request's prompt: run it through a
+        single-row batch, splice the row's caches into the engine batch
+        at ``slot``, return the last position's logits."""
+        tok = jnp.asarray(req.prompt, jnp.int32)[None, :]
+        if self.cfg.enc_dec:
+            row_caches = encdec_mod.encdec_init_caches(
+                self.cfg, 1, self.max_seq, dtype=self.kv_cache_dtype,
+                kv_quant=self.rt.kv_quant)
+            frames = jnp.asarray(req.frames, jnp.float32)[None]
+            logits, row_caches = self._prefill_one(
+                self.params, frames, tok, row_caches, self.cfg, self.rt)
+        else:
+            row_caches = lm_mod.init_caches(
+                self.cfg, 1, self.max_seq, dtype=self.kv_cache_dtype,
+                kv_quant=self.rt.kv_quant)
+            logits, row_caches = self._prefill_one(
+                self.params, tok, row_caches, self.cfg, self.rt)
+        self.caches = _splice_caches(self.caches, row_caches, slot)
+        return logits
 
     def _decode_step_dense(self):
         active = [i for i, r in enumerate(self.slot_req) if r is not None]
         if not active:
             return
-        tokens = np.zeros(self.batch_slots, np.int32)
-        for i in active:
-            tokens[i] = self.slot_req[i].output[-1]
-        # continuous batching: each slot decodes at its own position
-        pos = jnp.asarray(self.slot_pos, jnp.int32)
-        logits, self.caches = self._decode(self.params,
-                                           jnp.asarray(tokens),
-                                           pos, self.caches, self.cfg,
-                                           self.rt)
-        self._model_calls += 1
-        logits = np.asarray(logits)
-        for i in active:
-            req = self.slot_req[i]
-            tok = self._pick_token(logits[i], req)
-            req.output.append(int(tok))
-            self._tokens_out += 1
-            self.slot_pos[i] += 1
-            self._maybe_finish(i)
+        with span("serve.decode"):
+            def call():
+                tokens = np.zeros(self.batch_slots, np.int32)
+                for i in active:
+                    tokens[i] = self.slot_req[i].output[-1]
+                # continuous batching: each slot decodes at its own
+                # position
+                logits, self.caches = self._decode(
+                    self.params, jnp.asarray(tokens),
+                    jnp.asarray(self.slot_pos, jnp.int32), self.caches,
+                    self.cfg, self.rt)
+                return logits
+            logits = self._launch("decode", "dense_decode", call,
+                                  rows=self.batch_slots,
+                                  tokens=len(active))
+            with span("serve.decode.emit"):
+                for i in active:
+                    req = self.slot_req[i]
+                    tok = self._pick_token(logits[i], req)
+                    req.output.append(int(tok))
+                    self._tokens_out += 1
+                    self.slot_pos[i] += 1
+                    self._maybe_finish(i)
 
     # -- shared --------------------------------------------------------------
 

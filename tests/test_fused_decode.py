@@ -161,28 +161,85 @@ def test_fused_step_single_trace_across_ragged_ticks():
     assert calls.get("paged_attention_quant") is None
 
 
+def _pallas_kernels(jaxpr) -> list[str]:
+    """Source names of the Pallas kernels a jaxpr launches, nested
+    jaxprs (the layer scan's body) included."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            src = eqn.params["jaxpr"].debug_info.func_src_info
+            out.append(src.split()[0])
+            continue
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    out += _pallas_kernels(sub)
+    return out
+
+
 def test_engine_fused_tick_is_one_compile_one_launch():
+    """Whatever earlier tests traced in this process: the engine's fused
+    step holds one ragged kernel launch, the engine launches it once per
+    decode tick, and ticks after a warm one neither trace nor compile
+    it again, though attend_len and n_valid vary from tick to tick."""
     cfg = _tiny_cfg()
     params = lm_mod.lm_init(jax.random.PRNGKey(3), cfg)
     eng = ServeEngine(params, cfg,
                       ServeConfig(batch_slots=2, max_seq=64, quantize=None,
                                   kv_layout="paged", fused_decode=True),
                       rt=RT)
+    # the step's program, traced with the Pallas kernels in place of the
+    # jnp oracles: one kernel, the ragged decode, and no legacy per-call
+    # paged attention (the layer scan traces its body once)
+    b = eng.batch_slots
+    traced = jax.jit(lm_mod.lm_paged_fused_step, static_argnums=(7, 8)).trace(
+        eng.params, jnp.zeros((b, 1), jnp.int32), jnp.zeros(b, jnp.int32),
+        jnp.asarray(eng.block_tables), jnp.ones(b, jnp.int32),
+        jnp.asarray(eng._state_idx), eng.caches, cfg,
+        dataclasses.replace(RT, impl="interpret"))
+    assert _pallas_kernels(traced.jaxpr.jaxpr) == ["_ragged_kernel"]
+
     rng = np.random.default_rng(3)
-    for i in range(4):
-        p = rng.integers(0, cfg.vocab_size,
-                         int(rng.integers(4, 24))).astype(np.int32)
-        eng.submit(Request(rid=i, prompt=p, max_new_tokens=6))
-    ops.reset_op_calls()
-    eng.run()
-    # ragged prompt lengths + continuous batching varied attend_len and
-    # n_valid across every tick; still one compiled fused step ...
-    assert eng._fused_step._cache_size() == 1
-    # ... whose single trace carried the tick's single ragged launch
-    assert ops.op_calls().get("paged_decode_ragged") == 1
+    reqs = [Request(rid=i, prompt=rng.integers(
+        0, cfg.vocab_size, int(rng.integers(4, 24))).astype(np.int32),
+        max_new_tokens=6) for i in range(4)]
+    for r in reqs:
+        eng.submit(r)
+    eng.step()                                  # warm: prefill + decode
+    assert eng.metrics()["launches"] == {"prefill": 1, "decode": 1}
+    events = []
+
+    def on_event(event, duration, **kw):
+        if "lm_paged_fused_step" in str(kw.get("fun_name", "")):
+            events.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    decode_ticks = 1
+    try:
+        # ragged prompt lengths + continuous batching vary attend_len and
+        # n_valid across ticks; a tick decodes where it emits more tokens
+        # than first tokens, and then launches the fused step once
+        while eng.has_work():
+            before = [len(r.output) for r in reqs]
+            launched = eng.metrics()["launches"]["decode"]
+            eng.step()
+            firsts = sum(1 for r, n in zip(reqs, before)
+                         if n == 0 and r.output)
+            decoded = sum(len(r.output) for r in reqs) - sum(before) \
+                - firsts
+            launched = eng.metrics()["launches"]["decode"] - launched
+            assert launched == (1 if decoded else 0)
+            decode_ticks += launched
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+    # ... and nothing traced or compiled the fused step after the warm
+    # tick
+    assert events == []
     m = eng.metrics()
     assert m["fused_decode"] is True
-    assert m["model_calls"] >= 1
+    assert m["launches"]["decode"] == decode_ticks > 2
+    assert m["model_calls"] == sum(m["launches"].values())
 
 
 # ---------------------------------------------------------------------------

@@ -427,6 +427,7 @@ def _mini_engine(**kw):
 def _fake_request(rid, enq, ttft_s, lat_s):
     r = Request(rid=rid, prompt=np.zeros(2, np.int32), max_new_tokens=4)
     r.t_enqueue = enq
+    r.t_admitted = enq + ttft_s / 2
     r.t_first_token = enq + ttft_s
     r.t_done = enq + lat_s
     r.done = True
@@ -450,6 +451,10 @@ def test_metrics_math_synthetic_timestamps():
     assert m["ttft_p95_ms"] == pytest.approx(38.5)
     assert m["latency_p50_ms"] == pytest.approx(250.0)
     assert m["latency_p95_ms"] == pytest.approx(385.0)
+    # queue waits of [5, 10, 15, 20] ms (admitted halfway to the first
+    # token)
+    assert m["queue_wait_p50_ms"] == pytest.approx(12.5)
+    assert m["queue_wait_p95_ms"] == pytest.approx(19.25)
 
 
 def test_metrics_single_sample_p95_equals_the_sample():
@@ -464,7 +469,9 @@ def test_reset_metrics_clears_counters_and_prefix_stats():
     eng = _mini_engine(kv_layout="paged", page_size=8, prefix_cache=True)
     eng.finished = [_fake_request(0, 1.0, 0.001, 0.002)]
     eng._tokens_out, eng._wall, eng._steps = 10, 1.0, 3
-    eng._occ_samples = [0.5]
+    eng._occ_sum, eng._occ_n, eng._occ_peak = 0.5, 1, 0.5
+    eng._launches.update(prefill=2, decode=3)
+    eng._prefill_valid, eng._prefill_computed = 5, 8
     eng._prefix_hits, eng._prefill_skipped, eng._cow_copies = 3, 42, 2
     eng.pool.stats.admission_denials = 5
     eng.reset_metrics()
@@ -474,6 +481,8 @@ def test_reset_metrics_clears_counters_and_prefix_stats():
     assert m["ttft_p50_ms"] == m["ttft_p95_ms"] == 0.0
     assert m["latency_p50_ms"] == m["latency_p95_ms"] == 0.0
     assert m["occupancy_mean"] == m["occupancy_peak"] == 0.0
+    assert m["launches"] == {} and m["model_calls"] == 0
+    assert m["prefill_row_util"] == 0.0
     assert m["prefix_hits"] == 0 and m["prefill_tokens_skipped"] == 0
     assert m["cow_copies"] == 0 and m["admission_denials"] == 0
     assert m["prefix_cache"] is True

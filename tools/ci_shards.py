@@ -50,6 +50,7 @@ SHARDS: dict[str, list[str]] = {
         "tests/test_prefix_cache.py",
         "tests/test_scheduler.py",
         "tests/test_serving.py",
+        "tests/test_spans.py",
         "tests/test_spec_decode.py",
         "tests/test_state_cache.py",
         "tests/test_streaming.py",
