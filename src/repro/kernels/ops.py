@@ -25,6 +25,7 @@ from repro.core.quantized import QuantizedTensor
 from repro.runtime import planner, registry
 
 from . import ref as ref_impl
+from .decode import LOOKUPS
 from .flash_attention import flash_attention_pallas
 from .paged_attention import (paged_attention_pallas,
                               paged_attention_quant_pallas,
@@ -34,7 +35,7 @@ from .spx_matmul import spx_matmul_pallas
 
 __all__ = ["spx_matmul", "flash_attention", "paged_attention",
            "paged_attention_quant", "paged_decode_ragged", "resolve_impl",
-           "op_calls", "op_impls", "reset_op_calls"]
+           "op_calls", "op_impls", "lut_lookups", "reset_op_calls"]
 
 
 def _on_tpu() -> bool:
@@ -68,9 +69,17 @@ def op_impls() -> dict[tuple[str, str], int]:
     return dict(_OP_IMPLS)
 
 
+def lut_lookups() -> dict[tuple[str, str], int]:
+    """Kernel builds per (kernel, codebook lookup it compiled with:
+    "gather" or "select") since the last ``reset_op_calls()``; counted
+    at trace time like ``op_impls``, so a cached trace adds nothing."""
+    return dict(LOOKUPS)
+
+
 def reset_op_calls() -> None:
     _OP_CALLS.clear()
     _OP_IMPLS.clear()
+    LOOKUPS.clear()
 
 
 def _whole_per_device(fn, mesh):

@@ -30,7 +30,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from .decode import lut_decode, lut_spec
+from .decode import lut_decode, lut_operand
 
 __all__ = ["paged_attention_pallas", "paged_attention_quant_pallas",
            "paged_decode_ragged_pallas", "paged_decode_ragged_quant_pallas"]
@@ -113,7 +113,7 @@ def _quant_kernel(bt_ref, ctx_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
     a per-token f32 scale; the LUT decode (VPU) happens page-by-page in
     VMEM, so HBM only ever moves 1-byte codes — the §3.2 memory win
     applied to the decode hot path. The codebook (<=256 f32 entries) is
-    resident in SMEM for the whole grid."""
+    resident for the whole grid, where ``decode.lut_operand`` put it."""
     del bt_ref
     b = pl.program_id(0)
     j = pl.program_id(2)
@@ -211,7 +211,7 @@ def _ragged_quant_kernel(bt_ref, ctx_ref, qn_ref, q_ref, kc_ref, ks_ref,
                          acc_ref, *, scale: float, page_size: int, w: int,
                          n_logical: int, out_dtype):
     """Fused-LUT ragged megakernel: K/V pages stream as uint8 codes +
-    per-token scale, the <=256-entry codebook sits in SMEM for the whole
+    per-token scale, the <=256-entry codebook stays resident for the whole
     grid, and dequantization happens page-by-page right before the MXU —
     the §3.2 memory win on the one launch the decode tick makes."""
     del bt_ref
@@ -313,6 +313,7 @@ def paged_decode_ragged_quant_pallas(q, k_codes, k_scale, v_codes, v_scale,
         return pl.BlockSpec(
             (1, 1, page_size, width),
             lambda bb, h, j, bt, ctx, qn: (bt[bb, j], h, 0, 0))
+    lut, lut_spec = lut_operand(lut, dh, "paged_decode_ragged_quant")
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,             # block_table, ctx_len, q_len
@@ -324,7 +325,7 @@ def paged_decode_ragged_quant_pallas(q, k_codes, k_scale, v_codes, v_scale,
             page_spec(1),                  # k scale
             page_spec(dh),                 # v codes
             page_spec(1),                  # v scale
-            lut_spec(),                    # whole LUT, SMEM-resident
+            lut_spec,                      # whole LUT, resident
         ],
         out_specs=pl.BlockSpec((1, 1, rows, dh),
                                lambda bb, h, j, bt, ctx, qn: (bb, h, 0, 0)),
@@ -415,6 +416,7 @@ def paged_attention_quant_pallas(q, k_codes, k_scale, v_codes, v_scale,
     def page_spec(width):
         return pl.BlockSpec((1, 1, page_size, width),
                             lambda bb, h, j, bt, ctx: (bt[bb, j], h, 0, 0))
+    lut, lut_spec = lut_operand(lut, dh, "paged_attention_quant")
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,             # block_table, ctx_len
@@ -426,7 +428,7 @@ def paged_attention_quant_pallas(q, k_codes, k_scale, v_codes, v_scale,
             page_spec(1),                  # k scale
             page_spec(dh),                 # v codes
             page_spec(1),                  # v scale
-            lut_spec(),                    # whole LUT, SMEM-resident
+            lut_spec,                      # whole LUT, resident
         ],
         out_specs=pl.BlockSpec((1, 1, rep, dh),
                                lambda bb, h, j, bt, ctx: (bb, h, 0, 0)),

@@ -9,9 +9,10 @@ This is the paper's accelerator, re-thought for the TPU memory hierarchy:
   PU pipeline (clk_compute)    MXU consuming the current VMEM tile while the
                                next tile's DMA is in flight
   row-of-weights per clock     (bk x bn) weight-code tile per grid step
-  shift-add of PoT terms       b-bit code -> level via a select chain over
-                               the SMEM codebook (VPU), then a dense MXU
-                               matmul
+  shift-add of PoT terms       b-bit code -> level by a lane gather from
+                               the VMEM codebook (8-bit codes) or a select
+                               chain over it in SMEM (int4), on the VPU,
+                               then a dense MXU matmul
   temporary `array t`          f32 accumulator tile in VMEM scratch
 
 The load/compute decoupling argument of §3.1 (loading must stay ahead of
@@ -35,7 +36,7 @@ import jax.experimental.pallas.tpu as pltpu
 
 from repro.core.spx import int4_group
 
-from .decode import lut_decode, lut_spec, unpack_int4_tile
+from .decode import lut_decode, lut_operand, unpack_int4_tile
 
 __all__ = ["spx_matmul_pallas"]
 
@@ -55,7 +56,7 @@ def _kernel(x_ref, codes_ref, scale_ref, lut_ref, o_ref, acc_ref, *,
         codes = codes_ref[...].astype(jnp.int32)
     else:
         codes = unpack_int4_tile(codes_ref[...], group)
-    # LUT decode on the VPU against the SMEM-resident codebook
+    # LUT decode on the VPU against the resident codebook
     w = lut_decode(codes, lut_ref)
     x = x_ref[...]
     acc_ref[...] += jax.lax.dot_general(
@@ -95,6 +96,7 @@ def spx_matmul_pallas(x, codes, scale, lut, *, packed: bool,
     assert group is None or bn % (2 * group) == 0, (n, bn, group)
 
     grid = (m // bm, n // bn, n_k)
+    lut, lut_spec = lut_operand(lut, bn, "spx_matmul")
     return pl.pallas_call(
         functools.partial(_kernel, group=group, n_k=n_k,
                           out_dtype=out_dtype),
@@ -103,7 +105,7 @@ def spx_matmul_pallas(x, codes, scale, lut, *, packed: bool,
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn // pack_div), lambda i, j, kk: (kk, j)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
-            lut_spec(),
+            lut_spec,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),

@@ -505,11 +505,12 @@ class FusedDecodePlan:
     whole (rep * w, dh) query window + f32 accumulator/stats resident,
     double-buffers one K+V page pair (codes + per-token scale for a
     quantized pool), and — quantized pools only — keeps the <=256-entry
-    codebook LUT pinned in SMEM for the entire launch and decodes each
-    page in VMEM (int32 codes, then f32 levels, for K and V).
+    codebook LUT resident for the entire launch (``kernels/decode.py``:
+    VMEM rows for the lane gather, SMEM for the select chain) and decodes
+    each page in VMEM (int32 codes, then f32 levels, for K and V).
 
     rows         rep * w query rows per grid step (w = spec K+1, or 1)
-    lut_bytes    SMEM-resident codebook bytes (0 for a dense pool)
+    lut_bytes    resident codebook bytes (0 for a dense pool)
     pipelined    §3.1 condition for the page loop at this window size
     margin       compute/load ratio for one (K, V) page pair
     vmem_bytes   total per-step working set in bytes
@@ -550,8 +551,8 @@ def plan_fused_decode(dh: int, *, rep: int = 1, w: int = 1,
     Hkv``; ``w`` is the static decode window (spec K+1, or 1 for plain
     decode); ``act_bytes`` the query/cache element width. ``kv_scheme``
     switches the streamed-page byte model to the quantized codes+scale
-    layout, counts the scheme's codebook LUT (SMEM-resident for the whole
-    launch, prefetched once) and charges the per-page decode to VMEM.
+    layout, counts the scheme's codebook LUT (resident for the whole
+    launch, fetched once) and charges the per-page decode to VMEM.
 
     Always returns a plan — page geometry was already fixed by
     ``plan_kv_pages`` at pool allocation, so there is no candidate search

@@ -114,10 +114,27 @@ def _paged_args(one_chip, w, quant):
 @pytest.mark.parametrize("quant", [False, True], ids=["f32", "spx_8_x3"])
 def test_paged_decode_ragged_compiles(one_chip, w, quant):
     kv_scheme, args = _paged_args(one_chip, w, quant)
+    ops.reset_op_calls()
     compiled = _compile(
         lambda *a: ops.paged_decode_ragged(*a, kv_scheme=kv_scheme,
                                            impl="pallas"), *args)
     assert "tpu_custom_call" in compiled.as_text()
+    # SPx pages decode by the lane gather (dh = 128)
+    assert ops.lut_lookups() == (
+        {("paged_decode_ragged_quant", "gather"): 1} if quant else {})
+
+
+def test_paged_attention_quant_compiles(one_chip):
+    """The unragged quantized decode kernel, at the ragged one's pages."""
+    kv_scheme, (_, pool, _, bt, lens, _) = _paged_args(one_chip, 1, True)
+    q = _sds((SLOTS, HQ, DH), jnp.float32, one_chip)
+    ops.reset_op_calls()
+    compiled = _compile(
+        lambda q, kp, vp, bt, ctx: ops.paged_attention_quant(
+            q, kp, vp, bt, ctx, kv_scheme=kv_scheme, impl="pallas"),
+        q, pool, pool, bt, lens)
+    assert "tpu_custom_call" in compiled.as_text()
+    assert ops.lut_lookups() == {("paged_attention_quant", "gather"): 1}
 
 
 @pytest.mark.parametrize("seq", [256, 1024])

@@ -61,10 +61,13 @@ def _pools(rng, b, hkv, ps, max_pages, dh, scheme):
     return kq, vq, bt
 
 
+# dh=128 tiles take the lane-gather lookup for 8-bit codes, dh=32 the
+# select chain (kernels/decode.py)
+@pytest.mark.parametrize("dh", [32, 128])
 @pytest.mark.parametrize("scheme", SCHEMES)
-def test_ragged_interpret_matches_ref(scheme):
+def test_ragged_interpret_matches_ref(scheme, dh):
     rng = np.random.default_rng(11)
-    b, hq, hkv, dh, ps, mp, w = 4, 4, 2, 32, 8, 4, 4   # verify window K+1=4
+    b, hq, hkv, ps, mp, w = 4, 4, 2, 8, 4, 4   # verify window K+1=4
     kp, vp, bt = _pools(rng, b, hkv, ps, mp, dh, scheme)
     q = jnp.asarray(rng.standard_normal((b, w, hq, dh)), jnp.float32)
     # ragged surface: q_len 0 (inactive) .. w (full window); ctx at and

@@ -4,10 +4,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from repro.core import spx
 from repro.core.quantized import quantize_weight
 from repro.kernels import ops, ref
+from repro.kernels.decode import lut_decode, lut_operand
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.spx_matmul import spx_matmul_pallas
 
@@ -22,6 +24,35 @@ def _mk(shape, dtype, seed, scale=1.0):
 # ---------------------------------------------------------------------------
 # spx_matmul
 # ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("width", [32, 256])
+@pytest.mark.parametrize("scheme", ["sp2_4", "uniform8", "sp2_8",
+                                    "spx_8_x3"])
+def test_lut_decode_every_code_bit_identical(scheme, width):
+    """Every code of the codebook, at shuffled lanes, decodes in a Pallas
+    body to exactly ``lut[code]``: 8-bit tables on 128-lane-multiple
+    tiles take the lane gather, the rest the select chain."""
+    lut = spx.codebook(spx.scheme_levels(scheme))
+    n = lut.shape[0]
+    codes = np.random.default_rng(n + width).permutation(
+        np.resize(np.arange(n, dtype=np.int32), 8 * width)).reshape(8, width)
+    assert set(codes.ravel()) == set(range(n))
+    ops.reset_op_calls()
+    table, spec = lut_operand(lut, width, "lookup")
+
+    def kernel(codes_ref, lut_ref, o_ref):
+        o_ref[...] = lut_decode(codes_ref[...], lut_ref)
+
+    tile = pl.BlockSpec((8, width), lambda: (0, 0))
+    got = pl.pallas_call(
+        kernel, in_specs=[tile, spec], out_specs=tile,
+        out_shape=jax.ShapeDtypeStruct((8, width), jnp.float32),
+        interpret=True)(jnp.asarray(codes), table)
+    want = np.asarray(lut)[codes]
+    assert np.asarray(got).tobytes() == want.tobytes()
+    path = "select" if scheme == "sp2_4" or width == 32 else "gather"
+    assert ops.lut_lookups() == {("lookup", path): 1}
+
 
 @pytest.mark.parametrize("m,n,k", [(8, 128, 128), (128, 128, 256),
                                    (64, 256, 128), (256, 128, 384)])

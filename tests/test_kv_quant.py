@@ -154,10 +154,13 @@ def test_quantize_kv_uniform8_matches_legacy_int8():
 # Kernel parity: fused-dequant paged attention (interpret) vs jnp oracle
 # ---------------------------------------------------------------------------
 
+# dh=128 tiles take the lane-gather lookup, dh=16 the select chain
+# (kernels/decode.py)
+@pytest.mark.parametrize("dh", [16, 128])
 @pytest.mark.parametrize("scheme", ["uniform8", "sp2_8", "spx_8_x3"])
-def test_paged_quant_kernel_interpret_matches_ref(scheme):
+def test_paged_quant_kernel_interpret_matches_ref(scheme, dh):
     rng = np.random.default_rng(0)
-    b, hq, hkv, dh, ps, n_pages, mp = 3, 4, 2, 16, 8, 6, 2
+    b, hq, hkv, ps, n_pages, mp = 3, 4, 2, 8, 6, 2
     q = jnp.asarray(rng.standard_normal((b, hq, dh)), jnp.float32)
     kv = rng.standard_normal((2, n_pages, hkv, ps, dh)).astype(np.float32)
     kc, ks = quantize_kv(jnp.asarray(kv[0]), scheme)
