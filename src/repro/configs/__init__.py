@@ -12,7 +12,7 @@ from .base import ArchConfig, LM_SHAPES, ShapeConfig
 _ARCH_MODULES = [
     "granite_3_8b", "gemma_2b", "qwen2_5_14b", "minitron_4b", "xlstm_350m",
     "kimi_k2_1t_a32b", "olmoe_1b_7b", "whisper_small", "jamba_1_5_large_398b",
-    "qwen2_vl_2b", "paper_mlp",
+    "jamba2_mini", "qwen2_vl_2b", "paper_mlp",
 ]
 
 _REGISTRY: dict[str, ArchConfig] = {}
@@ -40,7 +40,7 @@ def list_configs() -> list[str]:
 
 
 def assigned_archs() -> list[str]:
-    """The 10 assigned architectures (excludes the paper's own MLP)."""
+    """The assigned architectures (excludes the paper's own MLP)."""
     _load()
     return sorted(n for n in _REGISTRY if n != "paper-mlp")
 

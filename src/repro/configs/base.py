@@ -43,11 +43,21 @@ class ArchConfig:
     tie_embeddings: bool = False
     rope_theta: float = 10000.0
     mrope_sections: Optional[tuple] = None   # qwen2-vl M-RoPE
+    # no positional encoding in attention (Jamba): q/k are never rotated
+    nope: bool = False
+    # precision of the serving steps' matmuls that state none, kernels
+    # included (jax.default_matmul_precision): None keeps the backend's
+    # default (one bf16 pass on a TPU), "highest" computes f32 at f32
+    # accuracy
+    matmul_precision: Optional[str] = None
     # MoE
     n_experts: int = 0
     top_k: int = 0
     n_shared_experts: int = 0
     capacity_factor: float = 1.25
+    # top-k router weights renormalized to sum to 1 (False: the softmax
+    # probabilities over all experts as they are, as Jamba routes)
+    router_renorm: bool = True
     # layer pattern: per-period slot kinds; n_layers % len(pattern) == 0.
     # slots: "attn+dense" | "attn+moe" | "attn" (no ffn) | "mamba+dense" |
     #        "mamba+moe" | "mamba" | "mlstm" | "slstm+dense" | "xdec+dense"
@@ -57,6 +67,9 @@ class ArchConfig:
     ssm_d_conv: int = 4
     ssm_expand: int = 2
     ssm_dt_rank: int = 0          # 0 -> d_model//16
+    # RMSNorms on the x_proj outputs (dt, B, C) before dt_proj and the
+    # scan (Jamba's dt/b/c_layernorm)
+    ssm_bcdt_norm: bool = False
     lstm_heads: int = 4
     # encoder-decoder (whisper)
     enc_dec: bool = False

@@ -1,11 +1,12 @@
 """Jamba-1.5-Large (398B): Mamba+attention 1:7 interleave, MoE 16e top-2 on
-every other layer. Period of 8 = [attn, 7x mamba], MoE on odd in-period
+every other layer. Period of 8 with attention at offset 4
+(``attn_layer_period`` 8, ``attn_layer_offset`` 4), MoE on odd in-period
 slots; 72 layers = 9 periods. [arXiv:2403.19887; hf]"""
 from .base import ArchConfig
 
 _PERIOD = (
-    "attn+dense", "mamba+moe", "mamba+dense", "mamba+moe",
     "mamba+dense", "mamba+moe", "mamba+dense", "mamba+moe",
+    "attn+dense", "mamba+moe", "mamba+dense", "mamba+moe",
 )
 
 CONFIG = ArchConfig(

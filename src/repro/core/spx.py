@@ -249,9 +249,19 @@ def calibrate_mse(x: jax.Array, scheme: str, channel_axis: int | None = -1,
         xh = dequantize_codes(codes, lut, scale, dtype=jnp.float32)
         return jnp.sum((xh - x.astype(jnp.float32)) ** 2, axis=axes, keepdims=True)
 
-    errs = jnp.stack([err_for(f) for f in fracs])          # (G, ...1s...)
-    best = jnp.argmin(errs, axis=0)                        # broadcast shape
-    fr = jnp.take(jnp.asarray(fracs, jnp.float32), best)
+    def keep_better(best, frac):
+        # one fraction at a time: the codes and the dequantized copy of
+        # ``x`` are live for one fraction, not for all of the grid at once
+        # (24 of them are 8 GB for one 4096 x 16384 weight); the strict <
+        # keeps the first of equal errors, as an argmin over the grid would
+        err, fr = best
+        e = err_for(frac)
+        return (jnp.where(e < err, e, err), jnp.where(e < err, frac, fr)), None
+
+    init = (jnp.full(base.shape, jnp.inf, jnp.float32),
+            jnp.zeros(base.shape, jnp.float32))
+    (_, fr), _ = jax.lax.scan(keep_better, init,
+                              jnp.asarray(fracs, jnp.float32))
     return base * fr
 
 

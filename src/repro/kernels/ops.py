@@ -31,9 +31,11 @@ from .paged_attention import (paged_attention_pallas,
                               paged_attention_quant_pallas,
                               paged_decode_ragged_pallas,
                               paged_decode_ragged_quant_pallas)
+from .spx_expert_matmul import spx_expert_matmul_pallas
 from .spx_matmul import spx_matmul_pallas
 
-__all__ = ["spx_matmul", "flash_attention", "paged_attention",
+__all__ = ["spx_matmul", "spx_expert_matmul", "flash_attention",
+           "paged_attention",
            "paged_attention_quant", "paged_decode_ragged", "resolve_impl",
            "op_calls", "op_impls", "lut_lookups", "reset_op_calls"]
 
@@ -194,6 +196,73 @@ def spx_matmul(x: jax.Array, qt: QuantizedTensor, *, impl: str = "auto",
         functools.partial(entry.fn, plan=plan, out_dtype=out_dtype),
         mesh)(x2, qt, scale)
     return out.reshape(*lead, n_dim)
+
+
+# ---------------------------------------------------------------------------
+# spx_expert_matmul: rows grouped by expert in tiles of bm, each tile
+# against its expert's SPx weight — registered impls share the signature
+# fn(x, qt, scale, tile_expert, tile_rows, *, bm, plan, out_dtype)
+# ---------------------------------------------------------------------------
+
+@registry.register("spx_expert_matmul", "ref",
+                   priority=registry.PRIORITY_REFERENCE)
+def _spx_expert_matmul_ref(x, qt: QuantizedTensor, scale, tile_expert,
+                           tile_rows, *, bm, plan, out_dtype):
+    del plan
+    return ref_impl.spx_expert_matmul_ref(
+        x, qt.codes, scale, qt.lut, tile_expert, tile_rows, bm=bm,
+        packed=qt.packed, out_dtype=out_dtype)
+
+
+def _spx_expert_matmul_planned(x, qt: QuantizedTensor, scale, tile_expert,
+                               tile_rows, *, bm, plan, out_dtype,
+                               interpret: bool):
+    return spx_expert_matmul_pallas(
+        x, qt.codes, scale, qt.lut, tile_expert, tile_rows,
+        packed=qt.packed, bm=bm, bn=plan.bn, bk=plan.bk,
+        out_dtype=out_dtype, interpret=interpret)
+
+
+registry.register("spx_expert_matmul", "pallas",
+                  priority=registry.PRIORITY_ACCELERATOR,
+                  available=_on_tpu)(
+    functools.partial(_spx_expert_matmul_planned, interpret=False))
+registry.register("spx_expert_matmul", "interpret",
+                  priority=registry.PRIORITY_DEBUG)(
+    functools.partial(_spx_expert_matmul_planned, interpret=True))
+
+
+def spx_expert_matmul(x: jax.Array, qt: QuantizedTensor,
+                      tile_expert: jax.Array, tile_rows: jax.Array, *,
+                      bm: int, impl: str = "auto", out_dtype=None,
+                      mesh=None) -> jax.Array:
+    """x: (G * bm, K) rows grouped by expert in G tiles of ``bm`` rows;
+    tile g multiplies dequant(qt[tile_expert[g]]) with qt: (E, K, N)
+    SPx codes. ``tile_rows`` (G,) int32: rows of each tile that carry a
+    pair; a tile with none comes back zero. Returns (G * bm, N).
+    ``mesh`` as in ``spx_matmul``."""
+    _OP_CALLS["spx_expert_matmul"] += 1
+    entry = registry.resolve("spx_expert_matmul", impl)
+    n_e, k_dim, n_dim = qt.logical_shape
+    scale = qt.scale.reshape(n_e, 1, n_dim).astype(jnp.float32)
+    tile_expert = jnp.asarray(tile_expert, jnp.int32)
+    tile_rows = jnp.asarray(tile_rows, jnp.int32)
+    plan = None
+    if entry.impl != "ref":
+        plan = planner.plan_matmul(bm, k_dim, n_dim, weight_bits=qt.bits,
+                                   act_bytes=x.dtype.itemsize,
+                                   packed=qt.packed)
+        if plan is None:
+            if entry.impl == "pallas":
+                raise ValueError(
+                    f"spx_expert_matmul: no legal Pallas tiling for "
+                    f"(bm={bm}, K={k_dim}, N={n_dim}, packed={qt.packed})")
+            entry = registry.resolve("spx_expert_matmul", "ref")
+    _OP_IMPLS["spx_expert_matmul", entry.impl] += 1
+    fn = functools.partial(entry.fn, bm=bm, plan=plan, out_dtype=out_dtype)
+    if entry.impl != "ref":
+        fn = _whole_per_device(fn, mesh)
+    return fn(x, qt, scale, tile_expert, tile_rows)
 
 
 def _autotune_matmul(key, entry, x2, qt, scale, plan, out_dtype):

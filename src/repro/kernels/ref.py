@@ -10,7 +10,8 @@ import jax.numpy as jnp
 
 from repro.core import spx
 
-__all__ = ["spx_matmul_ref", "attention_ref", "paged_attention_ref",
+__all__ = ["spx_matmul_ref", "spx_expert_matmul_ref", "attention_ref",
+           "paged_attention_ref",
            "paged_attention_quant_ref", "paged_decode_ragged_ref",
            "paged_decode_ragged_quant_ref"]
 
@@ -27,6 +28,26 @@ def spx_matmul_ref(x, codes, scale, lut, *, packed: bool, out_dtype=None):
         x, w.astype(x.dtype), (((x.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     return (acc * scale).astype(out_dtype)
+
+
+def spx_expert_matmul_ref(x, codes, scale, lut, tile_expert, tile_rows, *,
+                          bm: int, packed: bool, out_dtype=None):
+    """x: (G * bm, K) in G row tiles; tile g @ (lut[codes[tile_expert[g]]]
+    * scale[tile_expert[g]]), zero where ``tile_rows[g]`` is 0. codes:
+    (E, K, N) (or int4-packed (E, K, N/2)); scale: (E, 1, N). One tile's
+    expert is dequantized at a time."""
+    out_dtype = out_dtype or x.dtype
+    g = tile_expert.shape[0]
+
+    def tile(args):
+        xt, e, rows = args
+        y = spx_matmul_ref(xt, codes[e], scale[e], lut, packed=packed,
+                           out_dtype=jnp.float32)
+        return jnp.where(rows > 0, y, 0.0).astype(out_dtype)
+
+    out = jax.lax.map(tile, (x.reshape(g, bm, x.shape[-1]), tile_expert,
+                             tile_rows))
+    return out.reshape(g * bm, out.shape[-1])
 
 
 def paged_attention_ref(q, k_pages, v_pages, block_table, ctx_len, *,

@@ -55,6 +55,13 @@ def _head_w(params: dict, cfg: ArchConfig):
     return params["head"]["w"]
 
 
+def _serving_precision(cfg: ArchConfig):
+    """The matmul precision the serving steps trace under: every dot that
+    states none, inside the Pallas kernels too, takes
+    ``cfg.matmul_precision``."""
+    return jax.default_matmul_precision(cfg.matmul_precision)
+
+
 def _default_positions(cfg: ArchConfig, b: int, s: int):
     pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     if cfg.mrope_sections is not None:
@@ -169,10 +176,11 @@ def lm_prefill(params, tokens, caches, cfg: ArchConfig, rt: Runtime,
                                                           tokens)
     from repro.nn.transformer import _sp_constrain
     x = _sp_constrain(x, rt)
-    h, new_caches, _ = stack_prefill(params["stack"], x, positions, cfg, rt,
-                                     caches)
-    h = norm_apply(cfg.norm, params["final_norm"], h[:, -1:])
-    logits = jnp.dot(h[:, 0], _head_w(params, cfg).astype(h.dtype))
+    with _serving_precision(cfg):
+        h, new_caches, _ = stack_prefill(params["stack"], x, positions, cfg,
+                                         rt, caches)
+        h = norm_apply(cfg.norm, params["final_norm"], h[:, -1:])
+        logits = jnp.dot(h[:, 0], _head_w(params, cfg).astype(h.dtype))
     return logits, new_caches
 
 
@@ -181,9 +189,11 @@ def lm_decode_step(params, token, pos, caches, cfg: ArchConfig, rt: Runtime):
     position = number of tokens already in cache). Returns (logits (B, V),
     new_caches)."""
     x = embedding_apply(params["embed"], token[:, None])
-    h, new_caches = stack_decode(params["stack"], x, pos, cfg, rt, caches)
-    h = norm_apply(cfg.norm, params["final_norm"], h)
-    logits = jnp.dot(h[:, 0], _head_w(params, cfg).astype(h.dtype))
+    with _serving_precision(cfg):
+        h, new_caches = stack_decode(params["stack"], x, pos, cfg, rt,
+                                     caches)
+        h = norm_apply(cfg.norm, params["final_norm"], h)
+        logits = jnp.dot(h[:, 0], _head_w(params, cfg).astype(h.dtype))
     return logits, new_caches
 
 
@@ -346,7 +356,7 @@ def lm_paged_step(params, tokens, ctx_len, block_table, n_valid, state_idx,
     attention patterns pass all-sentinel). Returns (logits (B, V) at each
     row's last valid position, new_caches).
     """
-    with jax.named_scope("prefill_step"):
+    with _serving_precision(cfg), jax.named_scope("prefill_step"):
         x = embedding_apply(params["embed"], tokens)
         h, new_caches = stack_paged(params["stack"], x, ctx_len,
                                     block_table, n_valid, state_idx, cfg,
@@ -376,7 +386,7 @@ def lm_paged_verify(params, tokens, ctx_len, block_table, n_valid,
     stays tiny. Rows with ``n_valid`` < C carry garbage logits past their
     window — the engine only reads positions < n_valid.
     """
-    with jax.named_scope("verify_step"):
+    with _serving_precision(cfg), jax.named_scope("verify_step"):
         x = embedding_apply(params["embed"], tokens)
         h, new_caches = stack_paged(params["stack"], x, ctx_len,
                                     block_table, n_valid, state_idx, cfg,
@@ -402,7 +412,7 @@ def lm_paged_fused_step(params, tokens, ctx_len, block_table, n_valid,
     Rows past ``n_valid`` carry garbage logits (the kernel returns zeros
     for them pre-head) — the engine only reads positions < n_valid.
     """
-    with jax.named_scope("decode_step"):
+    with _serving_precision(cfg), jax.named_scope("decode_step"):
         x = embedding_apply(params["embed"], tokens)
         h, new_caches = stack_paged(params["stack"], x, ctx_len,
                                     block_table, n_valid, state_idx, cfg,

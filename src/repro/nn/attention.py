@@ -67,6 +67,11 @@ def _project_qkv(p, x, n_heads, n_kv_heads, head_dim, rt):
 
 
 def _apply_positional(q, k, positions, rope_theta, mrope_sections):
+    """q and k rotated at ``positions`` (RoPE, or M-RoPE with
+    ``mrope_sections``); ``rope_theta`` None means no positional
+    encoding (NoPE), so both come back as they are."""
+    if rope_theta is None:
+        return q, k
     if mrope_sections is not None:
         # positions: (B, 3, S)
         q = apply_mrope(q, positions, sections=mrope_sections,
@@ -197,7 +202,8 @@ def attn_apply_dense(p: dict, x: jax.Array, positions: jax.Array, *,
     q, k, v = _project_qkv(p, x, n_heads, n_kv_heads, head_dim, rt)
     if cross_kv is not None:
         k, v = cross_kv
-        q = apply_rope(q, positions, theta=rope_theta) if mrope_sections is None else q
+        if mrope_sections is None and rope_theta is not None:
+            q = apply_rope(q, positions, theta=rope_theta)
     elif positions is not None:
         q, k = _apply_positional(q, k, positions, rope_theta, mrope_sections)
 
@@ -528,7 +534,8 @@ def attn_paged_step(p: dict, x: jax.Array, ctx_len: jax.Array,
     one launch for the whole ragged window, n_valid as the per-slot
     ``q_len``, dense or quantized pools alike; the serving engine's
     decode/verify tick sets it, chunked prefill keeps the gather path.
-    Returns (y (B, C, D), new_cache).
+    ``rope_theta`` None: no positional encoding (NoPE). Returns
+    (y (B, C, D), new_cache).
     """
     b, c, _ = x.shape
     quantized = isinstance(cache["kp"], dict)

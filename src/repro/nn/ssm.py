@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from repro.runtime import Runtime
 
 from .layers import (constrain_feature_sharded, dense_apply, dense_init,
-                     opt_barrier)
+                     opt_barrier, rmsnorm_apply, rmsnorm_init)
 
 __all__ = [
     "mamba_init", "mamba_apply", "mamba_decode_step", "mamba_init_state",
@@ -35,11 +35,13 @@ __all__ = [
 
 def mamba_init(key, d_model: int, *, d_state: int = 16, d_conv: int = 4,
                expand: int = 2, dt_rank: int | None = None,
-               dtype=jnp.float32) -> dict:
+               bcdt_norm: bool = False, dtype=jnp.float32) -> dict:
+    """``bcdt_norm``: RMSNorm gains for the x_proj outputs (dt, B, C),
+    applied before dt_proj and the scan wherever they are present."""
     di = expand * d_model
     dt_rank = dt_rank or max(16, d_model // 16)
     ks = jax.random.split(key, 6)
-    return {
+    p = {
         "in_proj": dense_init(ks[0], d_model, 2 * di, dtype=dtype),
         "conv_w": jax.random.normal(ks[1], (d_conv, di), dtype) * 0.2,
         "conv_b": jnp.zeros((di,), dtype),
@@ -54,6 +56,11 @@ def mamba_init(key, d_model: int, *, d_state: int = 16, d_conv: int = 4,
         "D": jnp.ones((di,), dtype),
         "out_proj": dense_init(ks[4], di, d_model, dtype=dtype),
     }
+    if bcdt_norm:
+        p.update(dt_norm=rmsnorm_init(dt_rank, dtype),
+                 b_norm=rmsnorm_init(d_state, dtype),
+                 c_norm=rmsnorm_init(d_state, dtype))
+    return p
 
 
 def _mamba_dims(p):
@@ -77,6 +84,17 @@ def _causal_depthwise_conv(x, w, b):
     for j in range(dc):  # dc is 4: unrolled taps, no While loop
         out = out + pad[:, j:j + x.shape[1], :] * w[j]
     return out + b
+
+
+def _ssm_inputs(p, proj, dt_rank: int, d_state: int):
+    """Split the x_proj output into (dt low-rank, B, C), each through its
+    RMSNorm where the block has them (Jamba)."""
+    dt, Bm, Cm = jnp.split(proj, [dt_rank, dt_rank + d_state], axis=-1)
+    if "dt_norm" in p:
+        dt = rmsnorm_apply(p["dt_norm"], dt)
+        Bm = rmsnorm_apply(p["b_norm"], Bm)
+        Cm = rmsnorm_apply(p["c_norm"], Cm)
+    return dt, Bm, Cm
 
 
 SSM_CHUNK = 128
@@ -136,6 +154,24 @@ def _selective_scan(u, dt, A, Bm, Cm, D, h0, *, chunk: int = SSM_CHUNK,
     return y, hT
 
 
+def _sequential_scan(u, dt, A, Bm, Cm, D, h0):
+    """The selective scan one position at a time, for the few positions
+    of a serving chunk. u, dt: (B,C,di); A: (di,ds); Bm/Cm: (B,C,ds); h0:
+    (B,di,ds). Returns y (B,C,di), hT. Only one (B,di,ds) state is live
+    at a time: the parallel form's (B,C,di,ds) tensors would take
+    gigabytes at a full batch of published-width slots."""
+    def step(h, inp):
+        u_t, dt_t, b_t, c_t = (a.astype(jnp.float32) for a in inp)
+        h = jnp.exp(dt_t[..., None] * A) * h \
+            + (dt_t * u_t)[..., None] * b_t[:, None, :]
+        return h, jnp.einsum("bds,bs->bd", h, c_t)
+
+    hT, ys = jax.lax.scan(step, h0, tuple(a.swapaxes(0, 1)
+                                          for a in (u, dt, Bm, Cm)))
+    y = ys.swapaxes(0, 1).astype(u.dtype) + u * D.astype(u.dtype)
+    return y, hT
+
+
 def mamba_apply(p: dict, x: jax.Array, *, rt: Runtime,
                 state: dict | None = None, return_state: bool = False):
     """x: (B, S, D) -> (B, S, D). Train/prefill form (scan over S)."""
@@ -146,7 +182,7 @@ def mamba_apply(p: dict, x: jax.Array, *, rt: Runtime,
     u = jax.nn.silu(_causal_depthwise_conv(u_pre, p["conv_w"], p["conv_b"]))
     u = constrain_feature_sharded(u, rt)
     proj = dense_apply(p["x_proj"], u, rt)
-    dt, Bm, Cm = jnp.split(proj, [dt_rank, dt_rank + d_state], axis=-1)
+    dt, Bm, Cm = _ssm_inputs(p, proj, dt_rank, d_state)
     # dt stays in compute dtype for the full sequence; f32 happens per-chunk
     dt = jax.nn.softplus(dense_apply(p["dt_proj"], dt, rt)
                          .astype(jnp.float32)).astype(x.dtype)
@@ -178,7 +214,7 @@ def mamba_decode_step(p: dict, x: jax.Array, state: dict, *, rt: Runtime):
                      p["conv_w"].astype(jnp.float32)) + p["conv_b"]
     u_c = jax.nn.silu(u_c)[:, None, :]                     # (B,1,di)
     proj = dense_apply(p["x_proj"], u_c.astype(x.dtype), rt)
-    dt, Bm, Cm = jnp.split(proj, [dt_rank, dt_rank + d_state], axis=-1)
+    dt, Bm, Cm = _ssm_inputs(p, proj, dt_rank, d_state)
     dt = jax.nn.softplus(dense_apply(p["dt_proj"], dt, rt).astype(jnp.float32))
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
     dA = jnp.exp(dt[:, 0, :, None] * A)                    # (B,di,ds)
@@ -229,7 +265,9 @@ def mamba_paged_step(p: dict, x: jax.Array, state: dict, n_valid, *,
     dBu = 0), so the returned state equals running only each row's valid
     prefix — a fully inactive row (n_valid == 0) returns its state bit
     exact. Chaining C=1 steps matches ``mamba_decode_step`` and the
-    ``mamba_apply`` full scan (regression-tested)."""
+    ``mamba_apply`` full scan (regression-tested). The chunk scans one
+    position at a time (``_sequential_scan``), so a full batch of
+    published-width slabs needs no (B, C, di, ds) temporaries."""
     b, c, _ = x.shape
     di, d_state, d_conv, dt_rank = _mamba_dims(p)
     xz = dense_apply(p["in_proj"], x, rt)
@@ -238,16 +276,15 @@ def mamba_paged_step(p: dict, x: jax.Array, state: dict, n_valid, *,
                                 p["conv_w"], p["conv_b"])
     u = jax.nn.silu(u_c).astype(x.dtype)                   # (B,C,di)
     proj = dense_apply(p["x_proj"], u, rt)
-    dt, Bm, Cm = jnp.split(proj, [dt_rank, dt_rank + d_state], axis=-1)
+    dt, Bm, Cm = _ssm_inputs(p, proj, dt_rank, d_state)
     dt = jax.nn.softplus(dense_apply(p["dt_proj"], dt, rt)
                          .astype(jnp.float32)).astype(x.dtype)
     valid = jnp.arange(c, dtype=jnp.int32)[None, :] \
         < n_valid[:, None].astype(jnp.int32)               # (B,C)
     dt = jnp.where(valid[..., None], dt, jnp.zeros_like(dt))
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
-    y, hT = _selective_scan(u, dt, A, Bm, Cm,
-                            p["D"].astype(jnp.float32), state["h"],
-                            unroll=rt.unroll)
+    y, hT = _sequential_scan(u, dt, A, Bm, Cm, p["D"].astype(jnp.float32),
+                             state["h"])
     out = dense_apply(p["out_proj"], (y.astype(x.dtype) * jax.nn.silu(z)),
                       rt)
     return out, {"h": hT, "conv": new_conv}

@@ -29,13 +29,18 @@ from .attention import (_apply_positional, _project_qkv, attention_core,
                         attn_paged_step)
 from .layers import norm_apply, norm_init, opt_barrier
 from .mlp import mlp_apply, mlp_init
-from .moe import moe_apply, moe_init
+from .moe import moe_apply, moe_init, moe_serve
 
 __all__ = ["stack_init", "stack_apply", "stack_prefill", "stack_decode",
            "stack_paged", "slot_init_cache", "slot_init_paged_cache",
            "SLOT_KINDS"]
 
 SLOT_KINDS = ("attn", "xdec", "mamba", "mlstm", "slstm")
+
+
+def _rope_theta(cfg: ArchConfig):
+    """The attention layers' RoPE base, or None for NoPE attention."""
+    return None if cfg.nope else cfg.rope_theta
 
 
 def _parse_slot(slot: str):
@@ -66,7 +71,7 @@ def _slot_init(key, slot: str, cfg: ArchConfig, dtype) -> dict:
         p["mamba"] = ssm.mamba_init(
             ks[0], d, d_state=cfg.ssm_d_state, d_conv=cfg.ssm_d_conv,
             expand=cfg.ssm_expand, dt_rank=cfg.ssm_dt_rank or None,
-            dtype=dtype)
+            bcdt_norm=cfg.ssm_bcdt_norm, dtype=dtype)
     elif mixer == "mlstm":
         p["mlstm"] = ssm.mlstm_init(ks[0], d, n_heads=cfg.lstm_heads,
                                     expand=cfg.ssm_expand,
@@ -165,20 +170,20 @@ def _slot_apply(slot: str, p: dict, x, positions, cfg: ArchConfig,
                     p["attn"], h, ctx_len, block_table, cache,
                     n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
                     head_dim=cfg.dh, n_valid=n_valid,
-                    rope_theta=cfg.rope_theta,
+                    rope_theta=_rope_theta(cfg),
                     mrope_sections=cfg.mrope_sections, rt=rt, fused=fused)
         elif mode == "decode":
             y, kv = attn_decode_step(
                 p["attn"], h, pos, (cache["k"], cache["v"]),
                 n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
-                head_dim=cfg.dh, rope_theta=cfg.rope_theta,
+                head_dim=cfg.dh, rope_theta=_rope_theta(cfg),
                 mrope_sections=cfg.mrope_sections, rt=rt)
             new_cache = dict(cache, k=kv[0], v=kv[1])
         elif mode == "prefill":
             y, (k, v) = attn_apply_dense(
                 p["attn"], h, positions, n_heads=cfg.n_heads,
                 n_kv_heads=cfg.n_kv_heads, head_dim=cfg.dh, causal=causal,
-                rope_theta=cfg.rope_theta, mrope_sections=cfg.mrope_sections,
+                rope_theta=_rope_theta(cfg), mrope_sections=cfg.mrope_sections,
                 rt=rt, kv_out=True)
             # write prefix into the (possibly longer) cache: (B,S,Hkv,dh) ->
             # (B,Hkv,S,dh) layout
@@ -202,7 +207,7 @@ def _slot_apply(slot: str, p: dict, x, positions, cfg: ArchConfig,
             y = attn_apply_dense(
                 p["attn"], h, positions, n_heads=cfg.n_heads,
                 n_kv_heads=cfg.n_kv_heads, head_dim=cfg.dh, causal=causal,
-                rope_theta=cfg.rope_theta, mrope_sections=cfg.mrope_sections,
+                rope_theta=_rope_theta(cfg), mrope_sections=cfg.mrope_sections,
                 rt=rt)
         x = x + y
         if mixer == "xdec":
@@ -226,7 +231,7 @@ def _slot_apply(slot: str, p: dict, x, positions, cfg: ArchConfig,
                 positions_x = ctx_len[:, None] \
                     + jnp.arange(cq, dtype=jnp.int32)
                 qx, _ = _apply_positional(qx, kx, positions_x,
-                                          cfg.rope_theta, None)
+                                          _rope_theta(cfg), None)
                 o = attention_core(jnp.swapaxes(qx, 1, 2), kh, vh,
                                    causal=False, rt=rt)
                 y = jnp.swapaxes(o, 1, 2).reshape(bq, cq,
@@ -238,7 +243,7 @@ def _slot_apply(slot: str, p: dict, x, positions, cfg: ArchConfig,
                 y, _ = attn_decode_step(
                     p["xattn"], hx, pos, None, n_heads=cfg.n_heads,
                     n_kv_heads=cfg.n_kv_heads, head_dim=cfg.dh,
-                    rope_theta=cfg.rope_theta, rt=rt,
+                    rope_theta=_rope_theta(cfg), rt=rt,
                     cross_kv=(jnp.swapaxes(xkv[0], 1, 2),
                               jnp.swapaxes(xkv[1], 1, 2)))
             else:
@@ -247,7 +252,7 @@ def _slot_apply(slot: str, p: dict, x, positions, cfg: ArchConfig,
                 y = attn_apply_dense(
                     p["xattn"], hx, positions, n_heads=cfg.n_heads,
                     n_kv_heads=cfg.n_kv_heads, head_dim=cfg.dh, causal=False,
-                    rope_theta=cfg.rope_theta, rt=rt, cross_kv=(xk, xv))
+                    rope_theta=_rope_theta(cfg), rt=rt, cross_kv=(xk, xv))
                 if mode == "prefill":
                     new_cache = dict(new_cache,
                                      xk=jnp.swapaxes(xk, 1, 2)
@@ -257,10 +262,11 @@ def _slot_apply(slot: str, p: dict, x, positions, cfg: ArchConfig,
             x = x + y
     elif mixer == "mamba":
         if mode == "paged":
-            y, new_cache = _slab_step(
-                cache, state_idx, n_valid,
-                lambda st: ssm.mamba_paged_step(p["mamba"], h, st, n_valid,
-                                                rt=rt))
+            with jax.named_scope("mamba"):
+                y, new_cache = _slab_step(
+                    cache, state_idx, n_valid,
+                    lambda st: ssm.mamba_paged_step(p["mamba"], h, st,
+                                                    n_valid, rt=rt))
         elif mode == "decode":
             y, new_cache = ssm.mamba_decode_step(p["mamba"], h, cache, rt=rt)
         elif mode == "prefill":
@@ -308,11 +314,18 @@ def _slot_apply(slot: str, p: dict, x, positions, cfg: ArchConfig,
                               act=cfg.act, rt=rt)
     elif ffn == "moe":
         h = norm_apply(cfg.norm, p["norm2"], x)
-        y, a = moe_apply(p["moe"], h, top_k=cfg.top_k,
-                         n_experts=cfg.n_experts,
-                         capacity_factor=cfg.capacity_factor, rt=rt)
+        if mode == "train":
+            y, a = moe_apply(p["moe"], h, top_k=cfg.top_k,
+                             n_experts=cfg.n_experts,
+                             capacity_factor=cfg.capacity_factor,
+                             renormalize=cfg.router_renorm, rt=rt)
+            aux = aux + a
+        else:
+            # serving: dropless, SPx expert codes read in place
+            y = moe_serve(p["moe"], h, top_k=cfg.top_k,
+                          n_experts=cfg.n_experts,
+                          renormalize=cfg.router_renorm, rt=rt)
         x = x + y
-        aux = aux + a
     return x, new_cache, aux
 
 

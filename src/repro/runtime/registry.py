@@ -19,7 +19,8 @@ so the nn/ layers never re-derive "pallas on TPU, ref elsewhere" themselves.
 The built-in kernels live in ``repro.kernels.ops`` and register themselves
 at import; ``resolve`` imports that module lazily so the registry package
 itself stays dependency-free. Current built-in ops: ``spx_matmul``,
-``flash_attention``, ``paged_attention`` (serving decode over the paged KV
+``spx_expert_matmul`` (the same over a stack of experts, rows grouped by
+expert — the serving MoE layer), ``flash_attention``, ``paged_attention`` (serving decode over the paged KV
 cache — see docs/SERVING.md), ``paged_attention_quant`` (same, over
 codes+scale quantized pools with fused codebook dequant —
 docs/QUANTIZATION.md), and ``paged_decode_ragged`` /

@@ -265,6 +265,8 @@ class ServeEngine:
         # prompt tokens, and rows computed, over the prefill launches
         self._prefill_valid = 0
         self._prefill_computed = 0
+        # slabs zeroed for fresh admissions
+        self._slab_resets = 0
         # speculation counters: per-row windows that carried >= 1 draft
         # (a batched verify call holds one window per drafted slot),
         # and drafts proposed/accepted across them
@@ -743,6 +745,7 @@ class ServeEngine:
         self._launches.clear()
         self._prefill_valid = 0
         self._prefill_computed = 0
+        self._slab_resets = 0
         self._spec_windows = 0
         self._spec_proposed = 0
         self._spec_accepted = 0
@@ -814,6 +817,11 @@ class ServeEngine:
                      "slabs_in_use": st.slabs_in_use,
                      "peak_slabs": st.peak_slabs_in_use,
                      "slab_bytes_per_seq": int(slab_bytes),
+                     # recurrent state held now, and slabs zeroed for
+                     # fresh admissions
+                     "slab_bytes_resident": int(st.slabs_in_use
+                                                * slab_bytes),
+                     "slab_resets": self._slab_resets,
                      "n_cross": st.n_cross,
                      "cross_in_use": st.cross_in_use,
                      "peak_cross": st.peak_cross_in_use,
@@ -1046,6 +1054,7 @@ class ServeEngine:
             # slab index is recycled, so the zero is explicit
             self.caches = self._reset_slabs(
                 self.caches, jnp.int32(self.pool.seq_slab(req.rid)))
+            self._slab_resets += 1
         if self._has_cross and self.pool.consume_cross_fresh(req.rid):
             # cross-region miss: run the encoder + per-slot K/V
             # projection once and fill the claimed entry. A hit (same

@@ -83,8 +83,9 @@ def test_spx_matmul_compiles(one_chip, proj, m, scheme):
         _sds((k, n // 2 if packed else n), jnp.uint8, one_chip),
         _sds((1, n), jnp.float32, one_chip), scheme, packed)
     x = _sds((m, k), jnp.float32, one_chip)
-    compiled = _compile(lambda x, qt: ops.spx_matmul(x, qt, impl="pallas"),
-                        x, qt)
+    with jax.default_matmul_precision(JAMBA.matmul_precision):
+        compiled = _compile(
+            lambda x, qt: ops.spx_matmul(x, qt, impl="pallas"), x, qt)
     assert "tpu_custom_call" in compiled.as_text()
 
 
@@ -162,3 +163,51 @@ def test_spx_matmul_compiles_on_a_four_chip_mesh(topo):
     hlo = _compile(lambda x, qt: ops.spx_matmul(x, qt, impl="pallas",
                                                 mesh=mesh), x, qt).as_text()
     assert "tpu_custom_call" in hlo and "all-gather" in hlo
+
+
+# jamba2-mini (AI21-Jamba2-Mini's widths): its Mamba projections, x_proj's
+# 288 columns (one int4 lane group of 144), and the grouped expert kernel
+# at the tile rows a decode tick (64 rows x top-2 over 16 experts) and a
+# prefill tick (64 x 32 rows) pick, at the entry's matmul precision (f32
+# accuracy), as its serving steps trace them
+JAMBA = get_config("jamba2-mini")
+JAMBA_DI = JAMBA.ssm_expand * JAMBA.d_model
+JAMBA_PROJ = {"in_proj": (JAMBA.d_model, 2 * JAMBA_DI),
+              "x_proj": (JAMBA_DI, JAMBA.ssm_dt_rank + 2 * JAMBA.ssm_d_state),
+              "dt_proj": (JAMBA.ssm_dt_rank, JAMBA_DI),
+              "out_proj": (JAMBA_DI, JAMBA.d_model)}
+
+
+@pytest.mark.parametrize("m", [64, 64 * CHUNK])
+@pytest.mark.parametrize("proj", sorted(JAMBA_PROJ))
+def test_jamba_projection_compiles(one_chip, proj, m):
+    k, n = JAMBA_PROJ[proj]
+    qt = QuantizedTensor(_sds((k, n // 2), jnp.uint8, one_chip),
+                         _sds((1, n), jnp.float32, one_chip), "sp2_4", True)
+    x = _sds((m, k), jnp.float32, one_chip)
+    with jax.default_matmul_precision(JAMBA.matmul_precision):
+        compiled = _compile(
+            lambda x, qt: ops.spx_matmul(x, qt, impl="pallas"), x, qt)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [64, 64 * CHUNK])
+@pytest.mark.parametrize("which", ["gate_up", "down"])
+def test_spx_expert_matmul_compiles(one_chip, which, rows):
+    from repro.nn.moe import expert_tile_rows
+    e, d, f = JAMBA.n_experts, JAMBA.d_model, JAMBA.d_ff
+    k, n = (d, f) if which == "gate_up" else (f, d)
+    pairs = rows * JAMBA.top_k
+    bm = expert_tile_rows(pairs, e)
+    g = -(-pairs // bm) + e
+    qt = QuantizedTensor(_sds((e, k, n // 2), jnp.uint8, one_chip),
+                         _sds((e, 1, n), jnp.float32, one_chip), "sp2_4",
+                         True)
+    with jax.default_matmul_precision(JAMBA.matmul_precision):
+        compiled = _compile(
+            lambda x, qt, te, tr: ops.spx_expert_matmul(
+                x, qt, te, tr, bm=bm, impl="pallas"),
+            _sds((g * bm, k), jnp.float32, one_chip), qt,
+            _sds((g,), jnp.int32, one_chip),
+            _sds((g,), jnp.int32, one_chip))
+    assert "tpu_custom_call" in compiled.as_text()

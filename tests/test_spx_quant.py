@@ -124,6 +124,21 @@ class TestQuantizeProps:
         e_mse = jnp.mean((spx.fake_quantize(w, scheme, a_mse) - w) ** 2)
         assert float(e_mse) <= float(e_mm) * (1 + 1e-6)
 
+    @pytest.mark.parametrize("scheme", ["sp2_4", "spx_8_x3"])
+    def test_mse_calibration_is_the_grid_argmin(self, scheme):
+        """Per channel, the first grid fraction of max|w| whose rounding
+        error is least (the calibration walks the grid one fraction at a
+        time to bound its temporaries)."""
+        rng = np.random.default_rng(7)
+        w = jnp.asarray(rng.standard_t(3, (64, 40)).astype(np.float32))
+        base = np.maximum(np.abs(np.asarray(w)).max(0, keepdims=True), 1e-12)
+        fracs = np.linspace(0.4, 1.05, 24).astype(np.float32)
+        errs = [np.asarray(jnp.sum((spx.fake_quantize(
+            w, scheme, jnp.asarray(base * f)) - w) ** 2, 0)) for f in fracs]
+        want = base * fracs[np.argmin(np.stack(errs), 0)]
+        got = spx.calibrate_mse(w, scheme, axes=(-2,))
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6)
+
     @settings(max_examples=30, deadline=None)
     @given(seed=st.integers(0, 2**31 - 1), n=st.integers(1, 32))
     def test_pack_unpack_roundtrip(self, seed, n):

@@ -45,6 +45,7 @@ SHARDS: dict[str, list[str]] = {
     # serving engine + model-level serving paths
     "serving-models": [
         "tests/test_fused_decode.py",
+        "tests/test_jamba.py",
         "tests/test_kv_quant.py",
         "tests/test_models_smoke.py",
         "tests/test_prefix_cache.py",
