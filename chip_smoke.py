@@ -177,7 +177,7 @@ def kernel_checks(cfg, *, impl: str = "pallas", slots: int = SLOTS,
                                    kv_scheme=kv_scheme).page_size
         pps = max_seq // ps
         n_pages = slots * pps
-        shape = (n_pages, hkv, ps, dh)
+        shape = (1, n_pages, hkv, ps, dh)       # one layer's pools
         if kv_scheme is None:
             kp, vp = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
                       for _ in range(2))
@@ -186,7 +186,8 @@ def kernel_checks(cfg, *, impl: str = "pallas", slots: int = SLOTS,
             kp, vp = ({"codes": jnp.asarray(rng.integers(0, n_lv, shape),
                                             jnp.uint8),
                        "scale": jnp.asarray(
-                           rng.uniform(0.5, 2.0, shape[:3] + (1,)),
+                           rng.uniform(0.5, 2.0, shape[:3]
+                                       + (spx.kv_scale_lanes(ps),)),
                            jnp.float32)} for _ in range(2))
         bt = jnp.asarray(rng.permutation(n_pages).reshape(slots, pps),
                          jnp.int32)
@@ -198,10 +199,10 @@ def kernel_checks(cfg, *, impl: str = "pallas", slots: int = SLOTS,
                             jnp.float32)
             args = (q, kp, vp, bt, jnp.asarray(ctx, jnp.int32),
                     jnp.asarray(q_len, jnp.int32))
-            got = ops.paged_decode_ragged(*args, kv_scheme=kv_scheme,
-                                          impl=impl)
-            want = ops.paged_decode_ragged(*args, kv_scheme=kv_scheme,
-                                           impl="ref")
+            got = ops.paged_decode_ragged(*args, layer=0,
+                                          kv_scheme=kv_scheme, impl=impl)
+            want = ops.paged_decode_ragged(*args, layer=0,
+                                           kv_scheme=kv_scheme, impl="ref")
             name = ("paged_decode_ragged" if kv_scheme is None
                     else f"paged_decode_ragged_quant {kv_scheme}")
             check_close(f"{name} W={w} (page {ps})", got, want,

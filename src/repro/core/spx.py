@@ -45,6 +45,8 @@ __all__ = [
     "KV_CODE_BYTES",
     "KV_SCALE_BYTES",
     "kv_token_side_bytes",
+    "kv_scale_lanes",
+    "kv_page_side_bytes",
 ]
 
 #: Quantized KV-cache storage layout (scheme-independent; the single owner
@@ -59,6 +61,23 @@ def kv_token_side_bytes(dh: int) -> int:
     """Bytes one token's K *or* V occupies for one KV head in the
     quantized codes+scale cache layout."""
     return dh * KV_CODE_BYTES + KV_SCALE_BYTES
+
+
+def kv_scale_lanes(page_size: int) -> int:
+    """Lanes of one page's scale row in the paged quantized pool: the
+    scales of a page's tokens lie along the lanes of one (Hkv, lanes)
+    block, padded to whole 128-lane vregs — the layout XLA and the Pallas
+    kernels both keep row-major, so neither relayouts the pool."""
+    return -(-page_size // 128) * 128
+
+
+def kv_page_side_bytes(dh: int, page_size: int) -> int:
+    """Device bytes of one page of one KV head's K *or* V in the paged
+    quantized pool: the codes, plus the page's scale row, padded to
+    ``kv_scale_lanes`` — a little more than ``page_size`` x
+    ``kv_token_side_bytes``."""
+    return (page_size * dh * KV_CODE_BYTES
+            + kv_scale_lanes(page_size) * KV_SCALE_BYTES)
 
 
 # ---------------------------------------------------------------------------

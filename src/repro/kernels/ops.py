@@ -31,12 +31,14 @@ from .paged_attention import (paged_attention_pallas,
                               paged_attention_quant_pallas,
                               paged_decode_ragged_pallas,
                               paged_decode_ragged_quant_pallas)
+from .paged_kv_write import paged_kv_write_pallas
 from .spx_expert_matmul import spx_expert_matmul_pallas
 from .spx_matmul import spx_matmul_pallas
 
 __all__ = ["spx_matmul", "spx_expert_matmul", "flash_attention",
            "paged_attention",
-           "paged_attention_quant", "paged_decode_ragged", "resolve_impl",
+           "paged_attention_quant", "paged_decode_ragged", "paged_kv_write",
+           "resolve_impl",
            "op_calls", "op_impls", "lut_lookups", "reset_op_calls"]
 
 
@@ -94,6 +96,31 @@ def _whole_per_device(fn, mesh):
         return fn
     return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
                          check_vma=False)
+
+
+def _per_kv_head(fn, mesh, axis, n_kv_heads: int, in_heads, out_heads):
+    """``fn`` (a Pallas kernel call whose work splits by KV head) for a
+    jitted program over ``mesh``. Where the mesh's ``axis`` divides the
+    ``n_kv_heads`` heads, the serving pools are head-sharded over it
+    (``ShardingPolicy.paged_state_specs``): a shard_map hands each device
+    its own heads of every operand, so the pools are read and written
+    where they lie and nothing is gathered. ``in_heads``/``out_heads``
+    give each operand's (and result's) head dimension, None for one
+    every device holds whole; they follow the pytree structure of the
+    arguments and result. Otherwise as ``_whole_per_device``."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    n = dict(mesh.shape).get(axis, 1) if axis is not None else 1
+    if n == 1 or n_kv_heads % n:
+        return _whole_per_device(fn, mesh)
+
+    def spec(d):
+        return P() if d is None else P(*([None] * d), axis)
+
+    specs = lambda tree: jax.tree_util.tree_map(spec, tree,
+                                                is_leaf=lambda d: d is None)
+    return jax.shard_map(fn, mesh=mesh, in_specs=specs(in_heads),
+                         out_specs=specs(out_heads), check_vma=False)
 
 
 def resolve_impl(impl: str) -> str:
@@ -439,8 +466,8 @@ def paged_attention_quant(q: jax.Array, k_pages: dict, v_pages: dict,
     codes stay 1 byte/element all the way to VMEM).
 
     q: (B, Hq, dh); k_pages/v_pages: {"codes": (n_pages, Hkv, page_size,
-    dh) uint8, "scale": (n_pages, Hkv, page_size, 1) f32} — the pools
-    ``nn.attention.paged_kv_write`` maintains; ``kv_scheme`` names the
+    dh) uint8, "scale": (n_pages, Hkv, lanes) f32} — one layer of the
+    pools ``nn.attention.paged_kv_write`` maintains; ``kv_scheme`` names the
     core/spx level set the codes were quantized under (static — resolves
     to a <=256-entry f32 codebook). Returns (B, Hq, dh). ``mesh`` as in
     ``spx_matmul``.
@@ -466,19 +493,20 @@ def paged_attention_quant(q: jax.Array, k_pages: dict, v_pages: dict,
 # batched decode tick, plain decode AND the spec-decode verify window, over
 # a ragged (slot, attend_len) grid, for dense or quantized (fused-LUT) KV
 # pools. Registered impls share the signatures
-#   dense: fn(q4, k_pages, v_pages, block_table, ctx_len, q_len, *, w)
+#   dense: fn(q4, k_pages, v_pages, block_table, ctx_len, q_len, layer, *, w)
 #   quant: fn(q4, k_codes, k_scale, v_codes, v_scale, block_table, ctx_len,
-#             q_len, lut, *, w)
-# with q4: (B, Hkv, rep * w, dh) rep-major window rows.
+#             q_len, layer, lut, *, w)
+# with q4: (B, Hkv, rep * w, dh) rep-major window rows, pools stacked over
+# layers and layer: (1,) int32.
 # ---------------------------------------------------------------------------
 
 @registry.register("paged_decode_ragged", "ref",
                    priority=registry.PRIORITY_REFERENCE)
 def _paged_decode_ragged_ref(q4, k_pages, v_pages, block_table, ctx_len,
-                             q_len, *, w):
-    return ref_impl.paged_decode_ragged_ref(q4, k_pages, v_pages,
-                                            block_table, ctx_len, q_len,
-                                            w=w)
+                             q_len, layer, *, w):
+    return ref_impl.paged_decode_ragged_ref(q4, k_pages[layer[0]],
+                                            v_pages[layer[0]], block_table,
+                                            ctx_len, q_len, w=w)
 
 
 registry.register("paged_decode_ragged", "pallas",
@@ -493,10 +521,12 @@ registry.register("paged_decode_ragged", "interpret",
 @registry.register("paged_decode_ragged_quant", "ref",
                    priority=registry.PRIORITY_REFERENCE)
 def _paged_decode_ragged_quant_ref(q4, k_codes, k_scale, v_codes, v_scale,
-                                   block_table, ctx_len, q_len, lut, *, w):
+                                   block_table, ctx_len, q_len, layer, lut,
+                                   *, w):
+    l = layer[0]
     return ref_impl.paged_decode_ragged_quant_ref(
-        q4, k_codes, k_scale, v_codes, v_scale, block_table, ctx_len,
-        q_len, lut, w=w)
+        q4, k_codes[l], k_scale[l], v_codes[l], v_scale[l], block_table,
+        ctx_len, q_len, lut, w=w)
 
 
 registry.register("paged_decode_ragged_quant", "pallas",
@@ -510,8 +540,10 @@ registry.register("paged_decode_ragged_quant", "interpret",
 
 def paged_decode_ragged(q: jax.Array, k_pages, v_pages,
                         block_table: jax.Array, ctx_len: jax.Array,
-                        q_len: jax.Array, *, kv_scheme: str | None = None,
-                        impl: str = "auto", mesh=None) -> jax.Array:
+                        q_len: jax.Array, *, layer,
+                        kv_scheme: str | None = None,
+                        impl: str = "auto", mesh=None,
+                        head_axis: str | None = None) -> jax.Array:
     """Ragged decode-window attention in ONE kernel launch per tick.
 
     q: (B, W, Hq, dh) — W window positions per slot (spec K+1, or 1 for
@@ -520,21 +552,25 @@ def paged_decode_ragged(q: jax.Array, k_pages, v_pages,
     ctx_len: (B,) int32 tokens already in the pages before this window
     (window position i of slot b attends cache positions <= ctx_len[b] +
     i; ctx_len = q_len = 0 marks an inactive slot, which skips every
-    page). k_pages/v_pages: either the dense (n_pages, Hkv, page_size,
-    dh) pools or the quantized {"codes", "scale"} dicts from
+    page). k_pages/v_pages: every layer's pools, stacked on axis 0 —
+    either the dense (P, n_pages, Hkv, page_size, dh) pools or the
+    quantized {"codes", "scale"} dicts from
     ``nn.attention.paged_kv_write`` — a dict pool routes to the fused-LUT
     variant, with ``kv_scheme`` naming the codebook (required then).
-    Returns (B, W, Hq, dh).
+    layer: int32 scalar, the layer read. Returns (B, W, Hq, dh).
 
     The per-slot attend_len = ctx_len + q_len drives the kernel's page
     loop trip count directly — no pow2 window padding, so varying
     attend_len across ticks never retraces. Block tables and both length
-    vectors ride as scalar prefetch. ``mesh`` as in ``spx_matmul``.
+    vectors ride as scalar prefetch. Under a ``mesh`` whose ``head_axis``
+    divides Hkv, each device attends with its own KV heads of the
+    head-sharded pools; otherwise as in ``spx_matmul``.
     """
     _OP_CALLS["paged_decode_ragged"] += 1
     quant = isinstance(k_pages, dict)
+    layer = jnp.reshape(layer, (1,)).astype(jnp.int32)
     b, w, hq, dh = q.shape
-    hkv = (k_pages["codes"] if quant else k_pages).shape[1]
+    hkv = (k_pages["codes"] if quant else k_pages).shape[2]
     assert hq % hkv == 0, (hq, hkv)
     rep = hq // hkv
     # (B, W, Hq, dh) -> (B, Hkv, rep * w, dh), rep-major: row r * w + i is
@@ -554,11 +590,13 @@ def paged_decode_ragged(q: jax.Array, k_pages, v_pages,
                              "in-kernel codebook")
         lut = spx.codebook(spx.scheme_levels(kv_scheme), dtype=jnp.float32)
         args = (q4, k_pages["codes"], k_pages["scale"], v_pages["codes"],
-                v_pages["scale"], block_table, ctx_len, q_len, lut)
-        page_size = k_pages["codes"].shape[2]
+                v_pages["scale"], block_table, ctx_len, q_len, layer, lut)
+        heads = (1, 2, 2, 2, 2) + (None,) * 5
+        page_size = k_pages["codes"].shape[3]
     else:
-        args = (q4, k_pages, v_pages, block_table, ctx_len, q_len)
-        page_size = k_pages.shape[2]
+        args = (q4, k_pages, v_pages, block_table, ctx_len, q_len, layer)
+        heads = (1, 2, 2) + (None,) * 4
+        page_size = k_pages.shape[3]
 
     if entry.impl == "pallas" and planner.autotune_enabled():
         # keyed per workload INCLUDING kv_scheme and the window w (spec
@@ -584,8 +622,59 @@ def paged_decode_ragged(q: jax.Array, k_pages, v_pages,
 
     fn = functools.partial(entry.fn, w=w)
     if entry.impl != "ref":
-        fn = _whole_per_device(fn, mesh)
+        fn = _per_kv_head(fn, mesh, head_axis, hkv, heads, 1)
     out = fn(*args)
     # inverse of the rep-major packing
     return jnp.moveaxis(out.reshape(b, hkv, rep, w, dh), 3, 1) \
               .reshape(b, w, hq, dh)
+
+
+# ---------------------------------------------------------------------------
+# paged_kv_write: a step's new K/V rows into the stacked page pools, in
+# place — registered impls share the signature
+#   fn(pools, rows, layer, block_table, ctx_len, n_valid)
+# ---------------------------------------------------------------------------
+
+registry.register("paged_kv_write", "ref",
+                  priority=registry.PRIORITY_REFERENCE)(
+    ref_impl.paged_kv_write_ref)
+registry.register("paged_kv_write", "pallas",
+                  priority=registry.PRIORITY_ACCELERATOR,
+                  available=_on_tpu)(
+    functools.partial(paged_kv_write_pallas, interpret=False))
+registry.register("paged_kv_write", "interpret",
+                  priority=registry.PRIORITY_DEBUG)(
+    functools.partial(paged_kv_write_pallas, interpret=True))
+
+
+def paged_kv_write(pools, rows, layer, block_table: jax.Array,
+                   ctx_len: jax.Array, n_valid: jax.Array, *,
+                   impl: str = "auto", mesh=None,
+                   head_axis: str | None = None) -> list:
+    """Write rows ``i < n_valid[b]`` of each slot's C-row window to token
+    positions ``ctx_len[b] + i`` of ``pools[k][layer]``; rows past
+    ``n_valid`` (chunk padding, inactive slots) are not written.
+
+    pools: every layer's pool leaves, stacked on axis 0 — each
+    ``(P, n_pages, Hkv, page_size, w)`` with rows ``(B, C, Hkv, w)``, or
+    ``(P, n_pages, Hkv, lanes)`` holding one value a token at lane
+    ``offset`` with rows ``(B, C, Hkv)`` (the SPx scales); the first leaf
+    is of the first kind. layer: int32 scalar; block_table: (B,
+    max_pages) int32. A written page belongs to one slot. The Pallas impl
+    updates the pools in place (aliased); returns the updated leaves.
+    ``mesh``/``head_axis`` as in ``paged_decode_ragged``: every leaf and
+    row block has its KV heads on axis 2."""
+    _OP_CALLS["paged_kv_write"] += 1
+    entry = registry.resolve("paged_kv_write", impl)
+    _OP_IMPLS["paged_kv_write", entry.impl] += 1
+    fn = entry.fn
+    if entry.impl != "ref":
+        n = len(pools)
+        fn = _per_kv_head(lambda *a: tuple(entry.fn(*a)), mesh, head_axis,
+                          pools[0].shape[2],
+                          ([2] * n, [2] * n, None, None, None, None),
+                          (2,) * n)
+    return list(fn(list(pools), list(rows), jnp.asarray(layer, jnp.int32),
+                   jnp.asarray(block_table, jnp.int32),
+                   jnp.asarray(ctx_len, jnp.int32),
+                   jnp.asarray(n_valid, jnp.int32)))

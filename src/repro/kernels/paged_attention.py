@@ -18,6 +18,10 @@ sequence's context length are skipped (pl.when), and positions beyond
 ``ctx_len`` inside the last page are masked; unused block-table slots
 point at page 0, whose DMA is wasted but whose values are never read.
 
+The ragged decode kernels (one launch per decode tick) take every
+layer's pools stacked on a leading axis plus the layer index as one more
+scalar prefetch, so the serving layer scan never slices a layer out.
+
 Page size comes from ``runtime.planner.plan_kv_pages`` — the same
 VMEM-budget model the matmul tiles use.
 """
@@ -76,6 +80,12 @@ def _page_update(q, k, v, ctx, j, *, scale, page_size, m_ref, l_ref,
     m_ref[...] = m_new
 
 
+def _token_scales(s_ref, h, page_size: int):
+    """KV head ``h``'s per-token scales, (1, page_size), from its page's
+    (1, Hkv, lanes) scale block (lane t = token t)."""
+    return s_ref[0, pl.ds(h, 1), pl.ds(0, page_size)]
+
+
 def _finalize_out(o_ref, m_ref, l_ref, acc_ref, out_dtype):
     # ctx == 0 rows (inactive slots) never ran a page: l == 0, out == 0
     denom = jnp.maximum(l_ref[...], 1e-30)
@@ -116,6 +126,7 @@ def _quant_kernel(bt_ref, ctx_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
     resident for the whole grid, where ``decode.lut_operand`` put it."""
     del bt_ref
     b = pl.program_id(0)
+    h = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -131,8 +142,8 @@ def _quant_kernel(bt_ref, ctx_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
         _page_update(q_ref[0, 0], k, v, ctx, j, scale=scale,
                      page_size=page_size, m_ref=m_ref, l_ref=l_ref,
                      acc_ref=acc_ref,
-                     k_scale=ks_ref[0, 0][:, 0][None, :],
-                     v_scale=vs_ref[0, 0][:, 0][None, :])
+                     k_scale=_token_scales(ks_ref, h, page_size),
+                     v_scale=_token_scales(vs_ref, h, page_size))
 
     @pl.when(j == n_logical - 1)
     def _finalize():
@@ -178,10 +189,10 @@ def _ragged_page_update(q, k, v, ctx, qn, j, *, scale, page_size, w,
     m_ref[...] = m_new
 
 
-def _ragged_kernel(bt_ref, ctx_ref, qn_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, scale: float, page_size: int,
-                   w: int, n_logical: int, out_dtype):
-    del bt_ref                    # consumed by the BlockSpec index maps
+def _ragged_kernel(bt_ref, ctx_ref, qn_ref, layer_ref, q_ref, k_ref, v_ref,
+                   o_ref, m_ref, l_ref, acc_ref, *, scale: float,
+                   page_size: int, w: int, n_logical: int, out_dtype):
+    del bt_ref, layer_ref         # consumed by the BlockSpec index maps
     b = pl.program_id(0)
     j = pl.program_id(2)
 
@@ -206,16 +217,17 @@ def _ragged_kernel(bt_ref, ctx_ref, qn_ref, q_ref, k_ref, v_ref, o_ref,
         _finalize_out(o_ref, m_ref, l_ref, acc_ref, out_dtype)
 
 
-def _ragged_quant_kernel(bt_ref, ctx_ref, qn_ref, q_ref, kc_ref, ks_ref,
-                         vc_ref, vs_ref, lut_ref, o_ref, m_ref, l_ref,
+def _ragged_quant_kernel(bt_ref, ctx_ref, qn_ref, layer_ref, q_ref, kc_ref,
+                         ks_ref, vc_ref, vs_ref, lut_ref, o_ref, m_ref, l_ref,
                          acc_ref, *, scale: float, page_size: int, w: int,
                          n_logical: int, out_dtype):
     """Fused-LUT ragged megakernel: K/V pages stream as uint8 codes +
     per-token scale, the <=256-entry codebook stays resident for the whole
     grid, and dequantization happens page-by-page right before the MXU —
     the §3.2 memory win on the one launch the decode tick makes."""
-    del bt_ref
+    del bt_ref, layer_ref
     b = pl.program_id(0)
+    h = pl.program_id(1)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
@@ -232,8 +244,8 @@ def _ragged_quant_kernel(bt_ref, ctx_ref, qn_ref, q_ref, kc_ref, ks_ref,
         _ragged_page_update(q_ref[0, 0], k, v, ctx, qn, j, scale=scale,
                             page_size=page_size, w=w, m_ref=m_ref,
                             l_ref=l_ref, acc_ref=acc_ref,
-                            k_scale=ks_ref[0, 0][:, 0][None, :],
-                            v_scale=vs_ref[0, 0][:, 0][None, :])
+                            k_scale=_token_scales(ks_ref, h, page_size),
+                            v_scale=_token_scales(vs_ref, h, page_size))
 
     @pl.when(j == n_logical - 1)
     def _finalize():
@@ -243,7 +255,7 @@ def _ragged_quant_kernel(bt_ref, ctx_ref, qn_ref, q_ref, kc_ref, ks_ref,
 @functools.partial(jax.jit,
                    static_argnames=("w", "out_dtype", "interpret"))
 def paged_decode_ragged_pallas(q, k_pages, v_pages, block_table, ctx_len,
-                               q_len, *, w: int, out_dtype=None,
+                               q_len, layer, *, w: int, out_dtype=None,
                                interpret: bool = False):
     """Ragged decode-window attention in one launch.
 
@@ -252,28 +264,32 @@ def paged_decode_ragged_pallas(q, k_pages, v_pages, block_table, ctx_len,
     ``w`` is the static window length (spec K+1; 1 = plain decode);
     q_len: (B,) int32 valid rows per slot (ragged; rows past it come back
     zero); ctx_len: (B,) int32 tokens in the pages before the window.
-    Pools/block_table as in ``paged_attention_pallas``.
+    k_pages/v_pages: every layer's pools, (P, n_pages, Hkv, page_size,
+    dh); layer: (1,) int32, the layer read, scalar-prefetched into the
+    page index maps. block_table as in ``paged_attention_pallas``.
     Returns (B, Hkv, R, dh).
     """
     b, hkv, rows, dh = q.shape
-    _, _, page_size, _ = k_pages.shape
+    page_size = k_pages.shape[3]
     max_pages = block_table.shape[1]
     out_dtype = out_dtype or q.dtype
     scale = 1.0 / (dh ** 0.5)
 
+    page = pl.BlockSpec((None, 1, 1, page_size, dh),
+                        lambda bb, h, j, bt, ctx, qn, l:
+                        (l[0], bt[bb, j], h, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,             # block_table, ctx_len, q_len
+        num_scalar_prefetch=4,        # block_table, ctx_len, q_len, layer
         grid=(b, hkv, max_pages),
         in_specs=[
             pl.BlockSpec((1, 1, rows, dh),
-                         lambda bb, h, j, bt, ctx, qn: (bb, h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, dh),
-                         lambda bb, h, j, bt, ctx, qn: (bt[bb, j], h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, dh),
-                         lambda bb, h, j, bt, ctx, qn: (bt[bb, j], h, 0, 0)),
+                         lambda bb, h, j, bt, ctx, qn, l: (bb, h, 0, 0)),
+            page,
+            page,
         ],
         out_specs=pl.BlockSpec((1, 1, rows, dh),
-                               lambda bb, h, j, bt, ctx, qn: (bb, h, 0, 0)),
+                               lambda bb, h, j, bt, ctx, qn, l:
+                               (bb, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),     # running max m
             pltpu.VMEM((rows, 1), jnp.float32),     # running denom l
@@ -288,47 +304,53 @@ def paged_decode_ragged_pallas(q, k_pages, v_pages, block_table, ctx_len,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(block_table, ctx_len, q_len, q, k_pages, v_pages)
+    )(block_table, ctx_len, q_len, layer, q, k_pages, v_pages)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("w", "out_dtype", "interpret"))
 def paged_decode_ragged_quant_pallas(q, k_codes, k_scale, v_codes, v_scale,
-                                     block_table, ctx_len, q_len, lut, *,
-                                     w: int, out_dtype=None,
+                                     block_table, ctx_len, q_len, layer, lut,
+                                     *, w: int, out_dtype=None,
                                      interpret: bool = False):
     """Fused-LUT ragged decode window over quantized (codes + scale) KV
     pools: same grid and per-row causal masking as
     ``paged_decode_ragged_pallas``, but each streamed page is 1-byte
     codes + per-token scale, dequantized in VMEM against the resident
-    codebook before the MXU. Args as ``paged_attention_quant_pallas``
-    plus ``q_len``/``w``. Returns (B, Hkv, R, dh)."""
+    codebook before the MXU. k_codes/v_codes: (P, n_pages, Hkv,
+    page_size, dh) uint8; k_scale/v_scale: (P, n_pages, Hkv, lanes) f32;
+    other args as ``paged_decode_ragged_pallas`` and
+    ``paged_attention_quant_pallas``. Returns (B, Hkv, R, dh)."""
     b, hkv, rows, dh = q.shape
-    _, _, page_size, _ = k_codes.shape
+    page_size = k_codes.shape[3]
+    lanes = k_scale.shape[3]
     max_pages = block_table.shape[1]
     out_dtype = out_dtype or q.dtype
     scale = 1.0 / (dh ** 0.5)
 
-    def page_spec(width):
-        return pl.BlockSpec(
-            (1, 1, page_size, width),
-            lambda bb, h, j, bt, ctx, qn: (bt[bb, j], h, 0, 0))
+    codes = pl.BlockSpec((None, 1, 1, page_size, dh),
+                         lambda bb, h, j, bt, ctx, qn, l:
+                         (l[0], bt[bb, j], h, 0, 0))
+    scales = pl.BlockSpec((None, 1, hkv, lanes),
+                          lambda bb, h, j, bt, ctx, qn, l:
+                          (l[0], bt[bb, j], 0, 0))
     lut, lut_spec = lut_operand(lut, dh, "paged_decode_ragged_quant")
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,             # block_table, ctx_len, q_len
+        num_scalar_prefetch=4,        # block_table, ctx_len, q_len, layer
         grid=(b, hkv, max_pages),
         in_specs=[
             pl.BlockSpec((1, 1, rows, dh),
-                         lambda bb, h, j, bt, ctx, qn: (bb, h, 0, 0)),
-            page_spec(dh),                 # k codes
-            page_spec(1),                  # k scale
-            page_spec(dh),                 # v codes
-            page_spec(1),                  # v scale
+                         lambda bb, h, j, bt, ctx, qn, l: (bb, h, 0, 0)),
+            codes,                         # k codes
+            scales,                        # k scale
+            codes,                         # v codes
+            scales,                        # v scale
             lut_spec,                      # whole LUT, resident
         ],
         out_specs=pl.BlockSpec((1, 1, rows, dh),
-                               lambda bb, h, j, bt, ctx, qn: (bb, h, 0, 0)),
+                               lambda bb, h, j, bt, ctx, qn, l:
+                               (bb, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((rows, 1), jnp.float32),     # running max m
             pltpu.VMEM((rows, 1), jnp.float32),     # running denom l
@@ -344,8 +366,8 @@ def paged_decode_ragged_quant_pallas(q, k_codes, k_scale, v_codes, v_scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(block_table, ctx_len, q_len, q, k_codes, k_scale, v_codes, v_scale,
-      lut)
+    )(block_table, ctx_len, q_len, layer, q, k_codes, k_scale, v_codes,
+      v_scale, lut)
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype", "interpret"))
@@ -397,8 +419,8 @@ def paged_attention_quant_pallas(q, k_codes, k_scale, v_codes, v_scale,
     """Fused-dequant paged decode attention (quantized KV pools).
 
     q: (B, Hkv, rep, dh); k_codes/v_codes: (n_pages, Hkv, page_size, dh)
-    uint8 codebook codes; k_scale/v_scale: (n_pages, Hkv, page_size, 1)
-    f32 per-token scales; lut: (2^w,) f32 codebook (spx.codebook of the KV
+    uint8 codebook codes; k_scale/v_scale: (n_pages, Hkv, lanes) f32
+    per-token scales, lane t the page's token t; lut: (2^w,) f32 codebook (spx.codebook of the KV
     scheme — a static per-scheme constant); block_table/ctx_len as in
     ``paged_attention_pallas``. Returns (B, Hkv, rep, dh).
 
@@ -413,9 +435,10 @@ def paged_attention_quant_pallas(q, k_codes, k_scale, v_codes, v_scale,
     out_dtype = out_dtype or q.dtype
     scale = 1.0 / (dh ** 0.5)
 
-    def page_spec(width):
-        return pl.BlockSpec((1, 1, page_size, width),
-                            lambda bb, h, j, bt, ctx: (bt[bb, j], h, 0, 0))
+    codes = pl.BlockSpec((1, 1, page_size, dh),
+                         lambda bb, h, j, bt, ctx: (bt[bb, j], h, 0, 0))
+    scales = pl.BlockSpec((1, hkv, k_scale.shape[2]),
+                          lambda bb, h, j, bt, ctx: (bt[bb, j], 0, 0))
     lut, lut_spec = lut_operand(lut, dh, "paged_attention_quant")
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -424,10 +447,10 @@ def paged_attention_quant_pallas(q, k_codes, k_scale, v_codes, v_scale,
         in_specs=[
             pl.BlockSpec((1, 1, rep, dh),
                          lambda bb, h, j, bt, ctx: (bb, h, 0, 0)),
-            page_spec(dh),                 # k codes
-            page_spec(1),                  # k scale
-            page_spec(dh),                 # v codes
-            page_spec(1),                  # v scale
+            codes,                         # k codes
+            scales,                        # k scale
+            codes,                         # v codes
+            scales,                        # v scale
             lut_spec,                      # whole LUT, resident
         ],
         out_specs=pl.BlockSpec((1, 1, rep, dh),

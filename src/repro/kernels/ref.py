@@ -13,7 +13,8 @@ from repro.core import spx
 __all__ = ["spx_matmul_ref", "spx_expert_matmul_ref", "attention_ref",
            "paged_attention_ref",
            "paged_attention_quant_ref", "paged_decode_ragged_ref",
-           "paged_decode_ragged_quant_ref"]
+           "paged_decode_ragged_quant_ref", "paged_kv_write_ref",
+           "page_scales"]
 
 
 def spx_matmul_ref(x, codes, scale, lut, *, packed: bool, out_dtype=None):
@@ -48,6 +49,37 @@ def spx_expert_matmul_ref(x, codes, scale, lut, tile_expert, tile_rows, *,
     out = jax.lax.map(tile, (x.reshape(g, bm, x.shape[-1]), tile_expert,
                              tile_rows))
     return out.reshape(g * bm, out.shape[-1])
+
+
+def paged_kv_write_ref(pools, rows, layer, block_table, ctx_len, n_valid):
+    """Scatter rows ``i < n_valid[b]`` of each slot's window to positions
+    ``ctx_len[b] + i`` of ``pools[k][layer]`` — the oracle of
+    ``paged_kv_write_pallas`` (args as there). Rows past ``n_valid`` are
+    dropped through an out-of-range page index."""
+    b, c = rows[0].shape[:2]
+    n_pages, page_size = pools[0].shape[1], pools[0].shape[3]
+    positions = ctx_len[:, None] + jnp.arange(c, dtype=jnp.int32)
+    phys = jnp.take_along_axis(
+        block_table, jnp.clip(positions // page_size, 0,
+                              block_table.shape[1] - 1), axis=1)
+    phys = jnp.where(jnp.arange(c)[None, :] < n_valid[:, None], phys,
+                     n_pages).reshape(-1)
+    off = (positions % page_size).reshape(-1)
+    out = []
+    for pool, new in zip(pools, rows):
+        flat = new.reshape((b * c,) + new.shape[2:]).astype(pool.dtype)
+        at = (pool.at[layer, phys, :, off, :] if pool.ndim == 5
+              else pool.at[layer, phys, :, off])
+        out.append(at.set(flat, mode="drop"))
+    return out
+
+
+def page_scales(scale_pages, page_size: int):
+    """Gathered scale pages (B, max_pages, Hkv, lanes), lane t the scale
+    of the page's token t, as a contiguous (B, Hkv, S, 1) view."""
+    b, mp, hkv = scale_pages.shape[:3]
+    a = jnp.moveaxis(scale_pages[..., :page_size], 2, 1)
+    return a.reshape(b, hkv, mp * page_size, 1)
 
 
 def paged_attention_ref(q, k_pages, v_pages, block_table, ctx_len, *,
@@ -104,7 +136,8 @@ def paged_attention_quant_ref(q, k_codes, k_scale, v_codes, v_scale,
     the fused-dequant Pallas kernel must match.
 
     k_codes/v_codes: (n_pages, Hkv, page_size, dh) uint8; k_scale/v_scale:
-    (n_pages, Hkv, page_size, 1) f32; lut: (2^w,) f32 codebook
+    (n_pages, Hkv, lanes) f32, lane t the scale of the page's token t
+    (``spx.kv_scale_lanes``); lut: (2^w,) f32 codebook
     (spx.codebook of the KV scheme). Other args as paged_attention_ref.
     """
     out_dtype = out_dtype or q.dtype
@@ -114,7 +147,7 @@ def paged_attention_quant_ref(q, k_codes, k_scale, v_codes, v_scale,
 
     def gather_dequant(codes, scale):
         c = jnp.moveaxis(codes[block_table], 2, 1).reshape(b, hkv, s_max, dh)
-        a = jnp.moveaxis(scale[block_table], 2, 1).reshape(b, hkv, s_max, 1)
+        a = page_scales(scale[block_table], ps)
         return jnp.take(lut, c.astype(jnp.int32), axis=0) * a
 
     k = gather_dequant(k_codes, k_scale)
@@ -192,7 +225,7 @@ def paged_decode_ragged_quant_ref(q, k_codes, k_scale, v_codes, v_scale,
 
     def gather_dequant(codes, scale):
         c = jnp.moveaxis(codes[block_table], 2, 1).reshape(b, hkv, s_max, dh)
-        a = jnp.moveaxis(scale[block_table], 2, 1).reshape(b, hkv, s_max, 1)
+        a = page_scales(scale[block_table], ps)
         return jnp.take(lut, c.astype(jnp.int32), axis=0) * a
 
     k = gather_dequant(k_codes, k_scale)

@@ -34,7 +34,8 @@ from .rotary import apply_mrope, apply_rope
 
 __all__ = ["attn_init", "attn_apply_dense", "attention_core",
            "decode_attention", "attn_decode_step", "paged_kv_write",
-           "attn_paged_step", "quantize_kv", "dequantize_kv", "kv_lut"]
+           "attn_paged_step", "quantize_kv", "quantize_pages", "dequantize_kv",
+           "kv_lut"]
 
 _NEG = -1e30
 
@@ -270,6 +271,18 @@ def quantize_kv(x, scheme: str = "uniform8", axis=-1):
     return codes, scale
 
 
+def quantize_pages(pages, scheme: str = "uniform8"):
+    """Page pools of values (..., n_pages, Hkv, page_size, dh) as the
+    quantized pool {"codes" uint8 (..., page_size, dh), "scale" f32
+    (..., n_pages, Hkv, lanes)}: the scale of a page's token t at lane t,
+    the lanes past the page holding 1 as a fresh pool does."""
+    codes, scale = quantize_kv(pages, scheme)
+    pad = spx.kv_scale_lanes(pages.shape[-2]) - pages.shape[-2]
+    scale = jnp.pad(scale[..., 0], [(0, 0)] * (scale.ndim - 2) + [(0, pad)],
+                    constant_values=1.0)
+    return {"codes": codes, "scale": scale}
+
+
 def dequantize_kv(codes, scale, scheme: str = "uniform8",
                   dtype=jnp.float32):
     """codes (uint8) + per-position scale -> values: lut[codes] * scale."""
@@ -416,88 +429,83 @@ def _n_axes(mesh, axes) -> int:
 # Paged KV cache: chunked prefill + paged decode (serving — docs/SERVING.md)
 # ---------------------------------------------------------------------------
 
-def paged_kv_write(k_pages, v_pages, k_new, v_new, block_table, positions,
-                   valid, kv_scheme: str = "uniform8"):
-    """Scatter a chunk of new K/V rows into the physical page pools.
+def paged_kv_write(k_pages, v_pages, k_new, v_new, layer, block_table,
+                   ctx_len, n_valid, *, kv_scheme: str = "uniform8",
+                   impl: str = "auto", mesh=None, head_axis=None):
+    """Write a chunk of new K/V rows into the stacked page pools, in
+    place (``ops.paged_kv_write``).
 
-    k_pages/v_pages: (n_pages, Hkv, page_size, dh) arrays, OR dicts
-    {"codes" uint8 (n_pages, Hkv, page_size, dh), "scale" f32
-    (n_pages, Hkv, page_size, 1)} for the quantized pool (codes under
-    ``kv_scheme``); k_new/v_new: (B, C, Hkv, dh); block_table:
-    (B, max_pages) int32; positions: (B, C) absolute token positions;
-    valid: (B, C) bool — False rows (chunk padding, inactive slots) are
-    dropped via an out-of-range scatter index instead of a masked
-    read-modify-write.
+    k_pages/v_pages: every layer's pools, (P, n_pages, Hkv, page_size,
+    dh) arrays, OR dicts {"codes" uint8 (P, n_pages, Hkv, page_size, dh),
+    "scale" f32 (P, n_pages, Hkv, lanes)} for the quantized pool (codes
+    under ``kv_scheme``, the scale of a page's token t at lane t);
+    k_new/v_new: (B, C, Hkv, dh); layer: int32 scalar, the pools' layer
+    written; block_table: (B, max_pages) int32; row i of slot b goes to
+    position ``ctx_len[b] + i`` if ``i < n_valid[b]`` — the other rows
+    (chunk padding, inactive slots) are not written. ``mesh`` and
+    ``head_axis`` as in ``ops.paged_kv_write``.
     """
     quantized = isinstance(k_pages, dict)
-    n_pages, hkv, ps, dh = (k_pages["codes"] if quantized
-                            else k_pages).shape
-    logical = positions // ps                            # (B, C)
-    phys = jnp.take_along_axis(block_table,
-                               jnp.clip(logical, 0,
-                                        block_table.shape[1] - 1), axis=1)
-    phys = jnp.where(valid, phys, n_pages)               # OOB -> dropped
-    off = positions % ps
-    flat_p = phys.reshape(-1)
-    flat_o = off.reshape(-1)
-
-    def scatter(pages, new, width):
-        flat = new.reshape(-1, hkv, width).astype(pages.dtype)
-        return pages.at[flat_p, :, flat_o, :].set(flat, mode="drop")
-
     if quantized:
         kc, ks = quantize_kv(k_new, kv_scheme)    # (B,C,Hkv,dh), (B,C,Hkv,1)
         vc, vs = quantize_kv(v_new, kv_scheme)
-        k_pages = {"codes": scatter(k_pages["codes"], kc, dh),
-                   "scale": scatter(k_pages["scale"], ks, 1)}
-        v_pages = {"codes": scatter(v_pages["codes"], vc, dh),
-                   "scale": scatter(v_pages["scale"], vs, 1)}
-        return k_pages, v_pages
-    return scatter(k_pages, k_new, dh), scatter(v_pages, v_new, dh)
+        pools = [k_pages["codes"], k_pages["scale"], v_pages["codes"],
+                 v_pages["scale"]]
+        rows = [kc, ks[..., 0], vc, vs[..., 0]]
+    else:
+        pools, rows = [k_pages, v_pages], [k_new, v_new]
+    out = ops.paged_kv_write(pools, rows, layer, block_table, ctx_len,
+                             n_valid, impl=impl, mesh=mesh,
+                             head_axis=head_axis)
+    if quantized:
+        return ({"codes": out[0], "scale": out[1]},
+                {"codes": out[2], "scale": out[3]})
+    return out[0], out[1]
 
 
-def _gather_pages(pages, block_table, kv_scheme: str):
-    """Gather one sequence's pages into a contiguous (B, Hkv, S, dh) view;
-    dict (quantized) pools are dequantized after the gather, so the f32
-    values are materialized *context-sized* (S = max_pages x page_size)
-    per chunk call — only the HBM-resident pool stays 1 byte/element.
-    That's the prefill path's trade (compute-bound, gather amortized);
-    the decode hot path never does this, it streams codes through the
-    fused-dequant kernel instead."""
+def _gather_pages(pages, layer, block_table, kv_scheme: str):
+    """Gather one sequence's pages of one layer into a contiguous
+    (B, Hkv, S, dh) view; the layer and page indices form one gather, so
+    no layer of the stacked pool is sliced out first. Dict (quantized)
+    pools are dequantized after the gather, so the f32 values are
+    materialized *context-sized* (S = max_pages x page_size) per chunk
+    call — only the HBM-resident pool stays 1 byte/element. That's the
+    prefill path's trade (compute-bound, gather amortized); the decode
+    hot path never does this, it streams codes through the fused-dequant
+    kernel instead."""
     bt = block_table
-    if isinstance(pages, dict):
-        b = bt.shape[0]
-        hkv, ps, dh = pages["codes"].shape[1:]
-        s_max = bt.shape[1] * ps
-        codes = jnp.moveaxis(pages["codes"][bt], 2, 1) \
-            .reshape(b, hkv, s_max, dh)
-        scale = jnp.moveaxis(pages["scale"][bt], 2, 1) \
-            .reshape(b, hkv, s_max, 1)
-        return dequantize_kv(codes, scale, kv_scheme, dtype=jnp.float32)
     b = bt.shape[0]
-    hkv, ps, dh = pages.shape[1:]
-    return jnp.moveaxis(pages[bt], 2, 1).reshape(b, hkv, bt.shape[1] * ps,
-                                                 dh)
+    if isinstance(pages, dict):
+        hkv, ps, dh = pages["codes"].shape[2:]
+        codes = jnp.moveaxis(pages["codes"][layer, bt], 2, 1) \
+            .reshape(b, hkv, bt.shape[1] * ps, dh)
+        scale = kref.page_scales(pages["scale"][layer, bt], ps)
+        return dequantize_kv(codes, scale, kv_scheme, dtype=jnp.float32)
+    hkv, ps, dh = pages.shape[2:]
+    return jnp.moveaxis(pages[layer, bt], 2, 1).reshape(
+        b, hkv, bt.shape[1] * ps, dh)
 
 
-def _paged_chunk_attention(q, k_pages, v_pages, block_table, positions,
-                           attend_len, kv_scheme: str = "uniform8"):
+def _paged_chunk_attention(q, k_pages, v_pages, layer, block_table,
+                           positions, attend_len,
+                           kv_scheme: str = "uniform8"):
     """Attention of a C-token chunk against the full paged context
     (including the chunk itself, already written to the pages).
 
-    q: (B, Hq, C, dh); positions: (B, C) absolute query positions;
-    attend_len: (B,) total attendable tokens. Gathers this sequence's
-    pages into a contiguous view — prefill is compute-bound, so the
-    gather's bytes are amortized; the single-token hot path goes through
-    the paged-attention kernel instead. Returns (B, Hq, C, dh).
+    q: (B, Hq, C, dh); k_pages/v_pages: the stacked pools, read at
+    ``layer``; positions: (B, C) absolute query positions; attend_len:
+    (B,) total attendable tokens. Gathers this sequence's pages into a
+    contiguous view — prefill is compute-bound, so the gather's bytes are
+    amortized; the single-token hot path goes through the paged-attention
+    kernel instead. Returns (B, Hq, C, dh).
     """
     b, hq, c, dh = q.shape
     quantized = isinstance(k_pages, dict)
-    hkv, ps = (k_pages["codes"] if quantized else k_pages).shape[1:3]
+    hkv, ps = (k_pages["codes"] if quantized else k_pages).shape[2:4]
     s_max = block_table.shape[1] * ps
     rep = hq // hkv
-    k = _gather_pages(k_pages, block_table, kv_scheme)
-    v = _gather_pages(v_pages, block_table, kv_scheme)
+    k = _gather_pages(k_pages, layer, block_table, kv_scheme)
+    v = _gather_pages(v_pages, layer, block_table, kv_scheme)
     if rep > 1:
         k = jnp.repeat(k, rep, axis=1)
         v = jnp.repeat(v, rep, axis=1)
@@ -515,10 +523,10 @@ def _paged_chunk_attention(q, k_pages, v_pages, block_table, positions,
 
 
 def attn_paged_step(p: dict, x: jax.Array, ctx_len: jax.Array,
-                    block_table: jax.Array, cache: dict, *, n_heads: int,
-                    n_kv_heads: int, head_dim: int, n_valid: jax.Array,
-                    rope_theta: float = 10000.0, mrope_sections=None,
-                    rt: Runtime, fused: bool = False):
+                    block_table: jax.Array, cache: dict, layer, *,
+                    n_heads: int, n_kv_heads: int, head_dim: int,
+                    n_valid: jax.Array, rope_theta: float = 10000.0,
+                    mrope_sections=None, rt: Runtime, fused: bool = False):
     """Attention sublayer over the paged KV cache — one code path for both
     chunked prefill (C > 1) and decode (C == 1, dispatched to the
     paged-attention kernel via the registry).
@@ -526,10 +534,12 @@ def attn_paged_step(p: dict, x: jax.Array, ctx_len: jax.Array,
     x: (B, C, D) — the next C tokens of each sequence; ctx_len: (B,) int32
     tokens already in the pages; n_valid: (B,) int32 valid tokens in this
     chunk (< C for ragged tails / inactive rows — invalid tokens are
-    neither written nor trusted); cache: {"kp", "vp"} physical pools —
-    arrays, or {"codes", "scale"} dicts for the quantized pool
-    (``rt.kv_scheme`` picks the level set; decode then dispatches to the
-    fused-dequant paged-attention kernel). ``fused`` routes the attention
+    neither written nor trusted); cache: {"kp", "vp"} physical pools of
+    every layer, stacked on axis 0 — arrays, or {"codes", "scale"} dicts
+    for the quantized pool (``rt.kv_scheme`` picks the level set; decode
+    then dispatches to the fused-dequant paged-attention kernel); layer:
+    int32 scalar, this sublayer's index into them — the new rows are
+    written and read there in place. ``fused`` routes the attention
     through the ragged decode megakernel (``ops.paged_decode_ragged``) —
     one launch for the whole ragged window, n_valid as the per-slot
     ``q_len``, dense or quantized pools alike; the serving engine's
@@ -552,37 +562,39 @@ def attn_paged_step(p: dict, x: jax.Array, ctx_len: jax.Array,
                                  mrope_sections)
     else:
         q, k = _apply_positional(q, k, positions, rope_theta, None)
-    valid = jnp.arange(c)[None, :] < n_valid[:, None]               # (B, C)
     with jax.named_scope("kv_write"):
-        kp, vp = paged_kv_write(cache["kp"], cache["vp"], k, v,
-                                block_table, positions, valid,
-                                kv_scheme=rt.kv_scheme)
+        kp, vp = paged_kv_write(cache["kp"], cache["vp"], k, v, layer,
+                                block_table, ctx_len, n_valid,
+                                kv_scheme=rt.kv_scheme, impl=rt.impl,
+                                mesh=rt.mesh, head_axis=rt.model_axis)
     attend_len = ctx_len + n_valid
     if fused:
         # one megakernel launch for the whole (slot, attend_len) ragged
         # window — window row i of slot b attends cache positions
         # <= ctx_len[b] + i, rows past n_valid[b] come back zero (unused)
         out = ops.paged_decode_ragged(
-            q, kp, vp, block_table, ctx_len, n_valid,
+            q, kp, vp, block_table, ctx_len, n_valid, layer=layer,
             kv_scheme=rt.kv_scheme if quantized else None, impl=rt.impl,
-            mesh=rt.mesh)
+            mesh=rt.mesh, head_axis=rt.model_axis)
         o = out.reshape(b, c, n_heads * head_dim)
     elif c == 1:
+        # the unfused kernels read one layer's pools (a copy of it)
+        kl, vl = jax.tree_util.tree_map(lambda a: a[layer], (kp, vp))
         q1 = q[:, 0].reshape(b, n_heads, head_dim)
         if quantized:
-            out = ops.paged_attention_quant(q1, kp, vp, block_table,
+            out = ops.paged_attention_quant(q1, kl, vl, block_table,
                                             attend_len,
                                             kv_scheme=rt.kv_scheme,
                                             impl=rt.impl, mesh=rt.mesh)
         else:
-            out = ops.paged_attention(q1, kp, vp, block_table, attend_len,
+            out = ops.paged_attention(q1, kl, vl, block_table, attend_len,
                                       impl=rt.impl, mesh=rt.mesh)
-        o = out[:, None]                                 # (B, 1, Hq*dh)->..
-        o = o.reshape(b, 1, n_heads * head_dim)
+        o = out.reshape(b, 1, n_heads * head_dim)
     else:
         qh = jnp.swapaxes(q, 1, 2)                       # (B, Hq, C, dh)
-        o = _paged_chunk_attention(qh, kp, vp, block_table, positions,
-                                   attend_len, kv_scheme=rt.kv_scheme)
+        o = _paged_chunk_attention(qh, kp, vp, layer, block_table,
+                                   positions, attend_len,
+                                   kv_scheme=rt.kv_scheme)
         o = jnp.swapaxes(o, 1, 2).reshape(b, c, n_heads * head_dim)
     y = dense_apply(p["wo"], o, rt)
     return y, dict(cache, kp=kp, vp=vp)

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.core.spx import kv_scale_lanes
 from repro.runtime import Runtime
 
 from . import ssm
@@ -125,21 +126,23 @@ def _cross_kv(p_attn: dict, enc_out: jax.Array, n_kv_heads: int,
     return k, v
 
 
-def _slab_step(cache: dict, state_idx, n_valid, step_fn):
+def _slab_step(cache: dict, layer, state_idx, n_valid, step_fn):
     """Run an SSM paged step against the slab region: gather each row's
-    slab (``state_idx[:, 0]``), step, scatter the new state back. Rows
-    with ``n_valid == 0`` (inactive slots in a mixed prefill/decode tick)
-    and rows whose slab index is the out-of-range sentinel are dropped by
-    the scatter — their slabs stay bit-identical (the step itself is also
-    identity-masked, so this is belt and braces). ``cache``: per-slot
-    state leaves shaped (n_slabs, ...)."""
+    slab (``state_idx[:, 0]``) of ``layer``, step, scatter the new state
+    back in place. Rows with ``n_valid == 0`` (inactive slots in a mixed
+    prefill/decode tick) and rows whose slab index is the out-of-range
+    sentinel are dropped by the scatter — their slabs stay bit-identical
+    (the step itself is also identity-masked, so this is belt and
+    braces). ``cache``: per-slot state leaves of every layer, shaped
+    (P, n_slabs, ...)."""
     slab_idx = state_idx[:, 0].astype(jnp.int32)
-    n_slabs = next(iter(cache.values())).shape[0]
+    n_slabs = next(iter(cache.values())).shape[1]
     safe = jnp.clip(slab_idx, 0, max(n_slabs - 1, 0))
-    state_b = {k: leaf[safe] for k, leaf in cache.items()}
+    state_b = {k: leaf[layer, safe] for k, leaf in cache.items()}
     y, ns = step_fn(state_b)
     dst = jnp.where(n_valid > 0, slab_idx, n_slabs)
-    new_cache = {k: leaf.at[dst].set(ns[k].astype(leaf.dtype), mode="drop")
+    new_cache = {k: leaf.at[layer, dst].set(ns[k].astype(leaf.dtype),
+                                            mode="drop")
                  for k, leaf in cache.items()}
     return y, new_cache
 
@@ -147,15 +150,17 @@ def _slab_step(cache: dict, state_idx, n_valid, step_fn):
 def _slot_apply(slot: str, p: dict, x, positions, cfg: ArchConfig,
                 rt: Runtime, *, mode: str, cache=None, pos=None,
                 enc_out=None, causal: bool = True, paged_ctx=None,
-                fused: bool = False):
+                fused: bool = False, layer=None):
     """mode: 'train' | 'prefill' | 'decode' | 'paged'. Returns
     (x, new_cache, aux). Paged mode (serving: chunked prefill + paged
     decode through one path) takes ``paged_ctx = (ctx_len, block_table,
-    n_valid, state_idx)`` and routes per *slot kind*: attention and
-    decoder self-attention write token pages, SSM mixers read/write their
-    row of the slab region (``state_idx[:, 0]``), cross-attention reads
-    the shared read-only cross region (``state_idx[:, 1]``) — one
-    state-cache, heterogeneous layers."""
+    n_valid, state_idx)``, the slot's regions of every period as
+    ``cache`` and the period index ``layer``, and routes per *slot kind*:
+    attention and decoder self-attention write token pages, SSM mixers
+    read/write their row of the slab region (``state_idx[:, 0]``),
+    cross-attention reads the shared read-only cross region
+    (``state_idx[:, 1]``) — one state-cache, heterogeneous layers, each
+    region read and written in place at ``layer``."""
     mixer, ffn = _parse_slot(slot)
     aux = jnp.zeros((), jnp.float32)
     new_cache = cache
@@ -167,7 +172,7 @@ def _slot_apply(slot: str, p: dict, x, positions, cfg: ArchConfig,
         if mode == "paged":
             with jax.named_scope("attention"):
                 y, new_cache = attn_paged_step(
-                    p["attn"], h, ctx_len, block_table, cache,
+                    p["attn"], h, ctx_len, block_table, cache, layer,
                     n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
                     head_dim=cfg.dh, n_valid=n_valid,
                     rope_theta=_rope_theta(cfg),
@@ -221,11 +226,11 @@ def _slot_apply(slot: str, p: dict, x, positions, cfg: ArchConfig,
                 # roped at the absolute token position, K/V are unroped,
                 # attention is non-causal over all encoder frames.
                 bq, cq, _ = hx.shape
-                n_cross = new_cache["xk"].shape[0]
+                n_cross = new_cache["xk"].shape[1]
                 xs_idx = jnp.clip(state_idx[:, 1], 0,
                                   max(n_cross - 1, 0)).astype(jnp.int32)
-                kh = new_cache["xk"][xs_idx]       # (B, Hkv, S_enc, dh)
-                vh = new_cache["xv"][xs_idx]
+                kh = new_cache["xk"][layer, xs_idx]  # (B, Hkv, S_enc, dh)
+                vh = new_cache["xv"][layer, xs_idx]
                 qx, kx, _ = _project_qkv(p["xattn"], hx, cfg.n_heads,
                                          cfg.n_kv_heads, cfg.dh, rt)
                 positions_x = ctx_len[:, None] \
@@ -264,7 +269,7 @@ def _slot_apply(slot: str, p: dict, x, positions, cfg: ArchConfig,
         if mode == "paged":
             with jax.named_scope("mamba"):
                 y, new_cache = _slab_step(
-                    cache, state_idx, n_valid,
+                    cache, layer, state_idx, n_valid,
                     lambda st: ssm.mamba_paged_step(p["mamba"], h, st,
                                                     n_valid, rt=rt))
         elif mode == "decode":
@@ -278,7 +283,7 @@ def _slot_apply(slot: str, p: dict, x, positions, cfg: ArchConfig,
     elif mixer == "mlstm":
         if mode == "paged":
             y, new_cache = _slab_step(
-                cache, state_idx, n_valid,
+                cache, layer, state_idx, n_valid,
                 lambda st: ssm.mlstm_paged_step(p["mlstm"], h, st, n_valid,
                                                 rt=rt,
                                                 n_heads=cfg.lstm_heads))
@@ -295,7 +300,7 @@ def _slot_apply(slot: str, p: dict, x, positions, cfg: ArchConfig,
     elif mixer == "slstm":
         if mode == "paged":
             y, new_cache = _slab_step(
-                cache, state_idx, n_valid,
+                cache, layer, state_idx, n_valid,
                 lambda st: ssm.slstm_paged_step(p["slstm"], h, st, n_valid,
                                                 rt=rt))
         elif mode == "decode":
@@ -350,12 +355,18 @@ def _sp_constrain(x, rt: Runtime):
 def _period_body(carry, xs, *, cfg: ArchConfig, rt: Runtime, mode: str,
                  positions=None, enc_out=None, causal: bool = True,
                  paged_ctx=None, fused: bool = False):
+    layer = None
     if mode == "decode":
         x, pos, aux = carry
         slot_params, caches = xs
-    elif mode in ("prefill", "paged"):
+    elif mode == "prefill":
         x, aux = carry
         slot_params, caches = xs
+        pos = None
+    elif mode == "paged":
+        # the regions of every period ride in the carry, indexed in place
+        x, aux, caches = carry
+        slot_params, layer = xs
         pos = None
     else:
         x, aux = carry
@@ -375,7 +386,7 @@ def _period_body(carry, xs, *, cfg: ArchConfig, rt: Runtime, mode: str,
             return _slot_apply(_slot, sp, xx, positions, cfg, rt, mode=mode,
                                cache=_cache, pos=pos, enc_out=enc_out,
                                causal=causal, paged_ctx=paged_ctx,
-                               fused=fused)
+                               fused=fused, layer=layer)
         if mode == "train" and rt.remat != "none" and len(cfg.pattern) > 1:
             # hierarchical remat: the period body is already checkpointed;
             # checkpointing each slot too keeps the backward's recompute
@@ -389,8 +400,10 @@ def _period_body(carry, xs, *, cfg: ArchConfig, rt: Runtime, mode: str,
         x = _sp_constrain(x, rt)
     if mode == "decode":
         return (x, pos, aux), new_caches
-    if mode in ("prefill", "paged"):
+    if mode == "prefill":
         return (x, aux), new_caches
+    if mode == "paged":
+        return (x, aux, new_caches), None
     return (x, aux), None
 
 
@@ -449,17 +462,21 @@ def stack_paged(params: dict, x: jax.Array, ctx_len, block_table, n_valid,
     (SSM state), column 1 its cross-region entry (encoder-output KV);
     out-of-range sentinels mark rows without that region. caches:
     per-slot region pytrees stacked over periods
-    (``slot_init_paged_cache``). ``fused`` routes every attention through
-    the ragged decode megakernel (serving decode/verify ticks; prefill
-    chunks stay on the gather path). Returns (x, new_caches)."""
+    (``slot_init_paged_cache``). The scan carries them whole and each
+    period reads and writes its own index in place: no period's region is
+    sliced out, and no second copy is stacked. ``fused`` routes every
+    attention through the ragged decode megakernel (serving decode/verify
+    ticks; prefill chunks stay on the gather path). Returns
+    (x, new_caches)."""
     def body(carry, xs):
         return _period_body(carry, xs, cfg=cfg, rt=rt, mode="paged",
                             paged_ctx=(ctx_len, block_table, n_valid,
                                        state_idx),
                             fused=fused)
-    (x, _), new_caches = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)),
-        (tuple(params["slots"]), tuple(caches)),
+    n_periods = jax.tree_util.tree_leaves(params["slots"])[0].shape[0]
+    (x, _, new_caches), _ = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), list(caches)),
+        (tuple(params["slots"]), jnp.arange(n_periods, dtype=jnp.int32)),
         unroll=True if rt.unroll else 1)
     return x, new_caches
 
@@ -541,8 +558,10 @@ def slot_init_paged_cache(slot: str, cfg: ArchConfig, n_pages: int,
 
       * attn: token-paged K/V pools {"kp", "vp"} each
         (P, n_pages, Hkv, page_size, dh) — or, when ``kv_quant``, each a
-        {"codes" uint8, "scale" f32 (..., 1)} dict (codes interpreted
-        under ``Runtime.kv_scheme``; ``dtype`` is ignored for them)
+        {"codes" uint8, "scale" f32 (P, n_pages, Hkv, lanes)} dict (codes
+        interpreted under ``Runtime.kv_scheme``; the scale of a page's
+        token t at lane t, ``spx.kv_scale_lanes``; ``dtype`` is ignored
+        for them)
       * xdec: the same self-attention pools plus the read-only cross
         region {"xk", "xv"} each (P, n_cross, Hkv, enc_seq_len, dh) —
         one entry per *distinct input*, shared across sequences
@@ -561,7 +580,8 @@ def slot_init_paged_cache(slot: str, cfg: ArchConfig, n_pages: int,
                 return {"codes": jnp.zeros((P, n_pages, cfg.n_kv_heads,
                                             page_size, cfg.dh), jnp.uint8),
                         "scale": jnp.ones((P, n_pages, cfg.n_kv_heads,
-                                           page_size, 1), jnp.float32)}
+                                           kv_scale_lanes(page_size)),
+                                          jnp.float32)}
             cache = {"kp": pool(), "vp": pool()}
         else:
             kp = jnp.zeros((P, n_pages, cfg.n_kv_heads, page_size, cfg.dh),

@@ -448,12 +448,12 @@ class ShardBudget:
 @functools.lru_cache(maxsize=256)
 def _plan_shard_budget_cached(n_kv_heads: int, dh: int, shards: int,
                               page_size: int, n_pages: int, n_layers: int,
-                              slab_bytes: int, tok_side_bytes: int,
+                              slab_bytes: int, page_side_bytes: int,
                               vmem_bytes: int) -> ShardBudget:
     kv_sharded = shards > 1 and n_kv_heads % shards == 0
     heads = n_kv_heads // shards if kv_sharded else n_kv_heads
     # K + V sides, all paged layers, the shard's resident heads
-    page_bytes = 2 * page_size * tok_side_bytes * heads * n_layers
+    page_bytes = 2 * page_side_bytes * heads * n_layers
     return ShardBudget(shards=shards, kv_sharded=kv_sharded,
                        kv_heads_per_shard=heads, page_bytes=page_bytes,
                        pool_bytes=page_bytes * n_pages,
@@ -482,15 +482,16 @@ def plan_shard_budget(n_kv_heads: int, dh: int, *, shards: int = 1,
     if shards < 1:
         raise ValueError(f"shards must be >= 1, got {shards}")
     if kv_scheme is not None:
-        from repro.core.spx import kv_token_side_bytes
-        tok_side = kv_token_side_bytes(dh)
+        # codes + the page's lane-padded scale row
+        from repro.core.spx import kv_page_side_bytes
+        page_side = kv_page_side_bytes(dh, page_size)
     else:
-        tok_side = dh * act_bytes
+        page_side = page_size * dh * act_bytes
     plan = plan_kv_pages(n_kv_heads, dh, act_bytes=act_bytes,
                          kv_scheme=kv_scheme, hw=hw)
     return _plan_shard_budget_cached(n_kv_heads, dh, shards, page_size,
                                      n_pages, n_layers, slab_bytes,
-                                     tok_side, plan.vmem_bytes)
+                                     page_side, plan.vmem_bytes)
 
 
 # ---------------------------------------------------------------------------

@@ -27,7 +27,8 @@ docs/QUANTIZATION.md), and ``paged_decode_ragged`` /
 ``paged_decode_ragged_quant`` (the decode megakernel: one launch per
 serving decode tick over a ragged (slot, attend_len) grid, covering plain
 decode and the speculative verify window, with in-kernel LUT dequant for
-quantized pools).
+quantized pools), and ``paged_kv_write`` (a step's new K/V rows into the
+stacked page pools, in place).
 """
 from __future__ import annotations
 

@@ -86,7 +86,7 @@ from repro.nn.layers import quantize_params
 from repro.runtime import Runtime, planner
 from repro.serving.config import LEGACY_KNOBS, ServeConfig
 from repro.serving.kv_cache import (StateCache, cross_kv_bytes_per_seq,
-                                    kv_bytes_per_token,
+                                    kv_bytes_per_token, page_bytes,
                                     ssm_state_bytes_per_seq)
 from repro.serving.spans import span
 from repro.serving.spec import PromptLookupDrafter
@@ -797,12 +797,15 @@ class ServeEngine:
                                              self.kv_cache_dtype)
         if self.kv_layout == "paged":
             st = self.pool.stats
-            peak_kv = st.peak_pages_in_use * self.page_size * per_tok
+            # a page bills what the device holds for it (lane-padded
+            # scale rows included); offloaded pages keep that layout
+            one_page = page_bytes(self._decode_cfg, self.page_size,
+                                  self.kv_cache_dtype,
+                                  kv_scheme=self.kv_scheme)
+            peak_kv = st.peak_pages_in_use * one_page
             peak_state = (peak_kv
                           + st.peak_slabs_in_use * slab_bytes
                           + st.peak_cross_in_use * cross_bytes)
-            # offloaded pages carry the same per-token layout on host
-            page_bytes = self.page_size * per_tok
             paged = {"page_size": self.page_size,
                      "n_pages": self.pool.n_pages,
                      "pages_per_seq": self.pages_per_seq,
@@ -838,7 +841,7 @@ class ServeEngine:
                      "host_pages": self.pool.host_pages,
                      "host_pages_in_use": st.host_pages_in_use,
                      "peak_host_pages": st.peak_host_pages,
-                     "peak_host_bytes": st.peak_host_pages * page_bytes,
+                     "peak_host_bytes": st.peak_host_pages * one_page,
                      # prefix-cache economics (pool-side counters)
                      "prefix_cache_pages": self.pool.cache_pages,
                      "prefix_lookups": st.prefix_lookups,

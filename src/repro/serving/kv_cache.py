@@ -45,9 +45,11 @@ Shared pages appear in several rows at once — the device neither knows nor
 cares; ownership and copy-on-write live here and in the engine.
 
 Sizing (all byte helpers return bytes; counts are tokens/pages):
-``kv_bytes_per_token`` x ``page_size`` x ``n_pages`` is the whole pool —
-derived from the *actual* cache dtype (and the codes+scale layout when the
-pool is quantized); see docs/SERVING.md for a worked example.
+``page_bytes`` x ``n_pages`` is the whole pool — derived from the
+*actual* cache dtype (and the codes+scale layout when the pool is
+quantized, whose per-page scale rows are lane-padded, so a quantized page
+holds a little more than ``kv_bytes_per_token`` x ``page_size``); see
+docs/SERVING.md for a worked example.
 """
 from __future__ import annotations
 
@@ -61,7 +63,7 @@ from repro.runtime import planner
 
 __all__ = [
     "PagePool", "StateCache", "PoolStats", "StateStats",
-    "kv_bytes_per_token", "pool_bytes",
+    "kv_bytes_per_token", "page_bytes", "pool_bytes",
     "ssm_state_bytes_per_seq", "cross_kv_bytes_per_seq",
 ]
 
@@ -115,6 +117,23 @@ def kv_bytes_per_token(cfg, cache_dtype=None, *,
     return 2 * cfg.n_periods * n_attn * cfg.n_kv_heads * per_head
 
 
+def page_bytes(cfg, page_size: int, cache_dtype=None, *,
+               dtype_bytes: int | None = None,
+               kv_scheme: str | None = None) -> int:
+    """Device bytes of one page of the paged K/V pool, across every
+    attention layer, both sides and every KV head — what the device holds
+    for each page in use. Arguments as ``kv_bytes_per_token``. A quantized
+    page's scales fill whole lane rows (``spx.kv_page_side_bytes``), so it
+    is a little larger than ``kv_bytes_per_token`` x ``page_size``."""
+    if kv_scheme is None:
+        return page_size * kv_bytes_per_token(cfg, cache_dtype,
+                                              dtype_bytes=dtype_bytes)
+    # (layer, side, head) page blocks: codes + one lane row of scales
+    blocks = (kv_bytes_per_token(cfg, kv_scheme=kv_scheme)
+              // spx.kv_token_side_bytes(cfg.dh))
+    return blocks * spx.kv_page_side_bytes(cfg.dh, page_size)
+
+
 def pool_bytes(cfg, n_pages: int, page_size: int, cache_dtype=None, *,
                dtype_bytes: int | None = None,
                kv_scheme: str | None = None) -> int:
@@ -122,8 +141,8 @@ def pool_bytes(cfg, n_pages: int, page_size: int, cache_dtype=None, *,
     construction to the summed ``.nbytes`` of the arrays
     ``models.lm.paged_init_caches`` allocates for the same geometry
     (regression-tested)."""
-    return n_pages * page_size * kv_bytes_per_token(
-        cfg, cache_dtype, dtype_bytes=dtype_bytes, kv_scheme=kv_scheme)
+    return n_pages * page_bytes(cfg, page_size, cache_dtype,
+                                dtype_bytes=dtype_bytes, kv_scheme=kv_scheme)
 
 
 def ssm_state_bytes_per_seq(cfg, cache_dtype=None, *,

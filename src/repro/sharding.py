@@ -233,7 +233,8 @@ class ShardingPolicy:
         """Specs for the serving engine's paged StateCache pytree.
 
         Token-KV page pools (``kp``/``vp``, ``(P, n_pages, Hkv, page_size,
-        dh)``) and read-only cross entries (``xk``/``xv``, ``(P, n_cross,
+        dh)``, a quantized pool's scales ``(P, n_pages, Hkv, lanes)``)
+        and read-only cross entries (``xk``/``xv``, ``(P, n_cross,
         Hkv, S_enc, dh)``) shard their KV-head axis over the model axis
         when it divides — including the ``codes``/``scale`` children of a
         quantized pool, which share the head axis. Everything else
@@ -249,10 +250,11 @@ class ShardingPolicy:
             kv_key = keys[-1] in pool_keys or (
                 len(keys) >= 2 and keys[-2] in pool_keys
                 and keys[-1] in ("codes", "scale"))
-            if kv_key and nd == 5 and self.n_model > 1 \
+            spec = [None] * nd
+            if kv_key and nd >= 4 and self.n_model > 1 \
                     and _div(shape[2], self.n_model):
-                return P(None, None, self.model_axis, None, None)
-            return P(*([None] * nd))
+                spec[2] = self.model_axis
+            return P(*spec)
 
         return jax.tree_util.tree_map_with_path(leaf, caches)
 

@@ -29,7 +29,7 @@ from repro.configs import get_config, reduced
 from repro.core import spx
 from repro.kernels import ops
 from repro.models import lm as lm_mod
-from repro.nn.attention import quantize_kv
+from repro.nn.attention import quantize_pages
 from repro.runtime import Runtime, planner
 from repro.serving.engine import Request, ServeConfig, ServeEngine
 
@@ -56,9 +56,13 @@ def _pools(rng, b, hkv, ps, max_pages, dh, scheme):
     bt = jnp.asarray(rng.integers(0, n_pages, (b, max_pages)), jnp.int32)
     if scheme is None:
         return kp, vp, bt
-    kq = dict(zip(("codes", "scale"), quantize_kv(kp, scheme)))
-    vq = dict(zip(("codes", "scale"), quantize_kv(vp, scheme)))
-    return kq, vq, bt
+    return quantize_pages(kp, scheme), quantize_pages(vp, scheme), bt
+
+
+def _stacked(kp, vp):
+    """The (k, v) pools as the ragged op takes them: every layer's pools
+    on a leading axis, here one layer (read at ``layer=0``)."""
+    return jax.tree_util.tree_map(lambda a: a[None], (kp, vp))
 
 
 # dh=128 tiles take the lane-gather lookup for 8-bit codes, dh=32 the
@@ -75,10 +79,10 @@ def test_ragged_interpret_matches_ref(scheme, dh):
     ctx = jnp.asarray([0, ps - 1, ps, ps + 1], jnp.int32)
     qlen = jnp.asarray([0, 1, 3, w], jnp.int32)
     kw = dict(kv_scheme=scheme) if scheme else {}
-    want = ops.paged_decode_ragged(q, kp, vp, bt, ctx, qlen, impl="ref",
-                                   **kw)
-    got = ops.paged_decode_ragged(q, kp, vp, bt, ctx, qlen,
-                                  impl="interpret", **kw)
+    want = ops.paged_decode_ragged(q, *_stacked(kp, vp), bt, ctx, qlen,
+                                   layer=0, impl="ref", **kw)
+    got = ops.paged_decode_ragged(q, *_stacked(kp, vp), bt, ctx, qlen,
+                                  layer=0, impl="interpret", **kw)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-3, atol=2e-3)
     # rows past q_len (and the whole inactive slot 0) are EXACT zeros in
@@ -100,7 +104,8 @@ def test_ragged_w1_bit_identical_to_paged_attention():
     q = jnp.asarray(rng.standard_normal((b, 1, hq, dh)), jnp.float32)
     ctx = jnp.asarray([ps - 1, ps, 2 * ps + 3], jnp.int32)
     ones = jnp.ones((b,), jnp.int32)
-    ragged = ops.paged_decode_ragged(q, kp, vp, bt, ctx, ones, impl="ref")
+    ragged = ops.paged_decode_ragged(q, *_stacked(kp, vp), bt, ctx, ones,
+                                     layer=0, impl="ref")
     plain = ops.paged_attention(q[:, 0], kp, vp, bt, ctx + 1, impl="ref")
     assert (np.asarray(ragged[:, 0]) == np.asarray(plain)).all()
 
@@ -111,7 +116,8 @@ def test_ragged_quant_needs_scheme():
     q = jnp.zeros((2, 1, 2, 16), jnp.float32)
     lens = jnp.ones((2,), jnp.int32)
     with pytest.raises(ValueError, match="kv_scheme"):
-        ops.paged_decode_ragged(q, kq, vq, bt, lens, lens, impl="ref")
+        ops.paged_decode_ragged(q, *_stacked(kq, vq), bt, lens, lens,
+                                layer=0, impl="ref")
 
 
 def test_registry_has_ragged_ops():
@@ -193,15 +199,17 @@ def test_engine_fused_tick_is_one_compile_one_launch():
                                   kv_layout="paged", fused_decode=True),
                       rt=RT)
     # the step's program, traced with the Pallas kernels in place of the
-    # jnp oracles: one kernel, the ragged decode, and no legacy per-call
-    # paged attention (the layer scan traces its body once)
+    # jnp oracles: the in-place KV write and the ragged decode, and no
+    # legacy per-call paged attention (the layer scan traces its body
+    # once)
     b = eng.batch_slots
     traced = jax.jit(lm_mod.lm_paged_fused_step, static_argnums=(7, 8)).trace(
         eng.params, jnp.zeros((b, 1), jnp.int32), jnp.zeros(b, jnp.int32),
         jnp.asarray(eng.block_tables), jnp.ones(b, jnp.int32),
         jnp.asarray(eng._state_idx), eng.caches, cfg,
         dataclasses.replace(RT, impl="interpret"))
-    assert _pallas_kernels(traced.jaxpr.jaxpr) == ["_ragged_kernel"]
+    assert _pallas_kernels(traced.jaxpr.jaxpr) == ["_kv_write_kernel",
+                                                   "_ragged_kernel"]
 
     rng = np.random.default_rng(3)
     reqs = [Request(rid=i, prompt=rng.integers(

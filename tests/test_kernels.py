@@ -241,19 +241,95 @@ def test_paged_attention_vs_ref(hq, hkv, ps):
 
 def test_paged_kv_write_scatter_and_masking():
     """paged_kv_write places token (b, i) at (block_table[b, p//ps],
-    p % ps) and drops invalid rows instead of writing them."""
+    p % ps) of the layer it is given and skips rows past n_valid instead
+    of writing them."""
     from repro.nn.attention import paged_kv_write
     ps, n_pages, hkv, dh = 4, 6, 2, 8
-    kp = jnp.zeros((n_pages, hkv, ps, dh), jnp.float32)
+    kp = jnp.zeros((2, n_pages, hkv, ps, dh), jnp.float32)
     vp = jnp.zeros_like(kp)
     k_new = _mk((1, 3, hkv, dh), jnp.float32, seed=4)
     v_new = _mk((1, 3, hkv, dh), jnp.float32, seed=5)
     bt = jnp.asarray([[5, 2, 0]], jnp.int32)
-    pos = jnp.asarray([[3, 4, 5]], jnp.int32)     # page 0 last slot, page 1
-    valid = jnp.asarray([[True, True, False]])    # third token masked
-    kp2, vp2 = paged_kv_write(kp, vp, k_new, v_new, bt, pos, valid)
-    np.testing.assert_allclose(np.asarray(kp2[5, :, 3]),
-                               np.asarray(k_new[0, 0]))
-    np.testing.assert_allclose(np.asarray(vp2[2, :, 0]),
-                               np.asarray(v_new[0, 1]))
-    assert float(jnp.abs(kp2[2, :, 1]).max()) == 0.0   # dropped write
+    ctx = jnp.asarray([3], jnp.int32)        # page 0 last slot, page 1
+    n_valid = jnp.asarray([2], jnp.int32)    # third token masked
+    for impl in ("ref", "interpret"):
+        kp2, vp2 = paged_kv_write(kp, vp, k_new, v_new, 1, bt, ctx,
+                                  n_valid, impl=impl)
+        np.testing.assert_allclose(np.asarray(kp2[1, 5, :, 3]),
+                                   np.asarray(k_new[0, 0]))
+        np.testing.assert_allclose(np.asarray(vp2[1, 2, :, 0]),
+                                   np.asarray(v_new[0, 1]))
+        assert float(jnp.abs(kp2[1, 2, :, 1]).max()) == 0.0   # skipped
+        assert float(jnp.abs(kp2[0]).max()) == 0.0      # other layer
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("ps", [8, 32])
+@pytest.mark.parametrize("scheme", [None, "spx_8_x3"], ids=["f32", "spx"])
+def test_paged_kv_write_kernel_bit_identical_to_ref(scheme, ps, layer):
+    """The in-place write kernel (interpret mode) leaves every leaf of the
+    stacked pools — f32 pages, or SPx codes and their lane-major scales —
+    bit-identical to the XLA scatter, with windows that cross page
+    boundaries, a window that ends mid-page, an inactive slot, and the
+    first and last layer of three."""
+    from repro.nn.attention import paged_kv_write, quantize_pages
+    rng = np.random.default_rng(ps + layer)
+    n_layers, b, hkv, dh, max_pages = 3, 4, 2, 32, 4
+    n_pages = b * max_pages + 2
+    c = ps + 5                                  # spans up to 3 pages
+
+    def pool():
+        x = _mk((n_layers, n_pages, hkv, ps, dh), jnp.float32,
+                seed=int(rng.integers(1 << 20)))
+        return x if scheme is None else quantize_pages(x, scheme)
+
+    kp, vp = pool(), pool()
+    k_new = _mk((b, c, hkv, dh), jnp.float32, seed=ps)
+    v_new = _mk((b, c, hkv, dh), jnp.float32, seed=ps + 1)
+    bt = jnp.asarray(rng.permutation(n_pages)[:b * max_pages]
+                     .reshape(b, max_pages), jnp.int32)
+    ctx = jnp.asarray([0, ps - 3, 2 * ps + 1, 7], jnp.int32)
+    n_valid = jnp.asarray([c, 5, 0, c - 2], jnp.int32)
+    kw = dict(kv_scheme=scheme) if scheme else {}
+    want = paged_kv_write(kp, vp, k_new, v_new, layer, bt, ctx, n_valid,
+                          impl="ref", **kw)
+    ops.reset_op_calls()
+    got = paged_kv_write(kp, vp, k_new, v_new, layer, bt, ctx, n_valid,
+                         impl="interpret", **kw)
+    assert ops.op_impls() == {("paged_kv_write", "interpret"): 1}
+    for g, w, old in zip(jax.tree_util.tree_leaves(got),
+                         jax.tree_util.tree_leaves(want),
+                         jax.tree_util.tree_leaves((kp, vp))):
+        assert np.asarray(g).tobytes() == np.asarray(w).tobytes()
+        assert not np.array_equal(np.asarray(g), np.asarray(old))
+
+
+@pytest.mark.parametrize("scheme", [None, "spx_8_x3"], ids=["f32", "spx"])
+def test_ragged_decode_reads_the_stacked_pool_in_place(scheme):
+    """Both ragged decode kernels, given every layer's pools and a layer
+    index, return bit for bit what they return for that layer's pools
+    alone, at the first and the last layer; so does the oracle."""
+    from repro.nn.attention import quantize_pages
+    rng = np.random.default_rng(9)
+    n_layers, b, hq, hkv, dh, ps, mp, w = 3, 3, 4, 2, 32, 8, 3, 2
+    n_pages = b * mp + 1
+
+    def pool():
+        x = jnp.asarray(rng.standard_normal((n_layers, n_pages, hkv, ps,
+                                             dh)), jnp.float32)
+        return x if scheme is None else quantize_pages(x, scheme)
+
+    kp, vp = pool(), pool()
+    q = jnp.asarray(rng.standard_normal((b, w, hq, dh)), jnp.float32)
+    bt = jnp.asarray(rng.integers(0, n_pages, (b, mp)), jnp.int32)
+    ctx = jnp.asarray([0, ps - 1, 2 * ps + 3], jnp.int32)
+    qlen = jnp.asarray([0, 2, 1], jnp.int32)
+    kw = dict(kv_scheme=scheme) if scheme else {}
+    for layer in (0, n_layers - 1):
+        one = jax.tree_util.tree_map(lambda a: a[layer:layer + 1], (kp, vp))
+        for impl in ("interpret", "ref"):
+            got = ops.paged_decode_ragged(q, kp, vp, bt, ctx, qlen,
+                                          layer=layer, impl=impl, **kw)
+            want = ops.paged_decode_ragged(q, *one, bt, ctx, qlen,
+                                           layer=0, impl=impl, **kw)
+            assert np.asarray(got).tobytes() == np.asarray(want).tobytes()

@@ -24,10 +24,11 @@ from repro.core import spx
 from repro.core.quantized import quantize_weight
 from repro.kernels import ops
 from repro.models import lm as lm_mod
-from repro.nn.attention import dequantize_kv, quantize_kv
+from repro.nn.attention import dequantize_kv, quantize_kv, quantize_pages
 from repro.runtime import Runtime, planner
 from repro.serving.engine import Request, ServeConfig, ServeEngine
-from repro.serving.kv_cache import PagePool, kv_bytes_per_token, pool_bytes
+from repro.serving.kv_cache import (PagePool, kv_bytes_per_token, page_bytes,
+                                    pool_bytes)
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -81,8 +82,8 @@ def test_paged_quant_engine_matches_dense_f32(scheme):
     # quantized pages bill codes+scale bytes, not cache-dtype elements
     assert m["peak_kv_bytes"] > 0
     assert (m["peak_kv_bytes"]
-            == eng.pool.stats.peak_pages_in_use * eng.page_size
-            * kv_bytes_per_token(cfg, kv_scheme=scheme))
+            == eng.pool.stats.peak_pages_in_use
+            * page_bytes(cfg, eng.page_size, kv_scheme=scheme))
 
 
 def test_paged_quant_undercuts_bf16_pool_bytes():
@@ -163,10 +164,8 @@ def test_paged_quant_kernel_interpret_matches_ref(scheme, dh):
     b, hq, hkv, ps, n_pages, mp = 3, 4, 2, 8, 6, 2
     q = jnp.asarray(rng.standard_normal((b, hq, dh)), jnp.float32)
     kv = rng.standard_normal((2, n_pages, hkv, ps, dh)).astype(np.float32)
-    kc, ks = quantize_kv(jnp.asarray(kv[0]), scheme)
-    vc, vs = quantize_kv(jnp.asarray(kv[1]), scheme)
-    kp = {"codes": kc, "scale": ks}
-    vp = {"codes": vc, "scale": vs}
+    kp = quantize_pages(jnp.asarray(kv[0]), scheme)
+    vp = quantize_pages(jnp.asarray(kv[1]), scheme)
     bt = jnp.asarray(rng.integers(0, n_pages, (b, mp)), jnp.int32)
     ctx = jnp.asarray([0, 5, 13], jnp.int32)     # inactive + partial pages
     ref = ops.paged_attention_quant(q, kp, vp, bt, ctx, kv_scheme=scheme,
@@ -207,6 +206,33 @@ def test_pool_bytes_matches_allocated_nbytes(dtype, kv_quant):
     scheme = "spx_8_x3" if kv_quant else None
     assert _tree_nbytes(caches) == pool_bytes(cfg, n_pages, ps, dtype,
                                               kv_scheme=scheme)
+
+
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["f32", "spx"])
+def test_metrics_bill_the_bytes_the_pool_holds(kv_quant):
+    """metrics() bills each page in use what the allocated pool holds for
+    one page — its nbytes over n_pages, the SPx pool's lane-padded scale
+    rows included — globally and per shard."""
+    cfg = _serving_cfg()
+    params = lm_mod.lm_init(jax.random.PRNGKey(3), cfg)
+    rt = RT.replace(kv_quant=True, kv_scheme="spx_8_x3") if kv_quant else RT
+    eng = ServeEngine(params, cfg,
+                      ServeConfig(batch_slots=2, max_seq=32, quantize=None,
+                                  kv_layout="paged", page_size=8,
+                                  prefill_chunk=8), rt=rt)
+    rng = np.random.default_rng(0)
+    for i, n in enumerate((5, 13)):
+        eng.submit(Request(rid=i, max_new_tokens=3, prompt=rng.integers(
+            0, cfg.vocab_size, n).astype(np.int32)))
+    eng.run()
+    m = eng.metrics()
+    held = _tree_nbytes(eng.caches)
+    assert held == pool_bytes(cfg, m["n_pages"], 8, jnp.float32,
+                              kv_scheme="spx_8_x3" if kv_quant else None)
+    assert m["peak_kv_pages"] > 0
+    assert m["peak_kv_bytes"] * m["n_pages"] == m["peak_kv_pages"] * held
+    assert m["pool_bytes_per_shard"] == held
+    assert m["peak_kv_bytes_per_shard"] == m["peak_kv_bytes"]
 
 
 @pytest.mark.parametrize("dtype,kv_quant",

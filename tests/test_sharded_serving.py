@@ -75,9 +75,9 @@ for width in (2, 4, 8):
                          "vp": Leaf((2, 8, Hkv, 8, dh)),
                          "slab": Leaf((2, 4, dh))},
                   "l1": {"kp": {"codes": Leaf((2, 8, Hkv, 8, dh)),
-                                "scale": Leaf((2, 8, Hkv, 8, 1))},
+                                "scale": Leaf((2, 8, Hkv, 128))},
                          "vp": {"codes": Leaf((2, 8, Hkv, 8, dh)),
-                                "scale": Leaf((2, 8, Hkv, 8, 1))}},
+                                "scale": Leaf((2, 8, Hkv, 128))}},
                   "xk": Leaf((2, 2, Hkv, 16, dh))}
         specs = policy.paged_state_specs(caches)
         check_specs(specs, caches, sizes, (arch, width, "pools"))
@@ -85,6 +85,7 @@ for width in (2, 4, 8):
         # and scale head-axes follow the same rule, never unevenly
         want = ("model" if Hkv % width == 0 else None)
         assert tuple(specs["l0"]["kp"])[2] == want, (arch, width)
+        assert tuple(specs["l1"]["kp"]["scale"])[2] == want, (arch, width)
         assert tuple(specs["l0"]["slab"]) == (None, None, None), arch
 print("OK divisible")
 """

@@ -48,6 +48,7 @@ SHARDS: dict[str, list[str]] = {
         "tests/test_jamba.py",
         "tests/test_kv_quant.py",
         "tests/test_models_smoke.py",
+        "tests/test_paged_layer_scan.py",
         "tests/test_prefix_cache.py",
         "tests/test_scheduler.py",
         "tests/test_serving.py",
