@@ -46,10 +46,6 @@ CHIPS_SINGLE_POD = 256
 
 ART = os.path.join(os.path.dirname(__file__), "artifacts")
 
-#: fused-decode bench artifact (repo root, like BENCH_serving.json)
-BENCH = os.path.normpath(os.path.join(os.path.dirname(__file__), "..",
-                                      "BENCH_roofline.json"))
-
 
 def _compile_cost_variant(cfg, shape, n_periods: int, mesh, *,
                           fsdp: bool, optimizer: str | None,
@@ -287,174 +283,6 @@ def run_cell(arch: str, shape_name: str, *, quantized: bool = True,
     return result
 
 
-def fused_decode_bench(csv_rows, *, requests: int = 6, slots: int = 2,
-                       max_seq: int = 512, new_tokens: int = 24,
-                       spec_k: int = 3, seed: int = 3,
-                       out_path: str = BENCH) -> dict:
-    """Fused vs unfused decode step on the pinned serving workload:
-    the ragged decode megakernel (one attention dispatch per tick, spec
-    verify included, in-kernel LUT dequant) against the pre-megakernel
-    path (per-call paged-attention kernel for plain ticks + full-width
-    page-gather verify for draft ticks).
-
-    Reports, per KV axis (plain f32 pages / SPx codes+scale pages):
-      * measured decode throughput (warmup pass pays every compile, then
-        reset_metrics + a timed pass — serving_bench's protocol),
-      * attention ops traced per decode tick (the trace-time op-call
-        counters; the fused path is asserted =1 in tests/test_fused_decode),
-      * modeled HBM bytes per decode tick (the gather path reads the full
-        block-table width and materializes rep-expanded f32 K/V; the
-        megakernel streams only touched pages once and keeps the <=1KiB
-        codebook LUT in VMEM),
-      * the planner's FusedDecodePlan for the tick's geometry.
-
-    On CPU with the DEFAULT (pinned) workload, asserts greedy outputs are
-    bit-identical fused vs unfused and fused throughput >= unfused — the
-    megakernel is a dispatch/memory optimization, not a numerics change.
-    Writes BENCH_roofline.json at the repo root (run.py + CI upload it).
-    """
-    import time
-
-    import numpy as np
-    from repro.configs import get_config, reduced
-    from repro.core.spx import kv_token_side_bytes
-    from repro.kernels import ops
-    from repro.models import lm as lm_mod
-    from repro.runtime import Runtime, planner
-    from repro.serving.engine import Request, ServeConfig, ServeEngine
-
-    # serving_bench's pinned geometry: dh=128 keeps the SPx byte ratio
-    # representative, vocab=32 keeps greedy argmaxes away from near-ties
-    cfg = dataclasses.replace(reduced(get_config("gemma-2b"), vocab=32),
-                              head_dim=128)
-    rt = Runtime(impl="auto", q_chunk=64)
-    params = lm_mod.lm_init(jax.random.PRNGKey(seed), cfg)
-    rng = np.random.default_rng(seed)
-    # tiled-motif prompts: the structure prompt-lookup drafting feeds on,
-    # so the verify window (the megakernel's q_len > 1 rows) stays hot
-    prompts = [np.tile(rng.integers(0, cfg.vocab_size, 4).astype(np.int32),
-                       4) for _ in range(requests)]
-
-    w = spec_k + 1
-    rep = cfg.n_heads // cfg.n_kv_heads
-    n_attn_layers = sum(1 for p in cfg.pattern
-                        if p.split("+")[0] in ("attn", "xdec")) \
-        * cfg.n_periods
-    axes = {"paged": rt,
-            "paged-spx": rt.replace(kv_quant=True, kv_scheme="spx_8_x3")}
-    pinned = (requests, slots, max_seq, new_tokens, spec_k, seed) \
-        == (6, 2, 512, 24, 3, 3)
-    result: dict = {"config": {"arch": cfg.name, "requests": requests,
-                               "batch_slots": slots, "max_seq": max_seq,
-                               "new_tokens": new_tokens, "spec_k": spec_k,
-                               "gqa_rep": rep,
-                               "n_attn_layers": n_attn_layers}}
-    print("\n== decode megakernel: fused vs unfused, plain and SPx KV ==")
-    for axis, ert in axes.items():
-        outs, mets = {}, {}
-        for fused in (True, False):
-            eng = ServeEngine(params, cfg,
-                              ServeConfig(batch_slots=slots, max_seq=max_seq,
-                                          quantize="sp2_4", kv_layout="paged",
-                                          spec_decode=True, spec_k=spec_k,
-                                          fused_decode=fused),
-                              rt=ert)
-            ops.reset_op_calls()
-            for i, p in enumerate(prompts):        # warmup: pay compiles
-                eng.submit(Request(rid=i, prompt=p,
-                                   max_new_tokens=new_tokens))
-            eng.run()
-            # every step is compiled now, so the counters hold ops traced,
-            # i.e. attention dispatches per compiled tick (layer-scanned)
-            traced = ops.op_calls()
-            # best-of-3 measured passes: one pass is ~0.1s on CPU, well
-            # inside scheduler noise; max-of-3 is the standard antidote
-            m, dt = None, float("inf")
-            for _ in range(3):
-                eng.reset_metrics()
-                t0 = time.monotonic()
-                for i, p in enumerate(prompts):
-                    eng.submit(Request(rid=i, prompt=p,
-                                       max_new_tokens=new_tokens))
-                outs[fused] = {r.rid: r.output for r in eng.run()}
-                dt = min(dt, time.monotonic() - t0)
-                mm = eng.metrics()
-                if m is None or mm["tokens_per_s"] > m["tokens_per_s"]:
-                    m = mm
-            mets[fused] = m
-            decode_ops = {k: v for k, v in traced.items()
-                          if "paged" in k or "decode" in k}
-            ps = m["page_size"]
-            tok_bytes = (kv_token_side_bytes(cfg.dh)
-                         if ert.kv_quant else 4 * cfg.dh)
-            s_max = -(-max_seq // ps) * ps          # block-table width
-            ctx_mean = float(np.mean([len(p) for p in prompts])
-                             + new_tokens / 2)
-            s_touch = -(-int(ctx_mean + w) // ps) * ps
-            if fused:
-                plan = planner.plan_fused_decode(
-                    cfg.dh, rep=rep, w=w, page_size=ps, act_bytes=4,
-                    kv_scheme=ert.kv_scheme if ert.kv_quant else None)
-                # streams each touched page once; LUT + q rows ride along
-                kv_tick = (2 * cfg.n_kv_heads * s_touch * tok_bytes
-                           + plan.lut_bytes
-                           + plan.rows * cfg.dh * 4)
-                bytes_tick = kv_tick * n_attn_layers * slots
-                result.setdefault(axis, {})["plan"] = \
-                    dataclasses.asdict(plan)
-            else:
-                # gather reads the FULL block-table width, materializes a
-                # contiguous f32 copy (write+read), then rep-expands it
-                # to Hq for the GQA einsum (write+read again)
-                kv_tick = (2 * cfg.n_kv_heads * s_max * tok_bytes
-                           + 2 * cfg.n_kv_heads * s_max * cfg.dh * 4 * 2
-                           + (2 * cfg.n_heads * s_max * cfg.dh * 4 * 2
-                              if rep > 1 else 0))
-                bytes_tick = kv_tick * n_attn_layers * slots
-            tag = "fused  " if fused else "unfused"
-            print(f"  {axis:10s} {tag}: {m['tokens_per_s']:8.1f} tok/s  "
-                  f"calls {m['model_calls']:3d}  accept "
-                  f"{m['draft_acceptance_rate']:.2f}  "
-                  f"~{bytes_tick / 2**20:6.2f} MiB/tick  "
-                  f"ops/trace {decode_ops}")
-            result.setdefault(axis, {})[
-                "fused" if fused else "unfused"] = {
-                    "tokens_per_s": m["tokens_per_s"],
-                    "model_calls": m["model_calls"],
-                    "draft_acceptance_rate": m["draft_acceptance_rate"],
-                    "wall_s": dt,
-                    "attention_ops_traced": decode_ops,
-                    "modeled_kv_bytes_per_tick": bytes_tick,
-                }
-        agree = outs[True] == outs[False]
-        speedup = (mets[True]["tokens_per_s"]
-                   / max(mets[False]["tokens_per_s"], 1e-9))
-        result[axis]["greedy_agreement"] = float(agree)
-        result[axis]["fused_speedup"] = speedup
-        fb = result[axis]["fused"]["modeled_kv_bytes_per_tick"]
-        ub = result[axis]["unfused"]["modeled_kv_bytes_per_tick"]
-        result[axis]["modeled_bytes_ratio_unfused_over_fused"] = \
-            ub / max(fb, 1)
-        print(f"  {axis:10s} fused speedup {speedup:.2f}x, modeled "
-              f"bytes/tick ratio {ub / fb:.1f}x, agree {agree}")
-        if jax.default_backend() == "cpu" and pinned:
-            assert agree, f"{axis}: megakernel changed greedy outputs"
-            assert speedup >= 1.0, \
-                f"{axis}: fused decode slower than unfused ({speedup:.2f}x)"
-        elif not agree:
-            print(f"  WARNING: {axis} fused vs unfused outputs differ "
-                  "(near-tie flips across reduction orders — not "
-                  "asserted off the pinned CPU workload)")
-        csv_rows.append((f"roofline/fused_decode_{axis}_tok_per_s", 0.0,
-                         mets[True]["tokens_per_s"]))
-        csv_rows.append((f"roofline/fused_decode_{axis}_speedup", 0.0,
-                         speedup))
-    with open(out_path, "w") as fh:
-        json.dump(result, fh, indent=2, sort_keys=True)
-    print(f"  wrote {out_path}")
-    return result
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
@@ -463,18 +291,9 @@ def main():
     ap.add_argument("--dense-baseline", action="store_true",
                     help="also run serve shapes with UNquantized weights "
                     "(pre-paper baseline)")
-    ap.add_argument("--fused-decode-bench", action="store_true",
-                    help="time the ragged decode megakernel against the "
-                    "per-call kernel + page-gather path and write "
-                    "BENCH_roofline.json (skips the roofline cells)")
     args = ap.parse_args()
-
-    if args.fused_decode_bench:
-        fused_decode_bench([])
-        return 0
     # the roofline cells compile against the production mesh on host
-    # devices; the fused-decode bench above times the real single-host
-    # engine, where fake devices would skew every measurement
+    # devices
     set_host_device_count(512)
 
     archs = assigned_archs() if (args.all or not args.arch) else [args.arch]

@@ -1,11 +1,9 @@
 """Benchmark harness: one function per paper table/figure plus the kernel
-microbenchmark, the dense-vs-paged serving comparison (which writes
-``BENCH_serving.json`` at the repo root), and the fused-vs-unfused decode
-megakernel bench (``BENCH_roofline.json``). Prints
+microbenchmark and the dense-vs-paged serving comparison (which writes
+``BENCH_serving.json`` at the repo root). Prints
 ``name,us_per_call,derived`` CSV at the end.
 
   PYTHONPATH=src python -m benchmarks.run [--skip-roofline-table]
-      [--skip-fused-decode-bench]
 """
 import argparse
 import glob
@@ -76,20 +74,6 @@ def plan_report(csv_rows):
         csv_rows.append((f"plan/{name}", 0.0, plan.margin))
 
 
-def fused_decode_table(csv_rows):
-    """Run the fused-vs-unfused decode bench in this process (one process
-    holds the chip) and fold BENCH_roofline.json into the CSV."""
-    from benchmarks import roofline
-    roofline.fused_decode_bench([])
-    with open(roofline.BENCH) as fh:
-        r = json.load(fh)
-    for axis in ("paged", "paged-spx"):
-        csv_rows.append((f"roofline/fused_decode_{axis}_tok_per_s", 0.0,
-                         r[axis]["fused"]["tokens_per_s"]))
-        csv_rows.append((f"roofline/fused_decode_{axis}_speedup", 0.0,
-                         r[axis]["fused_speedup"]))
-
-
 def roofline_table(csv_rows):
     """Summarize any roofline artifacts present (produced by
     `python -m benchmarks.roofline --all`)."""
@@ -117,7 +101,6 @@ def roofline_table(csv_rows):
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--skip-roofline-table", action="store_true")
-    ap.add_argument("--skip-fused-decode-bench", action="store_true")
     args = ap.parse_args()
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -130,8 +113,6 @@ def main() -> None:
     kernel_microbench(csv_rows)
     plan_report(csv_rows)
     serving_bench.run(csv_rows)
-    if not args.skip_fused_decode_bench:
-        fused_decode_table(csv_rows)
     if not args.skip_roofline_table:
         roofline_table(csv_rows)
 

@@ -216,8 +216,7 @@ def kernel_checks(cfg, *, impl: str = "pallas", slots: int = SLOTS,
 def serve_config(cfg, *, slots: int = SLOTS, max_seq: int = MAX_SEQ,
                  **kw) -> ServeConfig:
     return ServeConfig(batch_slots=slots, max_seq=max_seq, quantize=SCHEME,
-                       kv_layout="paged", scheduler="cb", fused_decode=True,
-                       **kw).resolve(cfg)
+                       kv_layout="paged", scheduler="cb", **kw).resolve(cfg)
 
 
 def runtime(kv_quant: bool, impl: str = "auto") -> Runtime:

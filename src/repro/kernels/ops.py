@@ -27,17 +27,14 @@ from repro.runtime import planner, registry
 from . import ref as ref_impl
 from .decode import LOOKUPS
 from .flash_attention import flash_attention_pallas
-from .paged_attention import (paged_attention_pallas,
-                              paged_attention_quant_pallas,
-                              paged_decode_ragged_pallas,
+from .paged_attention import (paged_decode_ragged_pallas,
                               paged_decode_ragged_quant_pallas)
 from .paged_kv_write import paged_kv_write_pallas
 from .spx_expert_matmul import spx_expert_matmul_pallas
 from .spx_matmul import spx_matmul_pallas
 
 __all__ = ["spx_matmul", "spx_expert_matmul", "flash_attention",
-           "paged_attention",
-           "paged_attention_quant", "paged_decode_ragged", "paged_kv_write",
+           "paged_decode_ragged", "paged_kv_write",
            "resolve_impl",
            "op_calls", "op_impls", "lut_lookups", "reset_op_calls"]
 
@@ -379,113 +376,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         functools.partial(entry.fn, causal=causal, plan=plan), mesh)(
             qf, kf, vf)
     return out.reshape(q.shape)
-
-
-# ---------------------------------------------------------------------------
-# paged_attention: single-token decode over the paged KV cache — registered
-# impls share the signature fn(q4, k_pages, v_pages, block_table, ctx_len)
-# with q4: (B, Hkv, rep, dh)
-# ---------------------------------------------------------------------------
-
-@registry.register("paged_attention", "ref",
-                   priority=registry.PRIORITY_REFERENCE)
-def _paged_attention_ref(q4, k_pages, v_pages, block_table, ctx_len):
-    return ref_impl.paged_attention_ref(q4, k_pages, v_pages, block_table,
-                                        ctx_len)
-
-
-registry.register("paged_attention", "pallas",
-                  priority=registry.PRIORITY_ACCELERATOR,
-                  available=_on_tpu)(
-    functools.partial(paged_attention_pallas, interpret=False))
-registry.register("paged_attention", "interpret",
-                  priority=registry.PRIORITY_DEBUG)(
-    functools.partial(paged_attention_pallas, interpret=True))
-
-
-def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                    block_table: jax.Array, ctx_len: jax.Array, *,
-                    impl: str = "auto", mesh=None) -> jax.Array:
-    """Decode attention of one query token per sequence against its paged
-    KV context.
-
-    q: (B, Hq, dh); k_pages/v_pages: (n_pages, Hkv, page_size, dh) —
-    physical page pools shared by all sequences; block_table:
-    (B, max_pages) int32 physical page per logical page; ctx_len: (B,)
-    int32 — positions < ctx_len attended (0 = inactive row, output zeros).
-    Returns (B, Hq, dh). Page geometry is chosen at pool-allocation time
-    via planner.plan_kv_pages, not per call. ``mesh`` as in
-    ``spx_matmul``.
-    """
-    _OP_CALLS["paged_attention"] += 1
-    b, hq, dh = q.shape
-    hkv = k_pages.shape[1]
-    assert hq % hkv == 0, (hq, hkv)
-    q4 = q.reshape(b, hkv, hq // hkv, dh)
-    entry = registry.resolve("paged_attention", impl)
-    _OP_IMPLS["paged_attention", entry.impl] += 1
-    fn = entry.fn if entry.impl == "ref" else _whole_per_device(entry.fn,
-                                                                mesh)
-    out = fn(q4, k_pages, v_pages, jnp.asarray(block_table, jnp.int32),
-             jnp.asarray(ctx_len, jnp.int32))
-    return out.reshape(b, hq, dh)
-
-
-# ---------------------------------------------------------------------------
-# paged_attention_quant: decode over quantized (codes + scale) KV pools with
-# the codebook dequant fused into the page loop — registered impls share the
-# signature fn(q4, k_codes, k_scale, v_codes, v_scale, block_table, ctx_len,
-# lut)
-# ---------------------------------------------------------------------------
-
-@registry.register("paged_attention_quant", "ref",
-                   priority=registry.PRIORITY_REFERENCE)
-def _paged_attention_quant_ref(q4, k_codes, k_scale, v_codes, v_scale,
-                               block_table, ctx_len, lut):
-    return ref_impl.paged_attention_quant_ref(q4, k_codes, k_scale,
-                                              v_codes, v_scale,
-                                              block_table, ctx_len, lut)
-
-
-registry.register("paged_attention_quant", "pallas",
-                  priority=registry.PRIORITY_ACCELERATOR,
-                  available=_on_tpu)(
-    functools.partial(paged_attention_quant_pallas, interpret=False))
-registry.register("paged_attention_quant", "interpret",
-                  priority=registry.PRIORITY_DEBUG)(
-    functools.partial(paged_attention_quant_pallas, interpret=True))
-
-
-def paged_attention_quant(q: jax.Array, k_pages: dict, v_pages: dict,
-                          block_table: jax.Array, ctx_len: jax.Array, *,
-                          kv_scheme: str = "uniform8",
-                          impl: str = "auto", mesh=None) -> jax.Array:
-    """Decode attention of one query token per sequence against its
-    quantized paged KV context, with LUT dequantization fused into the
-    page-streaming loop (no full-pool dequant pass — the paper's §3.2
-    codes stay 1 byte/element all the way to VMEM).
-
-    q: (B, Hq, dh); k_pages/v_pages: {"codes": (n_pages, Hkv, page_size,
-    dh) uint8, "scale": (n_pages, Hkv, lanes) f32} — one layer of the
-    pools ``nn.attention.paged_kv_write`` maintains; ``kv_scheme`` names the
-    core/spx level set the codes were quantized under (static — resolves
-    to a <=256-entry f32 codebook). Returns (B, Hq, dh). ``mesh`` as in
-    ``spx_matmul``.
-    """
-    _OP_CALLS["paged_attention_quant"] += 1
-    b, hq, dh = q.shape
-    hkv = k_pages["codes"].shape[1]
-    assert hq % hkv == 0, (hq, hkv)
-    q4 = q.reshape(b, hkv, hq // hkv, dh)
-    lut = spx.codebook(spx.scheme_levels(kv_scheme), dtype=jnp.float32)
-    entry = registry.resolve("paged_attention_quant", impl)
-    _OP_IMPLS["paged_attention_quant", entry.impl] += 1
-    fn = entry.fn if entry.impl == "ref" else _whole_per_device(entry.fn,
-                                                                mesh)
-    out = fn(q4, k_pages["codes"], k_pages["scale"], v_pages["codes"],
-             v_pages["scale"], jnp.asarray(block_table, jnp.int32),
-             jnp.asarray(ctx_len, jnp.int32), lut)
-    return out.reshape(b, hq, dh)
 
 
 # ---------------------------------------------------------------------------

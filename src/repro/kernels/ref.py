@@ -11,8 +11,7 @@ import jax.numpy as jnp
 from repro.core import spx
 
 __all__ = ["spx_matmul_ref", "spx_expert_matmul_ref", "attention_ref",
-           "paged_attention_ref",
-           "paged_attention_quant_ref", "paged_decode_ragged_ref",
+           "paged_decode_ragged_ref",
            "paged_decode_ragged_quant_ref", "paged_kv_write_ref",
            "page_scales"]
 
@@ -82,79 +81,6 @@ def page_scales(scale_pages, page_size: int):
     return a.reshape(b, hkv, mp * page_size, 1)
 
 
-def paged_attention_ref(q, k_pages, v_pages, block_table, ctx_len, *,
-                        out_dtype=None):
-    """Single-token decode attention over a paged KV cache.
-
-    q: (B, Hkv, rep, dh) — query heads grouped under their KV head;
-    k_pages/v_pages: (n_pages, Hkv, page_size, dh) physical page pools;
-    block_table: (B, max_pages) int32 physical page per logical page;
-    ctx_len: (B,) int32 — tokens attendable (positions < ctx_len).
-    Returns (B, Hkv, rep, dh).
-
-    Gathers this sequence's pages into a contiguous view and runs a plain
-    max-shifted softmax in f32 — the oracle the Pallas kernel's online
-    softmax must match.
-    """
-    out_dtype = out_dtype or q.dtype
-    b, hkv, rep, dh = q.shape
-    ps = k_pages.shape[2]
-    max_pages = block_table.shape[1]
-    s_max = max_pages * ps
-    # gather: (B, max_pages, Hkv, ps, dh) -> (B, Hkv, S, dh)
-    k = jnp.moveaxis(k_pages[block_table], 2, 1).reshape(b, hkv, s_max, dh)
-    v = jnp.moveaxis(v_pages[block_table], 2, 1).reshape(b, hkv, s_max, dh)
-    return _paged_softmax(q, k, v, ctx_len, out_dtype)
-
-
-def _paged_softmax(q, k, v, ctx_len, out_dtype):
-    """Shared masked-softmax core of the paged oracles. q: (B,Hkv,rep,dh);
-    k/v: (B,Hkv,S,dh) contiguous gathered views."""
-    dh = q.shape[-1]
-    s_max = k.shape[2]
-    s = jnp.einsum("bhrd,bhkd->bhrk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * (dh ** -0.5)
-    pos = jnp.arange(s_max)
-    s = jnp.where(pos[None, None, None, :] < ctx_len[:, None, None, None],
-                  s, -1e30)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = jnp.sum(p, axis=-1, keepdims=True)
-    o = jnp.einsum("bhrk,bhkd->bhrd", p, v.astype(jnp.float32))
-    o = o / jnp.maximum(l, 1e-30)
-    # ctx == 0 rows (inactive slots): everything is masked and the shifted
-    # softmax degenerates to a mean — force the kernel's all-zero output
-    o = jnp.where(ctx_len[:, None, None, None] > 0, o, 0.0)
-    return o.astype(out_dtype)
-
-
-def paged_attention_quant_ref(q, k_codes, k_scale, v_codes, v_scale,
-                              block_table, ctx_len, lut, *, out_dtype=None):
-    """Quantized-pool variant of ``paged_attention_ref``: pools hold uint8
-    codebook codes plus a per-token f32 scale, and dequantization
-    (``lut[codes] * scale``) is fused after the page gather — the oracle
-    the fused-dequant Pallas kernel must match.
-
-    k_codes/v_codes: (n_pages, Hkv, page_size, dh) uint8; k_scale/v_scale:
-    (n_pages, Hkv, lanes) f32, lane t the scale of the page's token t
-    (``spx.kv_scale_lanes``); lut: (2^w,) f32 codebook
-    (spx.codebook of the KV scheme). Other args as paged_attention_ref.
-    """
-    out_dtype = out_dtype or q.dtype
-    b, hkv, rep, dh = q.shape
-    ps = k_codes.shape[2]
-    s_max = block_table.shape[1] * ps
-
-    def gather_dequant(codes, scale):
-        c = jnp.moveaxis(codes[block_table], 2, 1).reshape(b, hkv, s_max, dh)
-        a = page_scales(scale[block_table], ps)
-        return jnp.take(lut, c.astype(jnp.int32), axis=0) * a
-
-    k = gather_dequant(k_codes, k_scale)
-    v = gather_dequant(v_codes, v_scale)
-    return _paged_softmax(q, k, v, ctx_len, out_dtype)
-
-
 def _ragged_softmax(q, k, v, ctx_len, q_len, w: int, out_dtype):
     """Shared masked-softmax core of the ragged decode-window oracles.
 
@@ -199,8 +125,12 @@ def paged_decode_ragged_ref(q, k_pages, v_pages, block_table, ctx_len,
     static window length (spec K+1, or 1 for plain decode); q_len: (B,)
     int32 valid window rows per slot (ragged — rows past it return zero);
     ctx_len: (B,) int32 tokens in the pages *before* this window (window
-    position i attends positions <= ctx_len + i). k_pages/v_pages/
-    block_table as in ``paged_attention_ref``. Returns (B, Hkv, R, dh).
+    position i attends positions <= ctx_len + i). k_pages/v_pages:
+    (n_pages, Hkv, page_size, dh) physical page pools of one layer;
+    block_table: (B, max_pages) int32 physical page per logical page.
+    Gathers each slot's pages into a contiguous view and runs a plain
+    max-shifted softmax in f32 — the oracle the kernel's online softmax
+    must match. Returns (B, Hkv, R, dh).
     """
     out_dtype = out_dtype or q.dtype
     b, hkv, _, dh = q.shape
@@ -217,7 +147,11 @@ def paged_decode_ragged_quant_ref(q, k_codes, k_scale, v_codes, v_scale,
     """Quantized-pool variant of ``paged_decode_ragged_ref``: pools hold
     uint8 codebook codes + per-token f32 scale, dequantized after the page
     gather (``lut[codes] * scale``) — the oracle the fused-LUT megakernel
-    must match. Args as ``paged_attention_quant_ref`` plus q_len/w."""
+    must match. k_codes/v_codes: (n_pages, Hkv, page_size, dh) uint8;
+    k_scale/v_scale: (n_pages, Hkv, lanes) f32, lane t the scale of the
+    page's token t (``spx.kv_scale_lanes``); lut: the KV scheme's f32
+    codebook (at most 256 entries). Other args as
+    ``paged_decode_ragged_ref``."""
     out_dtype = out_dtype or q.dtype
     b, hkv, _, dh = q.shape
     ps = k_codes.shape[2]

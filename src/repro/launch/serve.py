@@ -159,16 +159,6 @@ def main(argv=None):
     ap.add_argument("--spec-k", type=int, default=None, metavar="K",
                     help="draft window for --spec-decode (default 4; "
                          "passing it alone implies --spec-decode)")
-    fg = ap.add_mutually_exclusive_group()
-    fg.add_argument("--fused-decode", dest="fused_decode",
-                    action="store_true", default=None,
-                    help="ragged decode megakernel: one attention launch "
-                         "per decode tick (paged layout; default ON, "
-                         "REPRO_FUSED_DECODE=0 flips the default)")
-    fg.add_argument("--no-fused-decode", dest="fused_decode",
-                    action="store_false",
-                    help="per-call paged-attention kernels + page-gather "
-                         "verify (the pre-megakernel decode path)")
     ap.add_argument("--scheduler", default=None, choices=("fifo", "cb"),
                     help="admission policy: cb = continuous batching with "
                          "priority preemption + KV offload (paged default), "
@@ -238,7 +228,6 @@ def main(argv=None):
                         else jnp.float32),
         prefix_cache=args.prefix_cache,
         spec_decode=args.spec_decode, spec_k=args.spec_k,
-        fused_decode=args.fused_decode,
         scheduler=args.scheduler, host_pages=args.host_pages,
         prefix_cache_pages=args.prefix_cache_pages,
         shards=args.shards, replicas=args.replicas).resolve(cfg)

@@ -22,8 +22,7 @@ from .lm import _default_positions, _head_w, chunked_ce
 __all__ = ["encdec_init", "encdec_loss", "encdec_encode", "encdec_prefill",
            "encdec_decode_step", "encdec_init_caches", "enc_cfg", "dec_cfg",
            "encdec_paged_init_caches", "encdec_cross_kv",
-           "encdec_paged_step", "encdec_paged_verify",
-           "encdec_paged_fused_step"]
+           "encdec_paged_step", "encdec_paged_fused_step"]
 
 
 def enc_cfg(cfg: ArchConfig) -> ArchConfig:
@@ -166,20 +165,6 @@ def encdec_paged_step(params, tokens, ctx_len, block_table, n_valid,
     last = jnp.clip(n_valid - 1, 0, tokens.shape[1] - 1)          # (B,)
     h_last = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
     logits = jnp.dot(h_last, params["head"]["w"].astype(h.dtype))
-    return logits, new_caches
-
-
-def encdec_paged_verify(params, tokens, ctx_len, block_table, n_valid,
-                        state_idx, caches, cfg: ArchConfig, rt: Runtime):
-    """Decoder twin of ``lm.lm_paged_verify``: logits at every window
-    position, (B, C, V)."""
-    x = embedding_apply(params["embed"], tokens)
-    dcfg = dec_cfg(cfg)
-    h, new_caches = stack_paged(params["dec_stack"], x, ctx_len,
-                                block_table, n_valid, state_idx, dcfg, rt,
-                                caches)
-    h = norm_apply(cfg.norm, params["final_norm"], h)
-    logits = jnp.dot(h, params["head"]["w"].astype(h.dtype))
     return logits, new_caches
 
 

@@ -25,7 +25,7 @@ from repro.nn.transformer import (slot_init_cache, slot_init_paged_cache,
 
 __all__ = ["lm_init", "lm_loss", "lm_logits", "lm_prefill", "lm_decode_step",
            "init_caches", "paged_init_caches", "lm_paged_step",
-           "lm_paged_verify", "lm_paged_fused_step", "paged_copy_page",
+           "lm_paged_fused_step", "paged_copy_page",
            "paged_gather_pages", "paged_scatter_pages",
            "paged_gather_slabs", "paged_scatter_slabs", "paged_reset_slabs",
            "paged_fill_cross", "chunked_ce"]
@@ -344,9 +344,8 @@ def paged_fill_cross(caches, idx, entries):
 
 def lm_paged_step(params, tokens, ctx_len, block_table, n_valid, state_idx,
                   caches, cfg: ArchConfig, rt: Runtime):
-    """One paged engine step: run the next C tokens of each sequence —
-    a prefill chunk (C > 1) or a decode step (C == 1) — against the
-    unified state-cache.
+    """One paged prefill step: run the next C tokens of each sequence's
+    prompt (a chunk; C may be 1) against the unified state-cache.
 
     tokens: (B, C) int32 (rows may be padded past ``n_valid``);
     ctx_len: (B,) int32 tokens already in the pages; block_table:
@@ -370,59 +369,28 @@ def lm_paged_step(params, tokens, ctx_len, block_table, n_valid, state_idx,
     return logits, new_caches
 
 
-def lm_paged_verify(params, tokens, ctx_len, block_table, n_valid,
-                    state_idx, caches, cfg: ArchConfig, rt: Runtime):
-    """Score a speculation window in one paged forward pass (speculative
-    decoding's verify step — serving/spec.py has the drafter).
-
-    Same contract as ``lm_paged_step`` — ``tokens`` (B, C) is each row's
-    next C tokens (here: the pending token plus up to C-1 draft tokens,
-    padded past ``n_valid``), written to the pages and attended causally
-    within the window through the chunked-prefill page-gather path — but
-    logits come back at **every** window position, (B, C, V): position j
-    is the model's distribution for the token *after* window token j,
-    which is exactly what acceptance needs to compare draft j+1 against.
-    C is the draft window (K+1, single-digit), so the (B, C, V) block
-    stays tiny. Rows with ``n_valid`` < C carry garbage logits past their
-    window — the engine only reads positions < n_valid.
-    """
-    with _serving_precision(cfg), jax.named_scope("verify_step"):
-        x = embedding_apply(params["embed"], tokens)
-        h, new_caches = stack_paged(params["stack"], x, ctx_len,
-                                    block_table, n_valid, state_idx, cfg,
-                                    rt, caches)
-        logits = _window_head(params, cfg, h)
-    return logits, new_caches
-
-
 def lm_paged_fused_step(params, tokens, ctx_len, block_table, n_valid,
                         state_idx, caches, cfg: ArchConfig, rt: Runtime):
-    """One fused decode tick: plain decode (C == 1) *and* the speculative
+    """One decode tick: plain decode (C == 1) *and* the speculative
     verify window (C == K+1) through the ragged decode megakernel — every
     layer's attention is ONE ``paged_decode_ragged`` launch over the
-    batch's ragged (slot, attend_len) grid instead of a per-call kernel
-    plus page gathers.
+    batch's ragged (slot, attend_len) grid.
 
-    Same contract as ``lm_paged_verify``: ``tokens`` (B, C) is each row's
-    next window (pending token + drafts, padded past ``n_valid``), and
-    logits come back at every window position, (B, C, V) — position j is
-    the distribution for the token after window token j. With C == 1 the
-    engine reads logits[:, 0] and this is exactly ``lm_paged_step``'s
-    decode tick, so one compiled function serves both tick shapes.
-    Rows past ``n_valid`` carry garbage logits (the kernel returns zeros
-    for them pre-head) — the engine only reads positions < n_valid.
+    Arguments as ``lm_paged_step``: ``tokens`` (B, C) is each row's next
+    window (the pending token plus up to C-1 drafts, padded past
+    ``n_valid``), written to the pages and attended causally within the
+    window. Logits come back at every window position, (B, C, V) —
+    position j is the distribution for the token after window token j,
+    which is what acceptance compares draft j+1 against. Rows past
+    ``n_valid`` carry garbage logits (the kernel returns zeros for them
+    pre-head) — the engine only reads positions < n_valid.
     """
     with _serving_precision(cfg), jax.named_scope("decode_step"):
         x = embedding_apply(params["embed"], tokens)
         h, new_caches = stack_paged(params["stack"], x, ctx_len,
                                     block_table, n_valid, state_idx, cfg,
                                     rt, caches, fused=True)
-        logits = _window_head(params, cfg, h)
+        with jax.named_scope("head"):
+            h = norm_apply(cfg.norm, params["final_norm"], h)
+            logits = jnp.dot(h, _head_w(params, cfg).astype(h.dtype))
     return logits, new_caches
-
-
-def _window_head(params, cfg: ArchConfig, h):
-    """Final norm and vocabulary head at every window position."""
-    with jax.named_scope("head"):
-        h = norm_apply(cfg.norm, params["final_norm"], h)
-        return jnp.dot(h, _head_w(params, cfg).astype(h.dtype))

@@ -496,8 +496,8 @@ def _paged_chunk_attention(q, k_pages, v_pages, layer, block_table,
     ``layer``; positions: (B, C) absolute query positions; attend_len:
     (B,) total attendable tokens. Gathers this sequence's pages into a
     contiguous view — prefill is compute-bound, so the gather's bytes are
-    amortized; the single-token hot path goes through the paged-attention
-    kernel instead. Returns (B, Hq, C, dh).
+    amortized; the decode tick goes through the ragged decode kernel
+    instead. Returns (B, Hq, C, dh).
     """
     b, hq, c, dh = q.shape
     quantized = isinstance(k_pages, dict)
@@ -527,23 +527,23 @@ def attn_paged_step(p: dict, x: jax.Array, ctx_len: jax.Array,
                     n_heads: int, n_kv_heads: int, head_dim: int,
                     n_valid: jax.Array, rope_theta: float = 10000.0,
                     mrope_sections=None, rt: Runtime, fused: bool = False):
-    """Attention sublayer over the paged KV cache — one code path for both
-    chunked prefill (C > 1) and decode (C == 1, dispatched to the
-    paged-attention kernel via the registry).
+    """Attention sublayer over the paged KV cache, for a prefill chunk
+    and for the decode window alike.
 
     x: (B, C, D) — the next C tokens of each sequence; ctx_len: (B,) int32
     tokens already in the pages; n_valid: (B,) int32 valid tokens in this
     chunk (< C for ragged tails / inactive rows — invalid tokens are
     neither written nor trusted); cache: {"kp", "vp"} physical pools of
     every layer, stacked on axis 0 — arrays, or {"codes", "scale"} dicts
-    for the quantized pool (``rt.kv_scheme`` picks the level set; decode
-    then dispatches to the fused-dequant paged-attention kernel); layer:
+    for the quantized pool (``rt.kv_scheme`` picks the level set); layer:
     int32 scalar, this sublayer's index into them — the new rows are
-    written and read there in place. ``fused`` routes the attention
-    through the ragged decode megakernel (``ops.paged_decode_ragged``) —
-    one launch for the whole ragged window, n_valid as the per-slot
-    ``q_len``, dense or quantized pools alike; the serving engine's
-    decode/verify tick sets it, chunked prefill keeps the gather path.
+    written and read there in place. ``fused`` (the serving engine's
+    decode tick: the pending token, plus the speculative verify window)
+    attends through the ragged decode megakernel
+    (``ops.paged_decode_ragged``) — one launch for the whole ragged
+    window, n_valid as the per-slot ``q_len``, dense or quantized pools
+    alike. Otherwise the C-token prefill chunk, of any C, attends through
+    the page gather (``_paged_chunk_attention``).
     ``rope_theta`` None: no positional encoding (NoPE). Returns
     (y (B, C, D), new_cache).
     """
@@ -577,19 +577,6 @@ def attn_paged_step(p: dict, x: jax.Array, ctx_len: jax.Array,
             kv_scheme=rt.kv_scheme if quantized else None, impl=rt.impl,
             mesh=rt.mesh, head_axis=rt.model_axis)
         o = out.reshape(b, c, n_heads * head_dim)
-    elif c == 1:
-        # the unfused kernels read one layer's pools (a copy of it)
-        kl, vl = jax.tree_util.tree_map(lambda a: a[layer], (kp, vp))
-        q1 = q[:, 0].reshape(b, n_heads, head_dim)
-        if quantized:
-            out = ops.paged_attention_quant(q1, kl, vl, block_table,
-                                            attend_len,
-                                            kv_scheme=rt.kv_scheme,
-                                            impl=rt.impl, mesh=rt.mesh)
-        else:
-            out = ops.paged_attention(q1, kl, vl, block_table, attend_len,
-                                      impl=rt.impl, mesh=rt.mesh)
-        o = out.reshape(b, 1, n_heads * head_dim)
     else:
         qh = jnp.swapaxes(q, 1, 2)                       # (B, Hq, C, dh)
         o = _paged_chunk_attention(qh, kp, vp, layer, block_table,

@@ -455,8 +455,8 @@ def stack_decode(params: dict, x: jax.Array, pos, cfg: ArchConfig,
 def stack_paged(params: dict, x: jax.Array, ctx_len, block_table, n_valid,
                 state_idx, cfg: ArchConfig, rt: Runtime, caches, *,
                 fused: bool = False):
-    """C-token step over the unified state-cache — chunked prefill (C > 1)
-    and paged decode (C == 1) share this path, for every slot kind. x:
+    """C-token step over the unified state-cache — prefill chunks and the
+    decode window share this path, for every slot kind. x:
     (B, C, D); ctx_len/n_valid: (B,) int32; block_table: (B, max_pages)
     int32; state_idx: (B, 2) int32 — column 0 is each row's slab index
     (SSM state), column 1 its cross-region entry (encoder-output KV);
@@ -465,8 +465,9 @@ def stack_paged(params: dict, x: jax.Array, ctx_len, block_table, n_valid,
     (``slot_init_paged_cache``). The scan carries them whole and each
     period reads and writes its own index in place: no period's region is
     sliced out, and no second copy is stacked. ``fused`` routes every
-    attention through the ragged decode megakernel (serving decode/verify
-    ticks; prefill chunks stay on the gather path). Returns
+    attention through the ragged decode megakernel (the serving decode
+    tick, speculative window included; prefill chunks of any length stay
+    on the gather path). Returns
     (x, new_caches)."""
     def body(carry, xs):
         return _period_body(carry, xs, cfg=cfg, rt=rt, mode="paged",

@@ -95,7 +95,7 @@ class AttentionBlocks:
 class KVPagePlan:
     """Geometry for the paged KV cache (serving decode path).
 
-    page_size    tokens per page — the unit the paged-attention kernel
+    page_size    tokens per page — the unit the ragged decode kernel
                  streams per grid step AND the allocator's granularity
     pipelined    §3.1 condition for the decode kernel's page loop
     margin       compute/load ratio for one (K, V) page pair

@@ -20,14 +20,13 @@ The built-in kernels live in ``repro.kernels.ops`` and register themselves
 at import; ``resolve`` imports that module lazily so the registry package
 itself stays dependency-free. Current built-in ops: ``spx_matmul``,
 ``spx_expert_matmul`` (the same over a stack of experts, rows grouped by
-expert — the serving MoE layer), ``flash_attention``, ``paged_attention`` (serving decode over the paged KV
-cache — see docs/SERVING.md), ``paged_attention_quant`` (same, over
-codes+scale quantized pools with fused codebook dequant —
-docs/QUANTIZATION.md), and ``paged_decode_ragged`` /
-``paged_decode_ragged_quant`` (the decode megakernel: one launch per
-serving decode tick over a ragged (slot, attend_len) grid, covering plain
-decode and the speculative verify window, with in-kernel LUT dequant for
-quantized pools), and ``paged_kv_write`` (a step's new K/V rows into the
+expert — the serving MoE layer), ``flash_attention``,
+``paged_decode_ragged`` / ``paged_decode_ragged_quant`` (serving decode
+over the paged KV cache — see docs/SERVING.md: one launch per decode
+tick over a ragged (slot, attend_len) grid, covering plain decode and
+the speculative verify window, with in-kernel LUT dequant for
+codes+scale quantized pools — docs/QUANTIZATION.md), and
+``paged_kv_write`` (a step's new K/V rows into the
 stacked page pools, in place).
 """
 from __future__ import annotations

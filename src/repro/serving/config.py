@@ -1,16 +1,14 @@
 """One frozen config object for every serving knob.
 
-``ServeEngine`` grew its knobs one PR at a time — fifteen keyword
-arguments, each with its own ``REPRO_*`` env fallback and its own
-cross-knob gate scattered through ``__init__``. ``ServeConfig`` collapses
-them into a single frozen dataclass; ``ServeConfig.resolve(cfg)`` is the
-ONLY place env fallbacks are read and cross-knob validation runs, and it
-returns a fully-resolved copy (every ``None``/"auto" replaced by the
-concrete value the engine will use). The engine, the replica router,
-benches and the CLI all construct from the same resolved object, so a
-knob combination is legal or illegal in exactly one place.
+``ServeConfig`` holds every ``ServeEngine`` knob in a single frozen
+dataclass; ``ServeConfig.resolve(cfg)`` is the ONLY place env fallbacks
+are read and cross-knob validation runs, and it returns a fully-resolved
+copy (every ``None``/"auto" replaced by the concrete value the engine
+will use). The engine, the replica router, benches and the CLI all
+construct from the same resolved object, so a knob combination is legal
+or illegal in exactly one place.
 
-Resolution contract (unchanged from the per-kwarg era, now centralized):
+Resolution contract:
 
 * ``None`` means "read the env default, else the built-in default".
 * An env-enabled feature **degrades silently** where the architecture or
@@ -19,13 +17,15 @@ Resolution contract (unchanged from the per-kwarg era, now centralized):
   the failing predicate(s) enumerated.
 * ``resolve()`` is idempotent: resolving a resolved config returns it
   unchanged, so plumbing can resolve defensively.
+* ``fused_decode`` is no choice: there is one decode path (the ragged
+  decode kernel), ``None`` and ``True`` both resolve to it and ``False``
+  raises.
 
 Env knobs owned here: ``REPRO_PREFIX_CACHE``, ``REPRO_SPEC_K``,
-``REPRO_FUSED_DECODE``, ``REPRO_SCHEDULER``, ``REPRO_HOST_PAGES``,
-``REPRO_PREFIX_CACHE_PAGES``, ``REPRO_PREFILL_CHUNK``, ``REPRO_SHARDS``,
-``REPRO_REPLICAS``. (``REPRO_PAGE_SIZE`` stays with the planner: it pins
-the *planned* page size for every consumer of ``plan_kv_pages``, not just
-the engine.)
+``REPRO_SCHEDULER``, ``REPRO_HOST_PAGES``, ``REPRO_PREFIX_CACHE_PAGES``,
+``REPRO_PREFILL_CHUNK``, ``REPRO_SHARDS``, ``REPRO_REPLICAS``.
+(``REPRO_PAGE_SIZE`` stays with the planner: it pins the *planned* page
+size for every consumer of ``plan_kv_pages``, not just the engine.)
 
 Sharding knobs (docs/SERVING.md "Sharded serving"):
 
@@ -47,24 +47,13 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.serving.spec import DEFAULT_SPEC_K
 
-__all__ = ["ServeConfig", "DEFAULT_PREFILL_CHUNK", "LEGACY_KNOBS"]
+__all__ = ["ServeConfig", "DEFAULT_PREFILL_CHUNK"]
 
 #: chunk length for chunked prefill when the caller doesn't pass one;
 #: REPRO_PREFILL_CHUNK=N overrides. Ragged final chunks are padded up to
 #: the next power of two so the engine compiles O(log chunk) variants,
 #: not one per prompt length.
 DEFAULT_PREFILL_CHUNK = 32
-
-#: the pre-ServeConfig ``ServeEngine.__init__`` keyword knobs — accepted
-#: for one PR via a DeprecationWarning shim that forwards them into a
-#: ServeConfig (see ServeEngine.__init__).
-LEGACY_KNOBS = frozenset({
-    "batch_slots", "max_seq", "quantize", "seed", "kv_layout", "page_size",
-    "pool_pages", "prefill_chunk", "kv_cache_dtype", "prefix_cache",
-    "spec_decode", "spec_k", "fused_decode", "scheduler", "host_pages",
-    "prefix_cache_pages", "shards", "replicas",
-})
-
 
 def _decode_pattern_cfg(cfg: ArchConfig) -> ArchConfig:
     """The config whose layer pattern holds serving state (the DECODER
@@ -83,10 +72,8 @@ def _slab_mixers(dcfg: ArchConfig) -> list[str]:
 
 @dataclasses.dataclass(frozen=True)
 class ServeConfig:
-    """Every ServeEngine knob, in one frozen object. Field semantics are
-    identical to the old ``ServeEngine.__init__`` keywords; ``shards`` /
-    ``replicas`` are new (sharded serving). Construct with whatever
-    subset you care about and let ``resolve()`` fill the rest::
+    """Every ServeEngine knob, in one frozen object. Construct with
+    whatever subset you care about and let ``resolve()`` fill the rest::
 
         eng = ServeEngine(params, cfg, ServeConfig(batch_slots=8,
                                                    kv_layout="paged"))
@@ -103,6 +90,9 @@ class ServeConfig:
     prefix_cache: Optional[bool] = None
     spec_decode: Optional[bool] = None
     spec_k: Optional[int] = None
+    #: no choice left: a paged engine decodes through the ragged decode
+    #: kernel, and False is an error. Kept only while the benchmark's
+    #: harness passes fused_decode=True; it goes when that stops.
     fused_decode: Optional[bool] = None
     scheduler: Optional[str] = None
     host_pages: Optional[int] = None
@@ -130,8 +120,7 @@ class ServeConfig:
         if self.resolved:
             return self
         dcfg = _decode_pattern_cfg(cfg)
-        mixers = {s.split("+")[0] for s in dcfg.pattern}
-        has_slab = bool(mixers & {"mamba", "mlstm", "slstm"})
+        has_slab = bool(_slab_mixers(dcfg))
         has_cross = bool(cfg.enc_dec)
 
         if self.batch_slots < 1:
@@ -183,7 +172,7 @@ class ServeConfig:
             prefix_cache = False
 
         # speculative decoding (paged only — the verify window rides the
-        # paged chunk path). None = read the env default (REPRO_SPEC_K=N
+        # paged decode program). None = read the env default (REPRO_SPEC_K=N
         # enables with window N); passing spec_k alone also enables —
         # a window size IS the intent, silently ignoring it would let a
         # caller benchmark speculation that never ran. Mirroring
@@ -209,9 +198,8 @@ class ServeConfig:
             spec_decode = env_k > 0 or raw_k is not None
         spec_gaps = []
         if kv_layout != "paged":
-            spec_gaps.append("kv_layout='dense' — the verify step scores "
-                             "the draft window through the paged chunk "
-                             "path")
+            spec_gaps.append("kv_layout='dense' — the paged decode "
+                             "program scores the draft window")
         if has_slab:
             spec_gaps.append(
                 f"recurrent mixer(s) {_slab_mixers(dcfg)} in "
@@ -232,21 +220,10 @@ class ServeConfig:
         else:
             spec_k = 0
 
-        # fused ragged-decode megakernel (paged only). Default ON for
-        # paged engines (REPRO_FUSED_DECODE=0 opts out); the env default
-        # degrades silently for a dense engine while an explicit True
-        # there is a caller error.
-        explicit_fused = self.fused_decode is not None
-        fused_decode = self.fused_decode
-        if fused_decode is None:
-            fused_decode = os.environ.get(
-                "REPRO_FUSED_DECODE", "1").lower() not in ("0", "false")
-        if fused_decode and kv_layout != "paged":
-            if explicit_fused:
-                raise ValueError(
-                    "fused_decode=True needs kv_layout='paged' — the "
-                    "megakernel decodes through the paged page pools")
-            fused_decode = False
+        if self.fused_decode is False:
+            raise ValueError(
+                "fused_decode=False: the per-call decode path was removed "
+                "— the decode tick always runs the ragged decode kernel")
 
         # scheduler: "cb" (continuous batching — priority admission with
         # preemption + KV offload, the paged default) or "fifo" (the
@@ -335,7 +312,7 @@ class ServeConfig:
             self, kv_layout=kv_layout,
             kv_cache_dtype=jnp.dtype(self.kv_cache_dtype),
             prefix_cache=bool(prefix_cache), spec_decode=bool(spec_decode),
-            spec_k=spec_k, fused_decode=bool(fused_decode),
+            spec_k=spec_k, fused_decode=True,
             scheduler=scheduler, host_pages=host_pages,
             prefix_cache_pages=prefix_cache_pages,
             prefill_chunk=prefill_chunk, shards=shards, replicas=replicas,

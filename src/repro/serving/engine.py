@@ -18,9 +18,10 @@ Two KV layouts (docs/SERVING.md has the full lifecycle):
   covers its worst-case footprint, otherwise it waits in the queue.
   Prompts stream through **chunked prefill** (planner/env-sized chunks,
   one chunk per engine tick per slot, interleaved with decode steps of
-  already-running sequences), and decode attends through the block table
-  via the paged-attention kernel. Memory scales with tokens + sequences
-  in flight, not ``batch_slots x max_seq``.
+  already-running sequences), and every decode tick is one launch of the
+  decode program, whose attention is the ragged decode kernel reading
+  the pages through the block table. Memory scales with tokens +
+  sequences in flight, not ``batch_slots x max_seq``.
 
 * **dense**: the original per-slot ``(B, Hkv, max_seq, dh)`` cache (plus
   per-slot recurrent state / cross-KV blocks where the pattern has
@@ -43,8 +44,8 @@ The paged layout also supports **speculative decoding**
 (``spec_decode=True`` / ``--spec-decode`` / ``REPRO_SPEC_K=N``): a
 model-free prompt-lookup drafter (serving/spec.py) proposes up to K
 tokens per decoding slot from the sequence's own n-gram history, one
-batched verify pass scores the whole K+1 window against the paged cache
-(``models/lm.lm_paged_verify``), and the engine keeps the longest
+decode tick scores the whole K+1 window against the paged cache
+(``models/lm.lm_paged_fused_step``), and the engine keeps the longest
 accepted prefix plus one bonus/correction token — rolling the KV write
 cursor back past any rejected tail. Greedy outputs are identical with
 speculation on or off (regression-tested); what changes is model calls
@@ -54,7 +55,7 @@ per emitted token (``metrics()["model_calls"]``,
 Either layout composes with the quantized KV cache (``rt.kv_quant`` +
 ``rt.kv_scheme`` — uniform8 baseline or non-uniform SPx): paged pools
 store uint8 codes + per-token scale and decode through the fused-dequant
-paged-attention kernel; page/pool byte accounting follows the layout
+ragged decode kernel; page/pool byte accounting follows the layout
 actually allocated (``kv_cache_dtype``, or codes+scale when quantized).
 
 Both layouts produce identical greedy outputs (regression-tested); the
@@ -71,7 +72,6 @@ import collections
 import dataclasses
 import hashlib
 import time
-import warnings
 from typing import Optional
 
 import jax
@@ -84,7 +84,8 @@ from repro.models import encdec as encdec_mod
 from repro.models import lm as lm_mod
 from repro.nn.layers import quantize_params
 from repro.runtime import Runtime, planner
-from repro.serving.config import LEGACY_KNOBS, ServeConfig
+from repro.serving.config import (ServeConfig, _decode_pattern_cfg,
+                                  _slab_mixers)
 from repro.serving.kv_cache import (StateCache, cross_kv_bytes_per_seq,
                                     kv_bytes_per_token, page_bytes,
                                     ssm_state_bytes_per_seq)
@@ -148,25 +149,10 @@ def _pad_pow2(n: int, cap: int) -> int:
 class ServeEngine:
     def __init__(self, params, cfg: ArchConfig,
                  config: ServeConfig | None = None, *,
-                 rt: Runtime | None = None, devices=None, **legacy):
-        # one-PR migration shim: the old per-knob keyword arguments are
-        # still accepted, forwarded into a ServeConfig with a
-        # DeprecationWarning. ServeConfig is the sole knob path.
+                 rt: Runtime | None = None, devices=None):
         if config is not None and not isinstance(config, ServeConfig):
             raise TypeError(
                 f"config must be a ServeConfig, got {type(config).__name__}")
-        if legacy:
-            unknown = sorted(set(legacy) - LEGACY_KNOBS)
-            if unknown:
-                raise TypeError(
-                    f"ServeEngine() got unexpected keyword argument(s) "
-                    f"{unknown} — serving knobs live on ServeConfig")
-            warnings.warn(
-                "passing serving knobs as ServeEngine keyword arguments "
-                "is deprecated — construct a ServeConfig instead: "
-                f"ServeEngine(params, cfg, ServeConfig({', '.join(sorted(legacy))}))",
-                DeprecationWarning, stacklevel=2)
-            config = (config or ServeConfig()).replace(**legacy)
         self.cfg = cfg
         self.rt = rt or Runtime(impl="auto", q_chunk=256)
         # ALL env fallback + cross-knob validation happens here, nowhere
@@ -184,7 +170,6 @@ class ServeEngine:
         self.kv_layout = sc.kv_layout
         self.prefix_cache = sc.prefix_cache
         self.spec_k = sc.spec_k
-        self.fused_decode = sc.fused_decode
         self.scheduler = sc.scheduler
         self.host_pages = sc.host_pages
         self.prefix_cache_pages = sc.prefix_cache_pages
@@ -205,10 +190,10 @@ class ServeEngine:
         # pin one fixed-size slab per live sequence, enc-dec adds a
         # read-only shared cross region. For enc-dec models the DECODER
         # pattern is what holds serving state.
-        self._decode_cfg = encdec_mod.dec_cfg(cfg) if cfg.enc_dec else cfg
-        mixers = {s.split("+")[0] for s in self._decode_cfg.pattern}
-        self._has_pages = bool(mixers & {"attn", "xdec"})
-        self._has_slab = bool(mixers & {"mamba", "mlstm", "slstm"})
+        self._decode_cfg = _decode_pattern_cfg(cfg)
+        self._has_pages = any(s.split("+")[0] in ("attn", "xdec")
+                              for s in self._decode_cfg.pattern)
+        self._has_slab = bool(_slab_mixers(self._decode_cfg))
         self._has_cross = bool(cfg.enc_dec)
 
         # tensor parallelism (shards > 1): build a (data=1, model=shards)
@@ -299,11 +284,6 @@ class ServeEngine:
             self.caches = jax.device_put(self.caches,
                                          self._policy.named(specs))
 
-    def _slab_mixers(self) -> list[str]:
-        """The recurrent mixer kinds present in the decode pattern."""
-        return sorted({s.split("+")[0] for s in self._decode_cfg.pattern}
-                      & {"mamba", "mlstm", "slstm"})
-
     def _paged_layers(self) -> int:
         """Layer-slot count of the token-KV page pools (the pools'
         leading dim — what one page spans byte-wise)."""
@@ -385,7 +365,6 @@ class ServeEngine:
                 dtype=self.kv_cache_dtype, kv_quant=self.rt.kv_quant,
                 n_slabs=self._n_slabs, n_cross=self._n_cross)
             step_fn = encdec_mod.encdec_paged_step
-            verify_fn = encdec_mod.encdec_paged_verify
             fused_fn = encdec_mod.encdec_paged_fused_step
         else:
             self.caches = lm_mod.paged_init_caches(
@@ -393,27 +372,15 @@ class ServeEngine:
                 dtype=self.kv_cache_dtype, kv_quant=self.rt.kv_quant,
                 n_slabs=self._n_slabs, n_cross=self._n_cross)
             step_fn = lm_mod.lm_paged_step
-            verify_fn = lm_mod.lm_paged_verify
             fused_fn = lm_mod.lm_paged_fused_step
         self._paged_step = jax.jit(step_fn, static_argnums=(7, 8),
                                    donate_argnums=(6,))
-        if self.fused_decode:
-            # decode megakernel tick: ONE compiled function serves both
-            # tick shapes — plain decode (W == 1) and the spec verify
-            # window (W == spec_k + 1) — and inside it every layer's
-            # attention is one paged_decode_ragged launch
-            self._fused_step = jax.jit(fused_fn, static_argnums=(7, 8),
-                                       donate_argnums=(6,))
+        # the decode tick: one compiled function over a fixed window per
+        # row — 1, or spec_k + 1 with speculation — in which every layer's
+        # attention is one paged_decode_ragged launch
+        self._fused_step = jax.jit(fused_fn, static_argnums=(7, 8),
+                                   donate_argnums=(6,))
         if self.spec_k:
-            if not self.fused_decode:
-                # multi-token verify: same paged step, logits at every
-                # window position; one compile serves every tick (fixed
-                # K+1 window, ragged rows ride on n_valid like prefill
-                # chunks do). The fused path scores windows through
-                # _fused_step instead.
-                self._paged_verify = jax.jit(verify_fn,
-                                             static_argnums=(7, 8),
-                                             donate_argnums=(6,))
             self.drafter = PromptLookupDrafter()
         # copy-on-write page duplication; src/dst ride as traced scalars
         # so the one compile covers every page pair
@@ -855,7 +822,6 @@ class ServeEngine:
                      "cow_copies": self._cow_copies,
                      "spec_decode": bool(self.spec_k),
                      "spec_k": self.spec_k,
-                     "fused_decode": self.fused_decode,
                      # drafts accepted per drafted window (one window =
                      # one slot that proposed >= 1 draft this tick) /
                      # per proposed draft token — 0.0 until one ran
@@ -907,9 +873,8 @@ class ServeEngine:
             "tokens_generated": self._tokens_out,
             "engine_steps": self._steps,
             "model_calls": sum(self._launches.values()),
-            # launches by program: prefill, decode (fused or the
-            # one-token step), verify (unfused speculation),
-            # dense_prefill, dense_decode, encode (enc-dec cross entries)
+            # launches by program: prefill, decode, dense_prefill,
+            # dense_decode, encode (enc-dec cross entries)
             "launches": dict(self._launches),
             # prompt tokens over rows computed by the prefill launches:
             # the share not spent on padding and idle slots
@@ -1292,11 +1257,12 @@ class ServeEngine:
         with span(f"serve.{kind}.wait", bytes=logits.nbytes):
             return np.asarray(logits)
 
-    def _paged_launch(self, kind: str, program: str, step, width: int,
+    def _paged_launch(self, kind: str, step, width: int,
                       feed: dict) -> np.ndarray:
-        """One paged step over a ``(batch_slots, width)`` token block.
-        ``feed``: slot -> (tokens already in its pages, its next tokens);
-        the other rows ride along masked (n_valid = 0)."""
+        """One paged step (the ``kind`` program: prefill or decode) over a
+        ``(batch_slots, width)`` token block. ``feed``: slot -> (tokens
+        already in its pages, its next tokens); the other rows ride along
+        masked (n_valid = 0)."""
         def call():
             tokens = np.zeros((self.batch_slots, width), np.int32)
             ctx = np.zeros(self.batch_slots, np.int32)
@@ -1312,7 +1278,7 @@ class ServeEngine:
                 self.rt)
             return logits
         args = {"c_pad": width} if kind == "prefill" else {}
-        return self._launch(kind, program, call,
+        return self._launch(kind, kind, call,
                             rows=self.batch_slots * width,
                             tokens=sum(len(t) for _, t in feed.values()),
                             **args)
@@ -1335,8 +1301,8 @@ class ServeEngine:
                     fed:fed + self.prefill_chunk])
             c_pad = _pad_pow2(max(len(t) for _, t in feed.values()),
                               self.prefill_chunk)
-            logits = self._paged_launch("prefill", "prefill",
-                                        self._paged_step, c_pad, feed)
+            logits = self._paged_launch("prefill", self._paged_step, c_pad,
+                                        feed)
             with span("serve.prefill.emit"):
                 for i in rows:
                     self._after_chunk(i, len(feed[i][1]), logits[i])
@@ -1367,25 +1333,21 @@ class ServeEngine:
             self._maybe_finish(slot)        # max_new_tokens == 1
 
     def _decode_step_paged(self):
-        """One decode tick for every decoding slot, in one launch over a
-        fixed window W per row: the pending token plus this slot's drafts
-        (``n_valid`` carries the ragged part). Three programs serve it:
-
-        * fused (``lm_paged_fused_step``): every layer's attention is one
-          ``paged_decode_ragged`` launch; W = spec_k + 1, or 1 without
-          speculation, so drafted and undrafted ticks share one compile;
-        * unfused with drafts (``lm_paged_verify``): W = spec_k + 1;
-        * unfused without drafts (``lm_paged_step``, the C == 1 decode
-          kernel): W = 1 — no K+1 window is paid for zero drafts.
+        """One decode tick for every decoding slot, in one launch of the
+        decode program (``lm_paged_fused_step``) over a fixed window W per
+        row: the pending token plus this slot's drafts (``n_valid``
+        carries the ragged part). W = spec_k + 1, or 1 without
+        speculation, so drafted and undrafted ticks share one compile;
+        every layer's attention is one ``paged_decode_ragged`` launch.
 
         Each row keeps its longest accepted prefix plus a bonus or
         correction token (``_accept_tokens``; with no drafts that IS the
-        plain greedy/sampled pick, same key chain), so greedy outputs are
-        bit-identical across the three — regression-tested. Rollback is
-        cursor arithmetic: ``slot_pos`` advances only past accepted
-        tokens; a rejected tail's page slots are overwritten by the next
-        window at those positions and never attended (``attend_len``
-        masks them)."""
+        plain greedy/sampled pick, same key chain), so greedy outputs
+        are identical with speculation on or off — regression-tested.
+        Rollback is cursor arithmetic: ``slot_pos`` advances only past
+        accepted tokens; a rejected tail's page slots are overwritten by
+        the next window at those positions and never attended
+        (``attend_len`` masks them)."""
         active = [i for i, r in enumerate(self.slot_req)
                   if r is not None and self._fed[i] < 0]
         if not active:
@@ -1393,18 +1355,11 @@ class ServeEngine:
         with span("serve.decode"):
             drafts = {i: self._propose(i) for i in active}
             w = (self.spec_k + 1) if self.spec_k else 1
-            if self.fused_decode:
-                step, program = self._fused_step, "decode"
-            elif any(drafts.values()):
-                step, program = self._paged_verify, "verify"
-            else:
-                step, program, w = self._paged_step, "decode", 1
             feed = {i: (int(self.slot_pos[i]),
                         [self.slot_req[i].output[-1]] + drafts[i])
                     for i in active}
-            logits = self._paged_launch("decode", program, step, w, feed)
-            if logits.ndim == 2:
-                logits = logits[:, None]    # lm_paged_step: (B, V)
+            logits = self._paged_launch("decode", self._fused_step, w,
+                                        feed)
             with span("serve.decode.emit"):
                 for i in active:
                     self._emit_window(i, drafts[i], logits[i])

@@ -39,7 +39,8 @@ the **host-side** allocator that maps sequences onto pages:
 
 The *device* side consumes only the ``block_table`` this produces: an
 ``(n_seqs, pages_per_seq)`` int32 array of physical page indices that the
-paged-attention kernel uses to gather K/V (see kernels/paged_attention.py).
+ragged decode kernel streams K/V pages through (kernels/paged_attention.py)
+and the prefill chunk's page gather reads (nn/attention.py).
 Unused table slots point at page 0 and are masked by the context length.
 Shared pages appear in several rows at once — the device neither knows nor
 cares; ownership and copy-on-write live here and in the engine.

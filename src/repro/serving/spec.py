@@ -14,7 +14,7 @@ proposes nothing and the engine decodes one token per pass as before.
 
 Correctness never depends on the drafter: the engine verifies every
 proposal against the target model in a single multi-token forward pass
-(``models/lm.lm_paged_verify``) and keeps only the longest accepted
+(``models/lm.lm_paged_fused_step``) and keeps only the longest accepted
 prefix, so a bad proposal costs wasted window compute, never a wrong
 token (``docs/SERVING.md`` — speculative decoding).
 

@@ -133,19 +133,6 @@ def test_paged_decode_ragged_compiles(one_chip, w, quant):
         {("paged_decode_ragged_quant", "gather"): 1} if quant else {})
 
 
-def test_paged_attention_quant_compiles(one_chip):
-    """The unragged quantized decode kernel, at the ragged one's pages."""
-    kv_scheme, (_, pool, _, bt, lens, _) = _paged_args(one_chip, 1, True)
-    q = _sds((SLOTS, HQ, DH), jnp.float32, one_chip)
-    ops.reset_op_calls()
-    compiled = _compile(
-        lambda q, kp, vp, bt, ctx: ops.paged_attention_quant(
-            q, kp, vp, bt, ctx, kv_scheme=kv_scheme, impl="pallas"),
-        q, pool, pool, bt, lens)
-    assert "tpu_custom_call" in compiled.as_text()
-    assert ops.lut_lookups() == {("paged_attention_quant", "gather"): 1}
-
-
 @pytest.mark.parametrize("seq", [256, 1024])
 def test_flash_attention_compiles(one_chip, seq):
     plan = planner.plan_attention(seq, seq, DH, act_bytes=4, hw=TPU_V5E)

@@ -1,20 +1,22 @@
 """The ragged decode megakernel (ops.paged_decode_ragged) and the serving
-engine's fused decode tick (docs/SERVING.md §megakernel):
+engine's decode tick (docs/SERVING.md §megakernel), the one decode path:
 
 * interpret-vs-ref kernel parity per KV scheme (dense, uniform8, sp2_8,
   spx_8_x3) across the whole ragged surface — q_len from 0 (inactive
   slot) through the full K+1 verify window, attend_len straddling page
-  boundaries — plus exact zeros for padded window rows,
-* the non-negotiable invariant: greedy engine outputs with the megakernel
-  ON are bit-identical to the unfused per-call decode path, across
-  {plain, kv_quant} x {spec on, spec off},
-* ONE launch per decode tick: the fused step traces the ragged op exactly
-  once, compiles exactly once, and never retraces across ticks with
-  varying attend_len / n_valid (no pow2-window padding to bucket on),
+  boundaries — plus exact zeros for padded window rows; at W == 1 the
+  oracle equals plain attention over each slot's gathered context,
+* ONE launch per decode tick: the decode step traces the ragged op
+  exactly once, compiles exactly once, and never retraces across ticks
+  with varying attend_len / n_valid (no pow2-window padding to bucket
+  on), with or without speculation,
+* engine outputs against independent oracles: the dense engine (greedy,
+  speculative, seeded sampling), a one-token final prefill chunk, and
+  enc-dec speculation through the decode program's K+1 window,
 * planner sizing (codes+scale pages + resident LUT) and the autotune key
   separating kv_scheme and the spec window,
-* knobs: REPRO_FUSED_DECODE=0 opts out, explicit fused_decode=True on a
-  dense engine is an error, the env default degrades silently there.
+* knobs: ``fused_decode`` has one value left; False is an error and the
+  removed ops and environment variable are gone.
 
 No hypothesis dependency — collected on the bare tier-1 environment.
 """
@@ -28,8 +30,10 @@ import pytest
 from repro.configs import get_config, reduced
 from repro.core import spx
 from repro.kernels import ops
+from repro.kernels import ref as kref
+from repro.models import encdec as encdec_mod
 from repro.models import lm as lm_mod
-from repro.nn.attention import quantize_pages
+from repro.nn.attention import dequantize_kv, quantize_pages
 from repro.runtime import Runtime, planner
 from repro.serving.engine import Request, ServeConfig, ServeEngine
 
@@ -94,20 +98,43 @@ def test_ragged_interpret_matches_ref(scheme, dh):
         assert (out[3] != 0).any()
 
 
-def test_ragged_w1_bit_identical_to_paged_attention():
-    """W == 1 is plain decode: the ragged ref must equal the existing
-    single-token paged-attention ref bit for bit (attend_len = ctx + 1),
-    which is what makes fused-vs-unfused greedy outputs identical."""
+def _gathered(pages, bt, scheme):
+    """Each slot's pages of ``pages`` in logical order, (B, Hkv, S, dh),
+    dequantized when ``scheme`` names one."""
+    if scheme is None:
+        b, mp = bt.shape
+        hkv, ps, dh = pages.shape[1:]
+        return jnp.moveaxis(pages[bt], 2, 1).reshape(b, hkv, mp * ps, dh)
+    codes = _gathered(pages["codes"], bt, None)
+    scale = kref.page_scales(pages["scale"][bt], pages["codes"].shape[2])
+    return dequantize_kv(codes, scale, scheme, dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("scheme", [None, "spx_8_x3"], ids=["f32", "spx"])
+def test_ragged_w1_matches_attention_over_gathered_context(scheme):
+    """W == 1 is plain decode: the ragged ref equals plain softmax
+    attention (``ref.attention_ref``) of the one query over each slot's
+    gathered context, cut at its length (attend_len = ctx + 1)."""
     rng = np.random.default_rng(5)
     b, hq, hkv, dh, ps, mp = 3, 4, 2, 32, 8, 3
-    kp, vp, bt = _pools(rng, b, hkv, ps, mp, dh, None)
+    kp, vp, bt = _pools(rng, b, hkv, ps, mp, dh, scheme)
     q = jnp.asarray(rng.standard_normal((b, 1, hq, dh)), jnp.float32)
-    ctx = jnp.asarray([ps - 1, ps, 2 * ps + 3], jnp.int32)
+    ctx = [ps - 1, ps, 2 * ps + 3]
     ones = jnp.ones((b,), jnp.int32)
-    ragged = ops.paged_decode_ragged(q, *_stacked(kp, vp), bt, ctx, ones,
-                                     layer=0, impl="ref")
-    plain = ops.paged_attention(q[:, 0], kp, vp, bt, ctx + 1, impl="ref")
-    assert (np.asarray(ragged[:, 0]) == np.asarray(plain)).all()
+    kw = dict(kv_scheme=scheme) if scheme else {}
+    ragged = ops.paged_decode_ragged(q, *_stacked(kp, vp), bt,
+                                     jnp.asarray(ctx, jnp.int32), ones,
+                                     layer=0, impl="ref", **kw)
+    k, v = _gathered(kp, bt, scheme), _gathered(vp, bt, scheme)
+    rep = hq // hkv
+    for i, n in enumerate(ctx):
+        # query head h reads KV head h // rep
+        ki = jnp.repeat(k[i, :, :n + 1], rep, axis=0)
+        vi = jnp.repeat(v[i, :, :n + 1], rep, axis=0)
+        want = kref.attention_ref(q[i, 0][:, None], ki, vi, causal=False)
+        np.testing.assert_allclose(np.asarray(ragged[i, 0]),
+                                   np.asarray(want[:, 0]),
+                                   rtol=1e-5, atol=1e-5)
 
 
 def test_ragged_quant_needs_scheme():
@@ -125,6 +152,20 @@ def test_registry_has_ragged_ops():
     for op in ("paged_decode_ragged", "paged_decode_ragged_quant"):
         assert set(registry.available_impls(op)) >= {"ref", "interpret"}
         assert registry.resolve(op, "auto").impl == "ref"   # CPU
+
+
+@pytest.mark.parametrize("op", ["paged_attention", "paged_attention_quant"])
+def test_single_token_decode_ops_are_gone(op):
+    """The per-call single-token decode ops were removed: neither the
+    registry nor ``ops`` knows them, while the ragged ops resolve."""
+    from repro.runtime import registry
+    assert op not in registry.registered_ops()
+    assert registry.available_impls(op) == ()
+    with pytest.raises(registry.KernelUnavailable):
+        registry.resolve(op, "auto")
+    assert not hasattr(ops, op)
+    ragged = op.replace("paged_attention", "paged_decode_ragged")
+    assert registry.resolve(ragged, "ref").op == ragged
 
 
 # ---------------------------------------------------------------------------
@@ -164,10 +205,8 @@ def test_fused_step_single_trace_across_ragged_ticks():
     assert step._cache_size() == 1                    # zero retrace
     calls = ops.op_calls()
     # one trace, one ragged-op call site inside it (the layer scan traces
-    # its body once) — and the legacy per-call decode ops never appear
+    # its body once)
     assert calls.get("paged_decode_ragged") == 1
-    assert calls.get("paged_attention") is None
-    assert calls.get("paged_attention_quant") is None
 
 
 def _pallas_kernels(jaxpr) -> list[str]:
@@ -199,9 +238,8 @@ def test_engine_fused_tick_is_one_compile_one_launch():
                                   kv_layout="paged", fused_decode=True),
                       rt=RT)
     # the step's program, traced with the Pallas kernels in place of the
-    # jnp oracles: the in-place KV write and the ragged decode, and no
-    # legacy per-call paged attention (the layer scan traces its body
-    # once)
+    # jnp oracles: the in-place KV write and the ragged decode (the layer
+    # scan traces its body once)
     b = eng.batch_slots
     traced = jax.jit(lm_mod.lm_paged_fused_step, static_argnums=(7, 8)).trace(
         eng.params, jnp.zeros((b, 1), jnp.int32), jnp.zeros(b, jnp.int32),
@@ -248,74 +286,175 @@ def test_engine_fused_tick_is_one_compile_one_launch():
     # tick
     assert events == []
     m = eng.metrics()
-    assert m["fused_decode"] is True
     assert m["launches"]["decode"] == decode_ticks > 2
     assert m["model_calls"] == sum(m["launches"].values())
 
 
 # ---------------------------------------------------------------------------
-# Engine bit-identity: megakernel on vs off
+# Engine outputs on the one decode path, against independent oracles
 # ---------------------------------------------------------------------------
 
-def _drive(params, cfg, prompts, *, fused, kv_quant=False, spec=False,
-           new_tokens=8):
-    rt = dataclasses.replace(RT, kv_quant=kv_quant,
-                             kv_scheme="spx_8_x3" if kv_quant else RT.kv_scheme)
-    eng = ServeEngine(params, cfg,
-                      ServeConfig(batch_slots=2, max_seq=64, quantize="sp2_4",
-                                  kv_layout="paged", fused_decode=fused,
-                                  spec_decode=True if spec else None,
-                                  spec_k=3 if spec else None),
-                      rt=rt)
+SPX_RT = RT.replace(kv_quant=True, kv_scheme="spx_8_x3")
+
+
+def _engine(params, cfg, *, layout="paged", rt=RT, **kw):
+    return ServeEngine(params, cfg,
+                       ServeConfig(batch_slots=2, max_seq=64, quantize=None,
+                                   kv_layout=layout, **kw), rt=rt)
+
+
+def _run(eng, prompts, *, new_tokens=8, temperature=0.0, frames=None):
+    """{rid: output} of ``prompts`` served to the end on ``eng``."""
     for i, p in enumerate(prompts):
-        eng.submit(Request(rid=i, prompt=p, max_new_tokens=new_tokens))
-    out = {r.rid: list(r.output) for r in eng.run()}
-    return out, eng.metrics()
+        eng.submit(Request(rid=i, prompt=p, max_new_tokens=new_tokens,
+                           temperature=temperature,
+                           seed=17 + i if temperature else None,
+                           frames=None if frames is None else frames[i]))
+    return {r.rid: list(r.output) for r in eng.run()}
 
 
-@pytest.mark.parametrize("kv_quant", [False, True])
-@pytest.mark.parametrize("spec", [False, True])
-def test_fused_greedy_bit_identical_to_unfused(kv_quant, spec):
+def _widths(eng, kind: str) -> list[int]:
+    """The token width of each ``kind`` launch ("prefill" or "decode")
+    ``eng`` makes from now on."""
+    widths = []
+    launch = eng._paged_launch
+
+    def spy(k, step, width, feed):
+        if k == kind:
+            widths.append(width)
+        return launch(k, step, width, feed)
+
+    eng._paged_launch = spy
+    return widths
+
+
+def _repetitive(cfg, n=4, reps=3, seed=3):
+    # tiled motifs: the prompt-lookup drafter has something to propose
+    rng = np.random.default_rng(seed)
+    return [np.tile(rng.integers(1, cfg.vocab_size, 4).astype(np.int32),
+                    reps) for _ in range(n)]
+
+
+@pytest.mark.parametrize("kv", ["f32", "spx"])
+def test_one_token_prefill_chunk(kv):
+    """Prompts of length 1 (mod prefill_chunk) end in a one-token chunk:
+    a tick whose prefilling slots all hold one prompt token runs at
+    C = 1, through the chunk gather like every other prefill chunk.
+    Greedy outputs equal the dense engine's (f32 KV) or, with SPx KV,
+    the same engine's at a chunk size where no tick runs at C = 1."""
     cfg = _tiny_cfg()
     params = lm_mod.lm_init(jax.random.PRNGKey(3), cfg)
-    rng = np.random.default_rng(3)
-    # repetition-heavy tails give the n-gram drafter something to accept
-    prompts = [np.tile(rng.integers(0, cfg.vocab_size, 4).astype(np.int32),
-                       3) for _ in range(4)]
-    fused_out, fm = _drive(params, cfg, prompts, fused=True,
-                           kv_quant=kv_quant, spec=spec)
-    plain_out, pm = _drive(params, cfg, prompts, fused=False,
-                           kv_quant=kv_quant, spec=spec)
-    assert fused_out == plain_out
-    assert fm["fused_decode"] and not pm["fused_decode"]
-    assert fm["tokens_generated"] == pm["tokens_generated"]
-    if spec:
-        # speculation stays effective through the megakernel: fewer model
-        # calls than tokens means some windows accepted drafts
-        assert fm["draft_acceptance_rate"] > 0.0
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, cfg.vocab_size, n).astype(np.int32)
+               for n in (9, 9, 17, 25)]
+    rt = SPX_RT if kv == "spx" else RT
+    eng = _engine(params, cfg, rt=rt, prefill_chunk=8)
+    widths = _widths(eng, "prefill")
+    got = _run(eng, prompts)
+    assert 1 in widths
+    if kv == "f32":
+        want = _run(_engine(params, cfg, layout="dense"), prompts)
+    else:
+        oracle = _engine(params, cfg, rt=rt, prefill_chunk=32)
+        oracle_widths = _widths(oracle, "prefill")
+        want = _run(oracle, prompts)
+        assert 1 not in oracle_widths
+    assert got == want
 
 
-def test_fused_sampled_matches_unfused_key_chain():
-    """Temperature sampling: the fused tick consumes the per-request key
-    chain exactly like the unfused one (one draw per emitted token), so
-    seeded sampled outputs are identical too."""
+@pytest.mark.parametrize("kv", ["f32", "spx"])
+def test_encdec_spec_matches_nonspec(kv):
+    """Enc-dec speculation scores its K+1 window through the decode
+    program (``encdec_paged_fused_step``): the self-attention in the
+    ragged decode kernel, cross-attention over the shared encoder
+    entry. Greedy outputs equal speculation off."""
+    cfg = reduced(get_config("whisper-small"), vocab=32)
+    params = encdec_mod.encdec_init(jax.random.PRNGKey(2), cfg)
+    fr = np.asarray(jax.random.normal(
+        jax.random.PRNGKey(3), (2, cfg.enc_seq_len, cfg.d_model)))
+    frames = [fr[0], fr[1], fr[0]]
+    prompts = _repetitive(cfg, n=3)
+    rt = SPX_RT if kv == "spx" else RT
+    off = _run(_engine(params, cfg, rt=rt), prompts, frames=frames)
+    eng = _engine(params, cfg, rt=rt, spec_decode=True, spec_k=3)
+    on = _run(eng, prompts, frames=frames)
+    assert on == off
+    assert eng._spec_proposed > 0          # the windows carried drafts
+
+
+@pytest.mark.parametrize("prefix", [False, True], ids=["npx", "pfx"])
+def test_paged_spec_greedy_matches_dense(prefix):
+    """Speculative greedy decode on the paged engine — the K+1 window
+    through the ragged decode kernel, with shared prefix pages or
+    without — emits what the dense engine emits without speculation."""
+    cfg = _tiny_cfg()
+    params = lm_mod.lm_init(jax.random.PRNGKey(3), cfg)
+    sys_p = np.random.default_rng(8).integers(1, cfg.vocab_size, 8)
+    prompts = [np.concatenate([sys_p, p]).astype(np.int32)
+               for p in _repetitive(cfg)]
+    want = _run(_engine(params, cfg, layout="dense"), prompts)
+    eng = _engine(params, cfg, page_size=8, prefix_cache=prefix,
+                  spec_decode=True, spec_k=3)
+    assert _run(eng, prompts) == want
+    m = eng.metrics()
+    assert m["draft_acceptance_rate"] > 0
+    assert (m["prefix_hits"] > 0) == prefix
+
+
+def test_sampled_matches_dense_key_chain():
+    """Temperature sampling: the paged decode tick draws from each
+    request's key chain exactly as the dense engine does (one draw per
+    emitted token), so seeded sampled outputs are identical."""
     cfg = _tiny_cfg()
     params = lm_mod.lm_init(jax.random.PRNGKey(3), cfg)
     rng = np.random.default_rng(9)
     prompts = [rng.integers(0, cfg.vocab_size, 8).astype(np.int32)
                for _ in range(2)]
+    paged = _run(_engine(params, cfg), prompts, new_tokens=6,
+                 temperature=0.8)
+    dense = _run(_engine(params, cfg, layout="dense"), prompts,
+                 new_tokens=6, temperature=0.8)
+    assert paged == dense
 
-    def run(fused):
-        eng = ServeEngine(params, cfg,
-                          ServeConfig(batch_slots=2, max_seq=64, quantize=None,
-                                      kv_layout="paged", fused_decode=fused),
-                          rt=RT)
-        for i, p in enumerate(prompts):
-            eng.submit(Request(rid=i, prompt=p, max_new_tokens=6,
-                               temperature=0.8, seed=17 + i))
-        return {r.rid: list(r.output) for r in eng.run()}
 
-    assert run(True) == run(False)
+class _AlternateDrafter:
+    """Proposes ``k`` copies of the last token on every other tick and
+    nothing in between, so drafted and undrafted ticks alternate."""
+
+    def __init__(self):
+        self.history: dict[int, list[int]] = {}
+
+    def start(self, rid, prompt):
+        self.history[rid] = [int(t) for t in prompt]
+
+    def extend(self, rid, tok):
+        self.history[rid].append(int(tok))
+
+    def drop(self, rid):
+        self.history.pop(rid, None)
+
+    def propose(self, rid, k):
+        h = self.history[rid]
+        return [h[-1]] * k if len(h) % 2 else []
+
+
+def test_spec_ticks_share_one_decode_program():
+    """With speculation on, drafted and undrafted ticks launch the same
+    decode program at the same width W = spec_k + 1 — so one compile
+    serves both, and there is no launch but prefill and decode — and
+    greedy outputs equal speculation off."""
+    cfg = _tiny_cfg()
+    params = lm_mod.lm_init(jax.random.PRNGKey(3), cfg)
+    prompts = _repetitive(cfg, n=1)
+    want = _run(_engine(params, cfg), prompts, new_tokens=10)
+    eng = _engine(params, cfg, spec_decode=True, spec_k=3)
+    eng.drafter = _AlternateDrafter()
+    widths = _widths(eng, "decode")
+    assert _run(eng, prompts, new_tokens=10) == want
+    m = eng.metrics()
+    assert set(m["launches"]) == {"prefill", "decode"}
+    assert 0 < eng._spec_windows < m["launches"]["decode"]
+    assert widths == [4] * m["launches"]["decode"]
 
 
 # ---------------------------------------------------------------------------
@@ -323,28 +462,29 @@ def test_fused_sampled_matches_unfused_key_chain():
 # ---------------------------------------------------------------------------
 
 def test_fused_decode_knobs(monkeypatch):
+    """One decode path: the removed REPRO_FUSED_DECODE=0 changes nothing,
+    fused_decode=False is an error, and a dense engine still resolves."""
     cfg = _tiny_cfg()
     params = lm_mod.lm_init(jax.random.PRNGKey(3), cfg)
-    # default ON for paged engines
-    assert ServeEngine(
-        params, cfg, ServeConfig(quantize=None, kv_layout="paged"),
-        rt=RT).fused_decode is True
-    # REPRO_FUSED_DECODE=0 flips the default off
-    monkeypatch.setenv("REPRO_FUSED_DECODE", "0")
-    assert ServeEngine(
-        params, cfg, ServeConfig(quantize=None, kv_layout="paged"),
-        rt=RT).fused_decode is False
+    prompts = _repetitive(cfg, n=1)
+    for env in (None, "0"):
+        if env is not None:
+            monkeypatch.setenv("REPRO_FUSED_DECODE", env)
+        eng = _engine(params, cfg)
+        assert eng.config.fused_decode is True
+        _run(eng, prompts, new_tokens=3)
+        m = eng.metrics()
+        assert m["launches"] == {"prefill": 1, "decode": 2}
+        assert "fused_decode" not in m
     monkeypatch.delenv("REPRO_FUSED_DECODE")
-    # dense engine: the env/default degrades silently ...
-    dense = ServeEngine(params, cfg,
-                        ServeConfig(quantize=None, kv_layout="dense"), rt=RT)
-    assert dense.fused_decode is False
-    # ... but an explicit True there is a caller error
-    with pytest.raises(ValueError, match="fused_decode"):
-        ServeEngine(params, cfg,
-                    ServeConfig(quantize=None, kv_layout="dense",
-                                fused_decode=True),
-                    rt=RT)
+    assert ServeConfig(quantize=None, fused_decode=True).resolve(cfg) \
+        == ServeConfig(quantize=None).resolve(cfg)
+    with pytest.raises(ValueError, match="fused_decode=False"):
+        ServeConfig(quantize=None, fused_decode=False).resolve(cfg)
+    # the dense engine resolves, and so does its replace()d config
+    dense = _engine(params, cfg, layout="dense")
+    again = dense.config.replace(batch_slots=4).resolve(cfg)
+    assert again.kv_layout == "dense" and again.resolved
 
 
 # ---------------------------------------------------------------------------

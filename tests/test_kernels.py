@@ -215,25 +215,30 @@ def test_gqa_wrapper_expansion():
 
 
 # ---------------------------------------------------------------------------
-# paged_attention
+# paged decode (the ragged decode kernel at W = 1)
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2), (8, 1)])
 @pytest.mark.parametrize("ps", [8, 16])
 def test_paged_attention_vs_ref(hq, hkv, ps):
-    """Interpret-mode kernel (scalar-prefetched block table, online softmax
-    over pages) vs the gather-everything ref oracle, across GQA ratios,
-    page sizes, partial last pages and inactive (ctx=0) rows."""
+    """Single-token decode through the ragged decode kernel (W = 1) in
+    interpret mode (scalar-prefetched block table, online softmax over
+    pages) vs the gather-everything ref oracle, across GQA ratios, page
+    sizes, partial last pages and inactive (q_len = 0) rows."""
     rng = np.random.default_rng(hq * 100 + hkv * 10 + ps)
     B, dh, n_pages, max_pages = 3, 32, 12, 3
-    q = _mk((B, hq, dh), jnp.float32, seed=ps + hq)
-    kp = _mk((n_pages, hkv, ps, dh), jnp.float32, seed=2)
-    vp = _mk((n_pages, hkv, ps, dh), jnp.float32, seed=3)
+    q = _mk((B, 1, hq, dh), jnp.float32, seed=ps + hq)
+    kp = _mk((1, n_pages, hkv, ps, dh), jnp.float32, seed=2)
+    vp = _mk((1, n_pages, hkv, ps, dh), jnp.float32, seed=3)
     bt = jnp.asarray(rng.permutation(n_pages)[:B * max_pages]
                      .reshape(B, max_pages), jnp.int32)
-    ctx = jnp.asarray([ps + 3, max_pages * ps, 0], jnp.int32)
-    want = ops.paged_attention(q, kp, vp, bt, ctx, impl="ref")
-    got = ops.paged_attention(q, kp, vp, bt, ctx, impl="interpret")
+    # the token at ctx attends positions <= ctx: ps + 4 and all
+    # max_pages * ps tokens; row 2 is inactive
+    ctx = jnp.asarray([ps + 3, max_pages * ps - 1, 0], jnp.int32)
+    q_len = jnp.asarray([1, 1, 0], jnp.int32)
+    args = (q, kp, vp, bt, ctx, q_len)
+    want = ops.paged_decode_ragged(*args, layer=0, impl="ref")
+    got = ops.paged_decode_ragged(*args, layer=0, impl="interpret")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
     assert float(jnp.max(jnp.abs(got[2]))) == 0.0     # inactive row

@@ -4,8 +4,8 @@
   f32 engine on mixed-length batches (the tentpole acceptance),
 * dense ``kv_quant`` decode logits stay within tolerance of the f32 cache
   (including the GQA ``jnp.repeat`` scale-folding path),
-* the fused-dequant paged-attention kernel (interpret mode) matches the
-  jnp oracle bit-for-bit per scheme,
+* the fused-dequant ragged decode kernel at W = 1 (interpret mode)
+  matches the jnp oracle per scheme,
 * pool/cache byte accounting equals the bytes actually allocated,
 * SPx level-set edge cases (midpoint ties, codebook padding, sp2_8 uint8
   round-trip), pack_int4 odd-dim errors, PagePool.release errors.
@@ -152,7 +152,7 @@ def test_quantize_kv_uniform8_matches_legacy_int8():
 
 
 # ---------------------------------------------------------------------------
-# Kernel parity: fused-dequant paged attention (interpret) vs jnp oracle
+# Kernel parity: fused-dequant paged decode (interpret) vs jnp oracle
 # ---------------------------------------------------------------------------
 
 # dh=128 tiles take the lane-gather lookup, dh=16 the select chain
@@ -162,19 +162,23 @@ def test_quantize_kv_uniform8_matches_legacy_int8():
 def test_paged_quant_kernel_interpret_matches_ref(scheme, dh):
     rng = np.random.default_rng(0)
     b, hq, hkv, ps, n_pages, mp = 3, 4, 2, 8, 6, 2
-    q = jnp.asarray(rng.standard_normal((b, hq, dh)), jnp.float32)
-    kv = rng.standard_normal((2, n_pages, hkv, ps, dh)).astype(np.float32)
+    q = jnp.asarray(rng.standard_normal((b, 1, hq, dh)), jnp.float32)
+    # one layer's pools, stacked as the decode kernel takes them
+    kv = rng.standard_normal((2, 1, n_pages, hkv, ps, dh)).astype(np.float32)
     kp = quantize_pages(jnp.asarray(kv[0]), scheme)
     vp = quantize_pages(jnp.asarray(kv[1]), scheme)
     bt = jnp.asarray(rng.integers(0, n_pages, (b, mp)), jnp.int32)
-    ctx = jnp.asarray([0, 5, 13], jnp.int32)     # inactive + partial pages
-    ref = ops.paged_attention_quant(q, kp, vp, bt, ctx, kv_scheme=scheme,
-                                    impl="ref")
-    itp = ops.paged_attention_quant(q, kp, vp, bt, ctx, kv_scheme=scheme,
-                                    impl="interpret")
+    # inactive + partial pages: the token at ctx attends positions <= ctx
+    ctx = jnp.asarray([0, 4, 12], jnp.int32)
+    q_len = jnp.asarray([0, 1, 1], jnp.int32)
+    args = (q, kp, vp, bt, ctx, q_len)
+    ref = ops.paged_decode_ragged(*args, layer=0, kv_scheme=scheme,
+                                  impl="ref")
+    itp = ops.paged_decode_ragged(*args, layer=0, kv_scheme=scheme,
+                                  impl="interpret")
     np.testing.assert_allclose(np.asarray(itp), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
-    assert np.all(np.asarray(ref[0]) == 0.0)     # ctx=0 row forced to zero
+    assert np.all(np.asarray(ref[0]) == 0.0)     # inactive row: zeros
 
 
 def test_plan_kv_pages_quant_geometry():

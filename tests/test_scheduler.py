@@ -1,6 +1,6 @@
 """Continuous-batching scheduler: differential request-storm replay
 (FIFO synchronous engine vs cb scheduler, bit-identical greedy outputs
-across the paged x SPx-quant x prefix-cache x spec-decode x fused-decode
+across the paged x SPx-quant x prefix-cache x spec-decode x prefill-chunk
 matrix), fault-injected preemption at every tick-boundary class, the
 run()-undrained regression, and scheduler knob validation.
 
@@ -93,14 +93,14 @@ def _run_fifo(params, rt):
     return {r.rid: list(r.output) for r in eng.finished}
 
 
-def _run_cb(params, rt, *, prefix, spec, fused):
+def _run_cb(params, rt, *, prefix, spec, chunk=None):
     eng = ServeEngine(params, CFG,
                       ServeConfig(batch_slots=SLOTS, max_seq=MAX_SEQ,
                                   quantize=None, kv_layout="paged",
                                   page_size=PAGE, pool_pages=POOL,
                                   scheduler="cb", prefix_cache=prefix,
                                   spec_decode=spec, spec_k=3 if spec else None,
-                                  fused_decode=fused),
+                                  prefill_chunk=chunk),
                       rt=rt)
     pending = sorted(_storm(), key=lambda r: r[4])
     for t in range(500):
@@ -130,14 +130,16 @@ def _baseline(params, kvq):
 @pytest.mark.parametrize("kvq", [False, True], ids=["f32", "spx"])
 @pytest.mark.parametrize("prefix", [False, True], ids=["npx", "pfx"])
 @pytest.mark.parametrize("spec", [False, True], ids=["nsp", "spec"])
-@pytest.mark.parametrize("fused", [False, True], ids=["unf", "fused"])
-def test_storm_differential_cb_vs_fifo(params, kvq, prefix, spec, fused):
+# chunk 5: the 26-token background prompts prefill in 5 + 5 + 5 + 5 + 5 + 1,
+# so the cb engine preempts mid-prefill and runs one-token chunks
+@pytest.mark.parametrize("chunk", [None, 5], ids=["c32", "c5"])
+def test_storm_differential_cb_vs_fifo(params, kvq, prefix, spec, chunk):
     """The tentpole acceptance: the same seeded storm through the old
     synchronous FIFO engine and the continuous-batching scheduler yields
     bit-identical per-request greedy outputs in every cell of the
     feature matrix — while the cb run actually preempts and offloads."""
     rt = RT_Q if kvq else RT
-    eng, got = _run_cb(params, rt, prefix=prefix, spec=spec, fused=fused)
+    eng, got = _run_cb(params, rt, prefix=prefix, spec=spec, chunk=chunk)
     assert got == _baseline(params, kvq)
     m = eng.metrics()
     assert m["preemptions"] > 0, "storm was not oversubscribed enough"
@@ -155,7 +157,7 @@ def test_storm_priority_ordering(params):
     the preempted victim resumes only after burst work drains, so the
     first burst request finishes before it; offload traffic is exactly
     the pages covering the victim's write cursor."""
-    eng, _ = _run_cb(params, RT, prefix=False, spec=False, fused=True)
+    eng, _ = _run_cb(params, RT, prefix=False, spec=False)
     order = [r.rid for r in eng.finished]
     victim = next(r for r in eng.finished if r.preemptions > 0)
     burst_first = min(order.index(rid) for rid in (2, 3, 4))

@@ -10,7 +10,6 @@ import dataclasses
 import os
 import subprocess
 import sys
-import warnings
 
 import numpy as np
 import pytest
@@ -117,8 +116,7 @@ from repro.serving.engine import Request
 
 kvq = sys.argv[1] == "kvq"
 spec = sys.argv[2] == "spec"
-fused = sys.argv[3] == "fused"
-impl = sys.argv[4] if len(sys.argv) > 4 else "ref"
+impl = sys.argv[3] if len(sys.argv) > 3 else "ref"
 
 # the pinned exact-greedy workload (vocab 32 keeps random-init top-2
 # logit gaps wide; dh=128 keeps kernels in their deployed regime) with
@@ -136,7 +134,7 @@ def drive(shards):
     sc = ServeConfig(batch_slots=2, max_seq=64, quantize="sp2_4",
                      kv_layout="paged", page_size=8,
                      spec_decode=spec, spec_k=2 if spec else None,
-                     fused_decode=fused, shards=shards)
+                     shards=shards)
     eng = ServeEngine(params, cfg, sc, rt=rt)
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, prompt=p, max_new_tokens=6))
@@ -157,13 +155,11 @@ print("OK identical", m2["peak_kv_bytes_per_shard"])
 
 @pytest.mark.parametrize("kvq", [False, True], ids=["plain", "spx-kv"])
 @pytest.mark.parametrize("spec", [False, True], ids=["nospec", "spec"])
-@pytest.mark.parametrize("fused", [False, True], ids=["unfused", "fused"])
-def test_sharded_greedy_bit_identical(kvq, spec, fused):
+def test_sharded_greedy_bit_identical(kvq, spec):
     """shards=2 on a forced-host mesh reproduces the single-device
     greedy outputs bit-for-bit, with per-shard KV bytes halved."""
     out = _run(_IDENTITY_WORKER, "kvq" if kvq else "plain",
-               "spec" if spec else "nospec",
-               "fused" if fused else "unfused")
+               "spec" if spec else "nospec")
     assert "OK identical" in out
 
 
@@ -173,7 +169,7 @@ def test_sharded_pallas_kernels_bit_identical(kvq):
     GSPMD cannot partition a Mosaic call, so each runs whole on every
     device of the mesh, and greedy outputs match one device's."""
     out = _run(_IDENTITY_WORKER, "kvq" if kvq else "plain", "nospec",
-               "fused", "interpret")
+               "interpret")
     assert "OK identical" in out
 
 
@@ -315,7 +311,7 @@ def test_engine_rejects_router_knob(params):
 
 
 # ---------------------------------------------------------------------------
-# ServeConfig: resolution ownership, validation, one-PR legacy shim
+# ServeConfig: resolution ownership and validation
 # ---------------------------------------------------------------------------
 
 def test_resolve_fills_every_knob_and_is_idempotent():
@@ -356,21 +352,3 @@ def test_resolve_validates_new_knobs():
         ServeConfig(quantize=None, shards=0).resolve(CFG)
     with pytest.raises(ValueError, match="replicas"):
         ServeConfig(quantize=None, replicas=0).resolve(CFG)
-
-
-def test_legacy_kwargs_warn_once_and_forward(params):
-    """The one-PR shim: old-style knob kwargs still build the same
-    engine, under a DeprecationWarning naming ServeConfig."""
-    with pytest.warns(DeprecationWarning, match="ServeConfig"):
-        eng = ServeEngine(params, CFG, rt=RT, batch_slots=2, max_seq=64,
-                          quantize=None, kv_layout="paged", page_size=8)
-    assert eng.config.batch_slots == 2
-    assert eng.config.page_size == 8 and eng.config.resolved
-    with pytest.raises(TypeError, match="ServeConfig"):
-        ServeEngine(params, CFG, rt=RT, quantize=None, bogus_knob=1)
-    # new-style construction must stay warning-free
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        ServeEngine(params, CFG,
-                    ServeConfig(batch_slots=2, max_seq=64, quantize=None,
-                                kv_layout="paged", page_size=8), rt=RT)

@@ -9,7 +9,8 @@ with ``jax.profiler.ProfileData``.
   engine served, and ``rows`` is the block the program computes;
 * ``metrics()``'s launch counters, prefill row share, queue wait and
   occupancy agree with what the trace and the requests show;
-* a running trace changes no output, fused, unfused or dense.
+* a running trace changes no output: paged, paged with speculation
+  (the verify window through the decode program) or dense.
 """
 import dataclasses
 import glob
@@ -42,8 +43,8 @@ PARENT = {"serve.submit": None, "serve.tick": None,
              for kind in ("prefill", "decode")
              for part in ("launch", "wait", "emit")}}
 
-LAYOUTS = {"fused": dict(kv_layout="paged", fused_decode=True),
-           "unfused": dict(kv_layout="paged", fused_decode=False),
+LAYOUTS = {"fused": dict(kv_layout="paged"),
+           "spec": dict(kv_layout="paged", spec_decode=True, spec_k=3),
            "dense": dict(kv_layout="dense")}
 
 

@@ -1,5 +1,5 @@
 """Per-request token streaming + cancellation (serving/stream.py):
-stream-vs-run() bit-identity across the paged x SPx x spec x fused x cb
+stream-vs-run() bit-identity across the paged x SPx x spec x scheduler
 matrix, cancellation at every tick-boundary class with a clean pool
 ``validate()`` after each, the monotonic fake-clock regression, the
 submit-reuse regression, the strict-run stream sentinel, and the asyncio
@@ -49,7 +49,7 @@ def _prompts(seed=3, n=4):
     return out
 
 
-def _engine(params, *, kvq=False, prefix=False, spec=False, fused=True,
+def _engine(params, *, kvq=False, prefix=False, spec=False,
             scheduler="cb", layout="paged"):
     return ServeEngine(params, CFG,
                        ServeConfig(batch_slots=SLOTS, max_seq=MAX_SEQ,
@@ -60,8 +60,7 @@ def _engine(params, *, kvq=False, prefix=False, spec=False, fused=True,
                                                 scheduler=scheduler,
                                                 prefix_cache=prefix,
                                                 spec_decode=spec,
-                                                spec_k=3 if spec else None,
-                                                fused_decode=fused))),
+                                                spec_k=3 if spec else None))),
                        rt=RT_Q if kvq else RT)
 
 
@@ -74,25 +73,25 @@ def _submit_all(eng, new_tokens=6):
 # Stream-vs-run() bit-identity across the feature matrix
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("kvq,prefix,spec,fused,scheduler", [
-    (False, False, False, True, "cb"),
-    (False, False, False, True, "fifo"),
-    (True, False, False, True, "cb"),
-    (False, True, False, True, "cb"),
-    (False, False, True, True, "cb"),
-    (False, False, True, False, "cb"),
-    (True, True, True, True, "cb"),
+@pytest.mark.parametrize("kvq,prefix,spec,scheduler", [
+    (False, False, False, "cb"),
+    (False, False, False, "fifo"),
+    (True, False, False, "cb"),
+    (False, True, False, "cb"),
+    (False, False, True, "cb"),
+    (False, False, True, "fifo"),
+    (True, True, True, "cb"),
 ])
-def test_stream_matches_run(params, kvq, prefix, spec, fused, scheduler):
+def test_stream_matches_run(params, kvq, prefix, spec, scheduler):
     """Delivered token sequences are bit-identical to run() results —
     streams read Request.output behind a cursor, so this pins that the
     read path stays pure across every engine feature combination."""
-    ref = _engine(params, kvq=kvq, prefix=prefix, spec=spec, fused=fused,
+    ref = _engine(params, kvq=kvq, prefix=prefix, spec=spec,
                   scheduler=scheduler)
     _submit_all(ref)
     base = {r.rid: list(r.output) for r in ref.run(max_steps=500)}
 
-    eng = _engine(params, kvq=kvq, prefix=prefix, spec=spec, fused=fused,
+    eng = _engine(params, kvq=kvq, prefix=prefix, spec=spec,
                   scheduler=scheduler)
     _submit_all(eng)
     streams = {i: eng.stream(i) for i in range(4)}
